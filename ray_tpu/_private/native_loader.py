@@ -1,8 +1,10 @@
 """Build + load the native (C++) runtime components.
 
 The shared library compiles on first use (g++ -O3 -shared) and is
-cached under ``native/build/`` keyed by a source hash, so a fresh
-checkout needs no explicit build step and stale binaries can't load.
+cached under ``native/build/`` keyed by everything the binary depends
+on — sources, compiler flags and, because of ``-march=native``, the
+host CPU — so a fresh checkout needs no explicit build step, and a
+binary built for another commit or another machine can't load.
 """
 
 from __future__ import annotations
@@ -11,6 +13,7 @@ import ctypes
 import hashlib
 import logging
 import os
+import platform
 import subprocess
 import threading
 from typing import Optional
@@ -24,11 +27,29 @@ _lock = threading.Lock()
 _cache = {}
 
 
-def _source_hash(paths) -> str:
+def _host_cpu() -> bytes:
+    """What ``-march=native`` resolves against: the machine type plus
+    the first core's model and feature flags."""
+    ident = [platform.machine().encode()]
+    try:
+        with open("/proc/cpuinfo", "rb") as f:
+            for line in f:
+                if line.startswith((b"model name", b"flags", b"Features")):
+                    ident.append(line)
+                elif not line.strip():
+                    break               # end of the first core's block
+    except OSError:
+        pass    # no procfs: the machine type alone keys the build
+    return b"".join(ident)
+
+
+def _build_hash(paths, flags) -> str:
     h = hashlib.sha1()
     for p in paths:
         with open(p, "rb") as f:
             h.update(f.read())
+    h.update(" ".join(flags).encode())
+    h.update(_host_cpu())
     return h.hexdigest()[:16]
 
 
@@ -42,12 +63,12 @@ def load_library(name: str, sources, extra_flags=()) -> Optional[
             srcs = [os.path.join(_NATIVE_DIR, s) for s in sources]
             build_dir = os.path.join(_NATIVE_DIR, "build")
             os.makedirs(build_dir, exist_ok=True)
-            tag = _source_hash(srcs)
+            flags = ["-O3", "-march=native", "-std=c++17", "-shared",
+                     "-fPIC", *extra_flags]
+            tag = _build_hash(srcs, flags)
             so_path = os.path.join(build_dir, f"{name}-{tag}.so")
             if not os.path.exists(so_path):
-                cmd = ["g++", "-O3", "-march=native", "-std=c++17",
-                       "-shared", "-fPIC", *extra_flags,
-                       *srcs, "-o", so_path + ".tmp"]
+                cmd = ["g++", *flags, *srcs, "-o", so_path + ".tmp"]
                 # blocking-ok: one-time compile at first use; the lock
                 # IS the build serialization — concurrent callers must
                 # wait for the single .so rather than race the compiler

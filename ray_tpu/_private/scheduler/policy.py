@@ -404,18 +404,16 @@ _accelerator_cache: Optional[bool] = None
 
 
 def _accelerator_present() -> bool:
-    """True iff jax's default backend is a real accelerator (TPU/GPU).
+    """True iff jax's default backend is a TPU. A jax that cannot
+    initialize raises: the host must not quietly schedule as CPU-only.
 
     Cached: backend detection initializes jax, which is expensive and
     stable for the process lifetime.
     """
     global _accelerator_cache
     if _accelerator_cache is None:
-        try:
-            import jax
-            _accelerator_cache = jax.default_backend() not in ("cpu",)
-        except Exception:
-            _accelerator_cache = False
+        import jax
+        _accelerator_cache = jax.default_backend() == "tpu"
     return _accelerator_cache
 
 

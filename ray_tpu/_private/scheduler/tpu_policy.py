@@ -39,6 +39,7 @@ invalidated by ``ClusterResourceManager.version()``.
 from __future__ import annotations
 
 import functools
+import logging
 import threading
 import time
 from typing import Dict, List, NamedTuple, Optional, Sequence
@@ -57,6 +58,8 @@ from ray_tpu._private.scheduler.policy import (
     register_policy,
 )
 from ray_tpu._private.scheduler.resources import ClusterResourceManager
+
+logger = logging.getLogger(__name__)
 
 _EPS = 1e-6
 
@@ -298,9 +301,7 @@ def _schedule_classes_kernel(
         (avail, residual))
 
     # Pack every host-bound output into ONE int32 array so the policy
-    # pays for a single device->host transfer per invocation (transfer
-    # count, not bytes, dominates dispatch latency on remote-attached
-    # TPUs, and it is one DMA either way on local PCIe). Rows are
+    # pays for a single device->host transfer per invocation. Rows are
     # gathered back to the CALLER's class order — the scarcity
     # permutation is internal to the commit sequence.
     packed = jnp.concatenate(
@@ -575,10 +576,10 @@ _device_rt_thread: Optional[threading.Thread] = None
 
 
 def _measure_device_rt() -> None:
-    """One-shot measurement of the device dispatch round trip. On a
-    PCIe-local chip this is O(100 µs); on a remote-attached (tunneled)
-    chip it can be O(100 ms) — the adaptive policy must know which
-    world it lives in."""
+    """One-shot measurement of the device dispatch round trip (one
+    tiny jitted call plus its d2h transfer): the floor every kernel
+    invocation pays, which the adaptive policy weighs against the CPU
+    scan's per-task cost."""
     global _device_rt_s
     try:
         f = jax.jit(lambda x: x + 1.0)
@@ -588,7 +589,11 @@ def _measure_device_rt() -> None:
         np.asarray(f(x))
         _device_rt_s = time.perf_counter() - t0
     except Exception:
-        _device_rt_s = float("inf")          # no usable device
+        # probe thread: nowhere to raise to. inf keeps every live batch
+        # on the CPU scan; the warning says why the kernel never runs.
+        logger.warning("device round-trip probe failed; the scheduling "
+                       "kernel will not be used", exc_info=True)
+        _device_rt_s = float("inf")
 
 
 def _ensure_rt_measurement() -> None:
@@ -609,13 +614,11 @@ class AdaptiveSchedulingPolicy(ISchedulingPolicy):
     floor. The kernel therefore pays off only when the batch's CPU-scan
     cost exceeds the measured device round trip: the policy measures
     that round trip once (async, CPU path until known) and routes each
-    batch by ``batch × per_task_cpu_cost vs round_trip``. On a
-    PCIe-local chip the crossover is a few hundred tasks; on a
-    remote-attached chip it is high enough that live dispatch stays on
-    the native scan — which is exactly right, because scanning a small
-    cluster is nanoseconds while the tunnel is milliseconds. This is
-    the "dispatch small batches at high rate" answer to SURVEY §7's
-    dynamic-scheduling-on-static-device hard part.
+    batch by ``batch × per_task_cpu_cost vs round_trip``; the crossover
+    moves with the cluster size (the scan is O(nodes) per task) and is
+    counted, not assumed: ``num_kernel_batches`` / ``num_scan_batches``.
+    This is the "dispatch small batches at high rate" answer to SURVEY
+    §7's dynamic-scheduling-on-static-device hard part.
     """
 
     name = "tpu_adaptive"
@@ -631,6 +634,8 @@ class AdaptiveSchedulingPolicy(ISchedulingPolicy):
         self._tpu = TpuSchedulingPolicy()
         from ray_tpu._private.scheduler.policy import _cpu_hybrid_policy
         self._cpu = _cpu_hybrid_policy()
+        self.num_kernel_batches = 0
+        self.num_scan_batches = 0
         _ensure_rt_measurement()
 
     def _kernel_pays_off(self, n_tasks: int, n_nodes: int) -> bool:
@@ -647,7 +652,9 @@ class AdaptiveSchedulingPolicy(ISchedulingPolicy):
         if (len(requests) < self._min_batch
                 or not self._kernel_pays_off(len(requests),
                                              cluster.num_nodes())):
+            self.num_scan_batches += 1
             return self._cpu.schedule_batch(cluster, requests)
+        self.num_kernel_batches += 1
         return self._tpu.schedule_batch(cluster, requests)
 
     def schedule(self, cluster: ClusterResourceManager,

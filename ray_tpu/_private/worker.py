@@ -90,14 +90,15 @@ def _validate_runtime_env(runtime_env: Optional[dict]) -> Optional[dict]:
 
 
 def _detect_num_tpus() -> int:
-    """TPU chips owned by this host process (0 if jax unusable)."""
+    """TPU chips owned by this host process. A jax that cannot
+    initialize raises here: a host must not come up as a CPU-only node
+    because its chip failed to open."""
     if os.environ.get("RAY_TPU_FAKE_TPUS"):
         return int(os.environ["RAY_TPU_FAKE_TPUS"])
-    try:
-        import jax
-        return sum(1 for d in jax.devices() if d.platform != "cpu")
-    except Exception:
-        return 0
+    import jax
+    from ray_tpu._private.compile_cache import configure_compile_cache
+    configure_compile_cache()
+    return sum(1 for d in jax.devices() if d.platform == "tpu")
 
 
 @dataclass

@@ -740,12 +740,7 @@ class ExecutionEnv:
         otherwise."""
         import sys as _sys
         if "jax" in _sys.modules:
-            try:
-                from jax.profiler import TraceAnnotation
-            except ImportError:
-                return call()
-            # NOT inside the try: a user ImportError must propagate,
-            # not trigger a silent second execution.
+            from jax.profiler import TraceAnnotation
             with TraceAnnotation(name):
                 return call()
         return call()

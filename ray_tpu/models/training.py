@@ -92,10 +92,15 @@ def make_train_step(cfg: TransformerConfig,
     """Returns jitted (state, batch) -> (state, metrics). ``batch_keys``
     must name every key of the batch dict (e.g. add "loss_mask") so the
     sharding pytree matches. With an sp>1 mesh and no explicit
-    ``attn_fn``, attention runs as ring attention over the sp axis."""
-    if attn_fn is None and mesh is not None and mesh.shape.get("sp", 1) > 1:
+    ``attn_fn``, attention runs as ring attention over the sp axis;
+    with ``cfg.use_flash`` on any other mesh, as the flash kernel on
+    each device's batch/head shard."""
+    if attn_fn is None and mesh is not None:
         from ray_tpu.ops import make_attention_fn
-        attn_fn = make_attention_fn(mesh, impl="ring")
+        if mesh.shape.get("sp", 1) > 1:
+            attn_fn = make_attention_fn(mesh, impl="ring")
+        elif cfg.use_flash:
+            attn_fn = make_attention_fn(mesh, impl="flash")
 
     def train_step(state: TrainState, batch: Dict[str, jax.Array]):
         loss, grads = jax.value_and_grad(loss_fn)(

@@ -405,40 +405,51 @@ def _flash_bwd(q, k, v, out, lse, g, causal, sm_scale, block_q, block_k,
     return unfold(dq, s_q), unfold(dk, s_k), unfold(dv, s_k)
 
 
+def _for_lowering_platform(fn, interpret: Optional[bool], *arrays):
+    """``fn(*arrays, interpret=...)`` with the flag chosen from the
+    platform the arrays are LOWERED for — not the process's default
+    backend, which an AOT compile for a described TPU does not have:
+    the Pallas interpreter on CPU, the Mosaic kernel everywhere else,
+    so a TPU program never carries the interpreter. An explicit
+    ``interpret`` (the CPU tests) is taken as given."""
+    if interpret is not None:
+        return fn(*arrays, interpret=interpret)
+    return jax.lax.platform_dependent(
+        *arrays,
+        cpu=functools.partial(fn, interpret=True),
+        default=functools.partial(fn, interpret=False))
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def flash_attention(q, k, v, causal: bool = True,
                     sm_scale: Optional[float] = None,
                     block_q: int = 128, block_k: int = 128,
                     interpret: Optional[bool] = None):
     """Fused attention. [B,S,N,H] -> [B,S,N,H]."""
-    if sm_scale is None:
-        sm_scale = 1.0 / np.sqrt(q.shape[-1])
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
-    out, _lse = _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k,
-                           interpret)
+    out, _res = _flash_vjp_fwd(q, k, v, causal, sm_scale, block_q,
+                               block_k, interpret)
     return out
 
 
 def _flash_vjp_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret):
     if sm_scale is None:
         sm_scale = 1.0 / np.sqrt(q.shape[-1])
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
-    out, lse = _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k,
-                          interpret)
+    out, lse = _for_lowering_platform(
+        functools.partial(_flash_fwd, causal=causal, sm_scale=sm_scale,
+                          block_q=block_q, block_k=block_k),
+        interpret, q, k, v)
     return out, (q, k, v, out, lse)
 
 
 def _flash_vjp_bwd(causal, sm_scale, block_q, block_k, interpret,
                    residuals, g):
-    q, k, v, out, lse = residuals
+    q = residuals[0]
     if sm_scale is None:
         sm_scale = 1.0 / np.sqrt(q.shape[-1])
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
-    return _flash_bwd(q, k, v, out, lse, g, causal, sm_scale, block_q,
-                      block_k, interpret)
+    return _for_lowering_platform(
+        functools.partial(_flash_bwd, causal=causal, sm_scale=sm_scale,
+                          block_q=block_q, block_k=block_k),
+        interpret, *residuals, g)
 
 
 flash_attention.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)

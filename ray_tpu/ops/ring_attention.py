@@ -126,7 +126,9 @@ def make_attention_fn(mesh: Optional[Mesh] = None, *,
 
     impl: "auto" | "ring" | "ulysses" | "flash" | "reference".
     With a mesh whose ``sp`` axis > 1, "auto" = ring. Without, "auto"
-    = flash (pallas on TPU, interpreter on CPU).
+    = flash (pallas on TPU, interpreter on CPU); given a mesh, flash
+    runs per device on its batch/head shard, because the compiler
+    cannot partition a Mosaic kernel by itself.
     """
     sp = (mesh.shape.get(sp_axis, 1) if mesh is not None else 1)
     if impl == "auto":
@@ -137,13 +139,15 @@ def make_attention_fn(mesh: Optional[Mesh] = None, *,
     if impl == "reference":
         return functools.partial(mha_reference, causal=causal)
     if impl == "flash":
-        return lambda q, k, v: flash_attention(q, k, v, causal)
-
-    spec = P(batch_axes, sp_axis, tp_axis, None)
-    body = (ring_attention_shard if impl == "ring"
-            else ulysses_attention_shard)
-    shard_fn = jax.shard_map(
-        functools.partial(body, axis_name=sp_axis, causal=causal),
-        mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-        check_vma=False)
-    return shard_fn
+        body = lambda q, k, v: flash_attention(q, k, v, causal)  # noqa: E731
+        if mesh is None:
+            return body
+        spec = P(batch_axes, None, tp_axis, None)   # whole sequences
+    else:
+        spec = P(batch_axes, sp_axis, tp_axis, None)
+        body = functools.partial(
+            ring_attention_shard if impl == "ring"
+            else ulysses_attention_shard,
+            axis_name=sp_axis, causal=causal)
+    return jax.shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec, check_vma=False)

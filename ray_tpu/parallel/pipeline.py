@@ -28,15 +28,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:
-    from jax import shard_map as _jax_shard_map
-
-    def shard_map(f, mesh, in_specs, out_specs, check_rep=False):
-        return _jax_shard_map(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_vma=check_rep)
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map
-
 
 def stack_pipeline_blocks(blocks: List[Dict], num_stages: int):
     """[layer-list of block pytrees] -> stacked pytree with leading
@@ -77,9 +68,9 @@ def pipeline_apply(mesh: Mesh, stacked_blocks, x: jax.Array,
     other_axes = tuple(a for a in mesh.axis_names if a != "pp")
 
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(block_specs, P(), P()),
-        out_specs=P(), check_rep=False)
+        out_specs=P(), check_vma=False)
     def run(blocks, xm, pos):
         # local stage slab: [1, per, ...] -> [per, ...]
         blocks = jax.tree.map(lambda a: a[0], blocks)

@@ -149,9 +149,6 @@ def test_two_slice_trainer_matches_single_mesh_and_dcn_bytes():
     flat_dcn_bytes = world * grad_bytes * steps
     assert hier_stats["bytes_tx"] * num_slices <= flat_dcn_bytes
     assert hier_stats["ops"] == num_slices * steps
-    # cost model charged: 2 ms latency per remote read, 1 remote read
-    # per leader per step
-    assert hier_stats["ms"] >= 2.0 * num_slices * steps
 
     series = {}
     for line in text.splitlines():

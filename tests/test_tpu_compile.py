@@ -1,0 +1,92 @@
+"""Ask the TPU compiler, without a TPU, whether the main path's kernels
+compile at real widths (guide on-chip-measurement §2.3).
+
+libtpu compiles for a chip that is described and not attached, so these
+catch what interpret mode cannot: misaligned slices, too much VMEM, a
+Mosaic kernel the partitioner refuses, and a TPU program that carries
+the Pallas interpreter in place of the kernel. A compile that passes is
+not a chip run — ``python chip_smoke.py`` is.
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+from ray_tpu.ops import flash_attention, make_attention_fn
+from ray_tpu.parallel.mesh import MeshSpec, make_mesh
+
+# the flagship's attention shape (bench.py, chip_smoke.py)
+B, S, N, H = 4, 2048, 16, 128
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """The four described devices of a v5e 2x2 host. The persistent
+    compile cache is off around these compiles: an entry written
+    without a chip cannot be read back without one."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:    # no libtpu here: nothing to ask
+        pytest.skip(f"cannot describe a v5e:2x2 topology: {e!r}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield topo.devices
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _assert_kernel_not_interpreter(lowered):
+    text = lowered.as_text()
+    assert "tpu_custom_call" in text
+    assert "stablehlo.while" not in text    # the interpreter's grid loop
+    lowered.compile()
+
+
+@pytest.mark.parametrize("block", [128, 512])
+@pytest.mark.parametrize("direction", ["forward", "backward"])
+def test_flash_compiles_for_tpu(v5e, block, direction):
+    x = jax.ShapeDtypeStruct((B, S, N, H), jnp.bfloat16,
+                             sharding=SingleDeviceSharding(v5e[0]))
+
+    def attend(q, k, v):        # interpret=None: chosen by the lowering
+        return flash_attention(q, k, v, True, None, block, block)
+
+    fn = attend if direction == "forward" else jax.grad(
+        lambda q, k, v: jnp.sum(attend(q, k, v).astype(jnp.float32)),
+        argnums=(0, 1, 2))
+    _assert_kernel_not_interpreter(jax.jit(fn).lower(x, x, x))
+
+
+def test_flash_compiles_under_a_mesh(v5e):
+    """The partitioner refuses a bare Mosaic kernel; under a mesh the
+    kernel runs per device on its batch/head shard."""
+    mesh = make_mesh(MeshSpec(fsdp=2, tp=2), v5e)
+    x = jax.ShapeDtypeStruct(
+        (B, S, N, H), jnp.bfloat16,
+        sharding=NamedSharding(mesh, P(("dp", "fsdp"), None, "tp", None)))
+    attend = make_attention_fn(mesh, impl="flash")
+    _assert_kernel_not_interpreter(jax.jit(attend).lower(x, x, x))
+
+
+def test_scheduler_kernel_compiles_for_tpu(v5e):
+    from ray_tpu._private.scheduler.tpu_policy import _schedule_classes_kernel
+    one = SingleDeviceSharding(v5e[0])
+    n, k, r = 1024, 8, 4
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    _schedule_classes_kernel.lower(
+        arg((n, r), jnp.float32), arg((n, r), jnp.float32),
+        arg((n,), jnp.bool_), arg((k, r), jnp.float32),
+        arg((k,), jnp.int32), arg((k,), jnp.int32), arg((), jnp.float32),
+        num_classes=k).compile()
