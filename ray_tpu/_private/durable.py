@@ -28,7 +28,9 @@ from __future__ import annotations
 import os
 import pickle
 import tempfile
-from typing import Any, Callable, Dict
+from typing import Any, Callable, Dict, Optional
+
+from ray_tpu.util import tracing
 
 __all__ = [
     "fsync_dir",
@@ -100,8 +102,18 @@ def atomic_write_bytes(path: str, data: bytes) -> None:
 
 
 def atomic_pickle(path: str, obj: Any,
-                  protocol: int = pickle.HIGHEST_PROTOCOL) -> None:
-    atomic_write(path, lambda f: pickle.dump(obj, f, protocol=protocol))
+                  protocol: int = pickle.HIGHEST_PROTOCOL,
+                  span: Optional[str] = None) -> None:
+    """``span`` names a ``util.tracing`` span round the whole write
+    (pickle + fsync + rename), with the bytes written as its count."""
+    open_span = tracing.span(span) if span else tracing.NO_SPAN
+
+    def writer(f) -> None:
+        pickle.dump(obj, f, protocol=protocol)
+        open_span.note(bytes=f.tell())
+
+    with open_span:
+        atomic_write(path, writer)
 
 
 def atomic_savez(path: str, arrays: Dict[str, Any]) -> None:

@@ -2588,6 +2588,10 @@ class NodeManagerGroup:
             from ray_tpu._private.profiling import deliver_stack_reply
             deliver_stack_reply(worker, reply[1])
             return
+        if op == "spans":
+            from ray_tpu._private.profiling import deliver_spans_reply
+            deliver_spans_reply(worker, reply)
+            return
         if op == "done":
             _, task_id_b, results, err_blob = reply[:4]
             timings = reply[4] if len(reply) > 4 else None

@@ -42,9 +42,9 @@ def process_rss_bytes(pid: Optional[int] = None) -> int:
     return 0
 
 
-# Serializes concurrent stack requests: the per-worker reply slots
-# (_stack_evt/_stack_text) are shared state, and two overlapping
-# requesters would orphan each other's events.
+# Serializes concurrent requests: the per-worker reply slots
+# (_stack_evt/_stack_reply, _spans_evt/_spans_reply) are shared state,
+# and two overlapping requesters would orphan each other's events.
 _REQUEST_LOCK = threading.Lock()
 # Makes slot RESET (requester) and slot DELIVERY (worker IO thread)
 # atomic against each other: a late reply from a previous timed-out
@@ -74,42 +74,78 @@ def request_worker_stacks(workers, timeout: float = 3.0
     are reported as such rather than omitted."""
     import os
     import signal
+
+    def ask(w) -> None:
+        pid = getattr(getattr(w, "proc", None), "pid", None)
+        if pid is not None:
+            os.kill(pid, signal.SIGUSR1)
+        else:
+            w.send(("dump_stacks",))
+
+    out: Dict[str, str] = {}
+    for w, text in _request_replies(workers, "_stack", ask, timeout):
+        out[f"worker:{w.worker_id.hex()[:12]}"] = (
+            text if text is not None else "<no reply within deadline>")
+    return out
+
+
+def gather_pool_spans(worker_pool, timeout: float = 2.0) -> List[tuple]:
+    """The ("spans", ...) replies of a pool's live process workers:
+    each empties its span ring into the reply
+    (``ray_tpu.util.tracing.drain``). The request is a pipe message
+    that the worker's intake thread answers, also in mid-task; a
+    worker that does not answer in time is left out."""
+    with worker_pool._lock:
+        workers = [w for w in worker_pool._all.values()
+                   if getattr(w, "conn", None) is not None and w.alive]
+    replies = _request_replies(
+        workers, "_spans", lambda w: w.send(("dump_spans",)), timeout)
+    return [reply for _w, reply in replies if reply is not None]
+
+
+def _request_replies(workers, slot: str, ask, timeout: float) -> list:
+    """One request to each worker, one reply slot a worker
+    (``<slot>_evt`` / ``<slot>_reply``) that ``deliver_reply`` fills
+    from the reply routers; returns ``[(worker, reply or None)]``."""
     with _REQUEST_LOCK:
         asked = []
         for w in workers:
             with _SLOT_LOCK:
-                w._stack_evt = threading.Event()
-                w._stack_text = None
-            pid = getattr(getattr(w, "proc", None), "pid", None)
+                setattr(w, slot + "_evt", threading.Event())
+                setattr(w, slot + "_reply", None)
             try:
-                if pid is not None:
-                    os.kill(pid, signal.SIGUSR1)
-                else:
-                    w.send(("dump_stacks",))
+                ask(w)
                 asked.append(w)
             except Exception:
                 pass    # worker died mid-request: report the rest
-        out: Dict[str, str] = {}
         deadline = time.monotonic() + timeout
+        out = []
         for w in asked:
-            w._stack_evt.wait(max(0.0, deadline - time.monotonic()))
-            key = f"worker:{w.worker_id.hex()[:12]}"
-            out[key] = (w._stack_text if w._stack_text is not None
-                        else "<no reply within deadline>")
+            getattr(w, slot + "_evt").wait(
+                max(0.0, deadline - time.monotonic()))
+            out.append((w, getattr(w, slot + "_reply")))
         return out
 
 
-def deliver_stack_reply(worker, text: str) -> None:
-    """Reply half of ``request_worker_stacks`` (called from the reply
+def deliver_reply(worker, slot: str, reply) -> None:
+    """Reply half of ``_request_replies`` (called from the reply
     routers). Atomic against slot reset — a straggler reply either
     lands fully before the next request's reset (and is discarded by
-    it) or fully after (a fresh-enough dump the fresh reply then
+    it) or fully after (a fresh-enough reply the fresh one then
     overwrites)."""
     with _SLOT_LOCK:
-        worker._stack_text = text
-        evt = getattr(worker, "_stack_evt", None)
+        setattr(worker, slot + "_reply", reply)
+        evt = getattr(worker, slot + "_evt", None)
         if evt is not None:
             evt.set()
+
+
+def deliver_stack_reply(worker, text: str) -> None:
+    deliver_reply(worker, "_stack", text)
+
+
+def deliver_spans_reply(worker, reply: tuple) -> None:
+    deliver_reply(worker, "_spans", reply)
 
 
 def worker_rss_map(worker_pool) -> Dict[str, int]:

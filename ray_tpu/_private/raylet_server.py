@@ -184,6 +184,7 @@ class RayletServer:
         self.server.register("stats", lambda ctx: self.stats())
         self.server.register("read_logs", self._handle_read_logs)
         self.server.register("dump_stacks", self._handle_dump_stacks)
+        self.server.register("dump_spans", self._handle_dump_spans)
         self.server.register("submit", self._handle_submit)
         self.server.register("submit_many", self._handle_submit_many)
         self.server.register("submit_batch", self._handle_submit_batch)
@@ -671,6 +672,12 @@ class RayletServer:
         out.update(gather_pool_stacks(self.worker_pool))
         return out
 
+    def _handle_dump_spans(self, ctx) -> list:
+        """Span collection (``tracing.collect`` on the driver): the
+        emptied span rings of this raylet's process workers."""
+        from ray_tpu._private.profiling import gather_pool_spans
+        return gather_pool_spans(self.worker_pool)
+
     def _handle_read_logs(self, ctx, cursor):
         """Per-node agent log plane: incremental tail over this node's
         worker stdout/stderr files (the driver's log monitor and the
@@ -875,6 +882,10 @@ class RayletServer:
         if op == "stacks":
             from ray_tpu._private.profiling import deliver_stack_reply
             deliver_stack_reply(worker, reply[1])
+            return
+        if op == "spans":
+            from ray_tpu._private.profiling import deliver_spans_reply
+            deliver_spans_reply(worker, reply)
             return
         if op == "stream":
             # streaming generator item: seal big items locally, relay

@@ -2656,6 +2656,13 @@ class Worker:
             return
         self._shutdown = True
         self._actor_flush_wake.set()
+        try:
+            # the workers' spans outlive the pool (tracing.spans())
+            from ray_tpu.util import tracing
+            tracing.collect(timeout=1.0, worker=self)
+        except Exception:
+            logger.debug("span collection at shutdown failed",
+                         exc_info=True)
         if getattr(self, "_log_monitor", None) is not None:
             self._log_monitor.stop()
         from ray_tpu.util import metrics as _metrics
@@ -2834,6 +2841,24 @@ class Worker:
             except Exception as e:
                 out[nid.hex()[:12]] = {"error": repr(e)}
         return out
+
+    def gather_worker_spans(self, timeout: float = 2.0) -> List[tuple]:
+        """The emptied span rings of every live process worker, local
+        pools and remote raylets' (``util.tracing.collect``)."""
+        from ray_tpu._private.profiling import gather_pool_spans
+        with self.node_group._lock:
+            raylets = list(self.node_group._raylets.values())
+            remotes = list(self.node_group._remote_nodes.values())
+        replies: List[tuple] = []
+        for raylet in raylets:
+            replies += gather_pool_spans(raylet.worker_pool, timeout)
+        for handle in remotes:
+            try:
+                replies += handle.client.call("dump_spans",
+                                              timeout=timeout + 3)
+            except Exception:
+                pass    # node gone: its spans went with it
+        return replies
 
     def cluster_resources(self) -> Dict[str, float]:
         total: Dict[str, float] = {}

@@ -735,15 +735,12 @@ class ExecutionEnv:
 
     @staticmethod
     def _with_trace_annotation(name: str, call):
-        """Wrap the user call in a jax.profiler.TraceAnnotation when jax
-        is already loaded in this worker — no-op (and no jax import)
-        otherwise."""
-        import sys as _sys
-        if "jax" in _sys.modules:
-            from jax.profiler import TraceAnnotation
-            with TraceAnnotation(name):
-                return call()
-        return call()
+        """Run the user call under the task's name in any device
+        profile being taken (``tracing.annotate``: no-op, and no jax
+        import, where jax is not loaded). No span is recorded a task."""
+        from ray_tpu.util import tracing
+        with tracing.annotate(name):
+            return call()
 
     @staticmethod
     def _publish_channels(pubs, blob: bytes, kind: str = "blob") -> None:
@@ -1250,6 +1247,15 @@ def worker_main(conn, session: str, max_inline_bytes: int,
                     # unrelated steal must not pop a target whose own
                     # steal is still in flight)
                     send(("stolen", taken, list(wanted)))
+                except Exception:
+                    return
+                continue
+            if op0 == "dump_spans":
+                # span collection (tracing.collect): answered at
+                # intake, so also while the main loop is in user code
+                from ray_tpu.util import tracing
+                try:
+                    send(tracing.drain())
                 except Exception:
                     return
                 continue

@@ -395,8 +395,10 @@ def shutdown() -> None:
        in-flight HTTP requests while replicas are still alive (the
        old order killed the worker proxy while requests raced through
        it);
-    3. stop the controller — deployments deleted, replicas drained
-       and killed;
+    3. gather the process workers' spans (``tracing.collect``: the
+       proxy's and the replicas' rings die with their processes), then
+       stop the controller — deployments deleted, replicas drained and
+       killed;
     4. kill the (now idle, unrouted) worker proxy actor.
     """
     global _controller, _proxy, _worker_proxy
@@ -416,6 +418,13 @@ def shutdown() -> None:
         except Exception:
             pass    # proxy actor already dead / runtime torn down
     if controller is not None:
+        try:
+            # the spans of the proxy and of process-hosted replicas die
+            # with their processes: gather them while both still live
+            from ray_tpu.util import tracing
+            tracing.collect()
+        except Exception:
+            pass    # runtime torn down: nothing left to gather from
         controller.shutdown()
     if worker_proxy is not None:
         try:

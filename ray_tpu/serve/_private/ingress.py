@@ -61,6 +61,7 @@ from ray_tpu.exceptions import (
     TaskError,
     WorkerCrashedError,
 )
+from ray_tpu.util import tracing
 
 logger = logging.getLogger(__name__)
 
@@ -252,7 +253,9 @@ class _Slot:
 
     __slots__ = ("state", "keep_alive", "data", "t0", "ref", "cb",
                  "stream", "head", "sbuf", "attached", "stream_done",
-                 "close_after", "accounted", "cancelled")
+                 "close_after", "accounted", "cancelled",
+                 "t_in", "bytes_in", "rid", "t_ready", "polled",
+                 "t_write")
 
     def __init__(self, keep_alive: bool):
         self.state = _PENDING
@@ -269,6 +272,15 @@ class _Slot:
         self.close_after = False
         self.accounted = True     # counted in the server's _active
         self.cancelled = False    # worker-mode stream thread signal
+        # span marks (util.tracing; perf_counter_ns, 0 = not taken):
+        # request whole in the buffer, reply ref seen ready, render
+        # begun; the request id is the carrying actor task's
+        self.t_in = 0
+        self.bytes_in = 0
+        self.rid = None
+        self.t_ready = 0
+        self.polled = None        # refs in the poll set at wake-up
+        self.t_write = 0
 
 
 class _StreamState:
@@ -290,7 +302,8 @@ class _StreamState:
 class _Conn:
     __slots__ = ("sock", "addr", "rbuf", "wbuf", "slots", "cur",
                  "body_need", "closed", "paused_read",
-                 "close_after_write", "registered")
+                 "close_after_write", "registered", "t_recv", "sent",
+                 "marks")
 
     def __init__(self, sock, addr):
         self.sock = sock
@@ -308,6 +321,14 @@ class _Conn:
         self.paused_read = False
         self.close_after_write = False
         self.registered = False
+        self.t_recv = 0     # perf_counter_ns of the last recv
+        self.sent = 0       # bytes handed to the socket so far
+        # (stream position of a response's last byte, slot, bytes,
+        # status), in write order: `_flush` closes the request's spans
+        # once `sent` has passed it
+        # unbounded-ok: one entry per response in wbuf, itself capped
+        # by serve_http_pipeline_max
+        self.marks: deque = deque()
 
 
 class AsyncIngress:
@@ -532,6 +553,7 @@ class AsyncIngress:
         conn.slots.clear()
         conn.rbuf.clear()
         conn.wbuf.clear()
+        conn.marks.clear()
 
     def _uncount(self, slot: _Slot) -> None:
         if slot.accounted:
@@ -563,6 +585,7 @@ class AsyncIngress:
             self._close_conn(conn)
             return
         conn.rbuf += data
+        conn.t_recv = time.perf_counter_ns()
         self._parse(conn)
         self._update_events(conn)
 
@@ -657,7 +680,14 @@ class AsyncIngress:
     # -- request handling ----------------------------------------------
 
     def _handle(self, conn: _Conn, req: _Req, body: bytes) -> None:
+        traced = tracing.enabled()
+        entered = time.perf_counter_ns() if traced else 0
         slot = _Slot(req.keep_alive)
+        if traced:
+            # the root span begins with the recv that completed the
+            # request (`_parse`) and ends in `_flush`
+            slot.t_in = conn.t_recv or entered
+            slot.bytes_in = len(body)
         conn.slots.append(slot)
         self._active += 1
         path = req.target.partition(b"?")[0]
@@ -689,6 +719,7 @@ class AsyncIngress:
         if req.stream:
             self._start_stream(conn, slot, replica_set, args, req.sse)
             return
+        decoded = time.perf_counter_ns() if traced else 0
         try:
             if len(args) == 1:
                 # the batched promise plane — also for undecorated
@@ -703,8 +734,15 @@ class AsyncIngress:
                             _render_error(e, slot.keep_alive))
             return
         slot.ref = ref
+        if traced:
+            # the id exists only now: the span is written afterwards
+            slot.rid = tracing.request_of(ref)
+            tracing.record("serve.ingress.parse", entered, decoded,
+                           slot.rid)
         if self._driver_mode:
             def _cb(_oid, c=conn, s=slot, r=ref):
+                if s.t_in:
+                    s.t_ready = time.perf_counter_ns()
                 self._push(("resp", c, s, r))
 
             slot.cb = _cb
@@ -729,6 +767,9 @@ class AsyncIngress:
             s = slots[0]
             if s.state == _READY:
                 conn.wbuf += s.data
+                if s.t_in:
+                    conn.marks.append((conn.sent + len(conn.wbuf), s,
+                                       len(s.data), int(s.data[9:12])))
                 s.data = b""
                 self._uncount(s)
                 if not s.keep_alive:
@@ -771,12 +812,36 @@ class AsyncIngress:
                 return
             if n:
                 del conn.wbuf[:n]
+                conn.sent += n
+                if conn.marks:
+                    self._close_spans(conn)
         if not conn.wbuf:
             if conn.close_after_write:
                 self._close_conn(conn)
                 return
             self._resume_streams(conn)
         self._update_events(conn)
+
+    @staticmethod
+    def _close_spans(conn: _Conn) -> None:
+        """The responses whose last byte the socket now has: each one's
+        `serve.ingress.write`, `serve.ingress.reply` and root
+        `serve.request` end here."""
+        now = time.perf_counter_ns()
+        marks = conn.marks
+        while marks and marks[0][0] <= conn.sent:
+            _end, slot, nbytes, status = marks.popleft()
+            if slot.t_write:
+                tracing.record("serve.ingress.write", slot.t_write, now,
+                               slot.rid)
+            if slot.t_ready:
+                counts = ({} if slot.polled is None
+                          else {"polled": slot.polled})
+                tracing.record("serve.ingress.reply", slot.t_ready, now,
+                               slot.rid, **counts)
+            tracing.record("serve.request", slot.t_in, now, slot.rid,
+                           status=status, bytes_in=slot.bytes_in,
+                           bytes_out=nbytes)
 
     def _buffered(self, conn: _Conn, slot: _Slot) -> int:
         return len(conn.wbuf) + len(slot.sbuf)
@@ -852,7 +917,8 @@ class AsyncIngress:
         to the full get() machinery."""
         from ray_tpu._private.worker import _LostObjectSignal
         try:
-            value = self._worker._entry_value(ref.id(), entry)
+            with tracing.span("serve.ingress.get", slot.rid):
+                value = self._worker._entry_value(ref.id(), entry)
         except _LostObjectSignal:
             self._finish_unary(conn, slot, ref=ref)
             return
@@ -868,9 +934,12 @@ class AsyncIngress:
         if ref is not None:
             try:
                 # already in the owner's store: returns immediately
-                value = self._worker.get([ref], 30)[0]
+                with tracing.span("serve.ingress.get", slot.rid):
+                    value = self._worker.get([ref], 30)[0]
             except BaseException as e:  # noqa: BLE001 - typed mapping
                 error = e
+        if slot.t_in:
+            slot.t_write = time.perf_counter_ns()
         slot.ref = slot.cb = None
         if error is not None:
             data = _render_error(error, slot.keep_alive)
@@ -1066,14 +1135,18 @@ class AsyncIngress:
             except Exception:  # noqa: BLE001 - runtime tearing down
                 time.sleep(0.1)  # no-deadline: bounded by _shutdown
                 continue
+            seen = time.perf_counter_ns()
             for ref in ready:
                 with self._poll_lock:
                     entry = self._poll_entries.pop(ref.id(), None)
                 if entry is None:
                     continue
                 _ref, conn, slot = entry
+                if slot.t_in:
+                    slot.t_ready, slot.polled = seen, len(refs)
                 try:
-                    value = self._worker.get([ref], 30)[0]
+                    with tracing.span("serve.ingress.get", slot.rid):
+                        value = self._worker.get([ref], 30)[0]
                     self._push(("val", conn, slot, value))
                 except BaseException as e:  # noqa: BLE001 - typed
                     self._push(("err", conn, slot, e))

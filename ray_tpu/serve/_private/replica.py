@@ -44,6 +44,8 @@ from __future__ import annotations
 import contextvars
 import inspect
 
+from ray_tpu.util import tracing
+
 # Per-request model id (model multiplexing); re-exported by the public
 # package — defined HERE so replicas never import the full serve
 # package (controller/router machinery) just to reach one ContextVar.
@@ -285,19 +287,29 @@ class ReplicaActor:
 
     async def handle_request(self, method: str, args: tuple, kwargs: dict,
                              model_id=None, *zc):
-        self._ongoing += 1
-        try:
-            if zc:
-                args = tuple(_rehydrate(a, zc) for a in args)
-                kwargs = {k: _rehydrate(v, zc) for k, v in kwargs.items()}
-            sem = self._admission_sem()
-            if sem is not None:
-                async with sem:
-                    return await self._invoke(method, args, kwargs,
-                                              model_id)
-            return await self._invoke(method, args, kwargs, model_id)
-        finally:
-            self._ongoing -= 1
+        # `ongoing` is the queue length this request found: requests
+        # in this replica on entry, this one left out
+        rid = tracing.current_request()
+        with tracing.span("serve.replica.request", rid,
+                          ongoing=self._ongoing):
+            self._ongoing += 1
+            try:
+                if zc:
+                    args = tuple(_rehydrate(a, zc) for a in args)
+                    kwargs = {k: _rehydrate(v, zc)
+                              for k, v in kwargs.items()}
+                sem = self._admission_sem()
+                if sem is not None:
+                    with tracing.span("serve.replica.admission", rid):
+                        await sem.acquire()
+                    try:
+                        return await self._invoke(method, args, kwargs,
+                                                  model_id)
+                    finally:
+                        sem.release()
+                return await self._invoke(method, args, kwargs, model_id)
+            finally:
+                self._ongoing -= 1
 
     async def handle_request_batch(self, method: str, items: list,
                                    model_id=None, *zc):
@@ -311,22 +323,29 @@ class ReplicaActor:
         remaining in-flight count, the piggybacked queue signal for
         the router's power-of-two-choices (no extra RPC)."""
         n = len(items)
-        self._ongoing += n
-        try:
-            if zc:
-                items = [_rehydrate(v, zc) for v in items]
-            sem = self._admission_sem()
-            if sem is not None:
-                async with sem:
+        rid = tracing.current_request()
+        with tracing.span("serve.replica.request", rid,
+                          ongoing=self._ongoing, items=n):
+            self._ongoing += n
+            try:
+                if zc:
+                    items = [_rehydrate(v, zc) for v in items]
+                sem = self._admission_sem()
+                if sem is not None:
+                    with tracing.span("serve.replica.admission", rid):
+                        await sem.acquire()
+                    try:
+                        results, mixed = await self._run_batch(
+                            method, items, model_id)
+                    finally:
+                        sem.release()
+                else:
                     results, mixed = await self._run_batch(method, items,
                                                            model_id)
-            else:
-                results, mixed = await self._run_batch(method, items,
-                                                       model_id)
-            depth = max(0, self._ongoing - n)
-            return ("be" if mixed else "b", results, depth)
-        finally:
-            self._ongoing -= n
+                depth = max(0, self._ongoing - n)
+                return ("be" if mixed else "b", results, depth)
+            finally:
+                self._ongoing -= n
 
     def _batch_target(self, method: str):
         """(inner, owner) of a ``@serve.batch`` body reachable as
@@ -351,29 +370,32 @@ class ReplicaActor:
         token = (_multiplex_ctx.set(model_id)
                  if model_id is not None else None)
         try:
-            if inner is not None:
-                try:
-                    res = run_vectorized_sync(inner, owner, items)
-                    if inspect.isawaitable(res):
-                        res = await res
-                    return check_batch_result(res, len(items)), False
-                except Exception as e:  # noqa: BLE001 - per-item fanned
-                    return [(1, e) for _ in items], True
-            # undecorated method reached by a batched dispatch: run
-            # per item, isolating each item's error
-            out, mixed = [], False
-            for value in items:
-                try:
-                    r = fn(value)
-                    if inspect.isawaitable(r):
-                        r = await r
-                    out.append((0, r))
-                except Exception as e:  # noqa: BLE001 - per-item fanned
-                    out.append((1, e))
-                    mixed = True
-            if mixed:
-                return out, True
-            return [r for _s, r in out], False
+            with tracing.span("serve.replica.invoke",
+                              tracing.current_request(),
+                              items=len(items)):
+                if inner is not None:
+                    try:
+                        res = run_vectorized_sync(inner, owner, items)
+                        if inspect.isawaitable(res):
+                            res = await res
+                        return check_batch_result(res, len(items)), False
+                    except Exception as e:  # noqa: BLE001 - per-item fanned
+                        return [(1, e) for _ in items], True
+                # undecorated method reached by a batched dispatch: run
+                # per item, isolating each item's error
+                out, mixed = [], False
+                for value in items:
+                    try:
+                        r = fn(value)
+                        if inspect.isawaitable(r):
+                            r = await r
+                        out.append((0, r))
+                    except Exception as e:  # noqa: BLE001 - per-item fanned
+                        out.append((1, e))
+                        mixed = True
+                if mixed:
+                    return out, True
+                return [r for _s, r in out], False
         finally:
             if token is not None:
                 _multiplex_ctx.reset(token)
@@ -384,10 +406,12 @@ class ReplicaActor:
         token = (_multiplex_ctx.set(model_id)
                  if model_id is not None else None)
         try:
-            result = fn(*args, **kwargs)
-            if inspect.isawaitable(result):
-                result = await result
-            return result
+            with tracing.span("serve.replica.invoke",
+                              tracing.current_request()):
+                result = fn(*args, **kwargs)
+                if inspect.isawaitable(result):
+                    result = await result
+                return result
         finally:
             if token is not None:
                 _multiplex_ctx.reset(token)

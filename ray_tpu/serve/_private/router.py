@@ -45,6 +45,7 @@ from ray_tpu.exceptions import (
     WorkerCrashedError,
 )
 from ray_tpu.serve._private.replica import _batch_defaults
+from ray_tpu.util import tracing
 
 logger = logging.getLogger(__name__)
 
@@ -266,11 +267,15 @@ class ReplicaSet:
     # -- assignment (direct path) --------------------------------------
 
     def begin(self, model_id: Optional[str] = None,
-              nowait: bool = False):
+              nowait: bool = False, span=tracing.NO_SPAN):
         """Pick a replica (pow-2 / sticky-model) and charge one
         in-flight request to it. Returns the replica handle; the caller
         MUST balance with ``end(id(handle))`` when the request
-        resolves (``assign`` wires this automatically).
+        resolves (``assign`` wires this automatically). ``span`` (the
+        caller's ``serve.router.assign``) is given the counts taken
+        under the lock: ``inflight``, this router's charge on the
+        chosen replica before this request, and ``parked``, the
+        admission waits taken here.
 
         ``nowait=True`` (the async HTTP ingress): instead of parking
         the calling thread when every candidate is at its
@@ -279,6 +284,7 @@ class ReplicaSet:
         the event loop maps it to 503 + Retry-After and stays
         non-blocking."""
         deadline = None
+        parked = 0
         with self._lock:
             while True:
                 if not self._replicas:
@@ -319,6 +325,7 @@ class ReplicaSet:
                                     + self.ADMISSION_TIMEOUT_S)
                     remaining = deadline - time.monotonic()
                     self._waiters += 1
+                    parked += 1
                     try:
                         if remaining <= 0 or not self._slot_free.wait(
                                 timeout=remaining):
@@ -339,8 +346,9 @@ class ReplicaSet:
                     self._model_routes[model_id] = id(chosen)
                 if chosen is None:
                     chosen = self._pow2_locked(pool)
-                self._inflight[id(chosen)] = \
-                    self._inflight.get(id(chosen), 0) + 1
+                charged = self._inflight.get(id(chosen), 0)
+                span.note(inflight=charged, parked=parked)
+                self._inflight[id(chosen)] = charged + 1
                 self.total_assigned += 1
                 return chosen
 
@@ -375,34 +383,40 @@ class ReplicaSet:
         ``BackpressureError`` (retryable) when the deployment's queue
         bound is hit — always with ``nowait=True`` (event-loop
         callers), which sheds instead of parking in admission."""
-        self._check_shed()
-        serve_stats.incr("requests")
-        bcfg = self.batch_cfg.get(method)
-        if (bcfg is not None and not stream and self._driver_side
-                and len(args) == 1 and not kwargs):
-            return self._assign_batched(method, args[0], model_id, bcfg)
-        chosen = self.begin(model_id, nowait=nowait)
-        if stream:
-            gen = chosen.handle_request_streaming.options(
-                num_returns="streaming").remote(method, args, kwargs,
-                                                model_id)
-            self._watch(gen.completed(), id(chosen))
-            return gen
-        zc_refs = []
-        if args:
-            promoted = []
-            for i, a in enumerate(args):
-                value, ref = _zero_copy_promote(a)
-                if ref is not None:
-                    value.i = len(zc_refs)
-                    zc_refs.append(ref)
-                promoted.append(value)
-            if zc_refs:
-                args = tuple(promoted)
-        ref = chosen.handle_request.remote(method, args, kwargs,
-                                           model_id, *zc_refs)
-        self._watch(ref, id(chosen))
-        return ref
+        with tracing.span("serve.router.assign") as span:
+            self._check_shed()
+            serve_stats.incr("requests")
+            bcfg = self.batch_cfg.get(method)
+            if (bcfg is not None and not stream and self._driver_side
+                    and len(args) == 1 and not kwargs):
+                ref = self._assign_batched(method, args[0], model_id, bcfg)
+                span.note(request=tracing.request_of(ref))
+                return ref
+            chosen = self.begin(model_id, nowait=nowait, span=span)
+            if stream:
+                reply = chosen.handle_request_streaming.options(
+                    num_returns="streaming").remote(method, args, kwargs,
+                                                    model_id)
+                span.note(request=reply._task_id.hex())
+                done = reply.completed()
+            else:
+                zc_refs = []
+                if args:
+                    promoted = []
+                    for i, a in enumerate(args):
+                        value, ref = _zero_copy_promote(a)
+                        if ref is not None:
+                            value.i = len(zc_refs)
+                            zc_refs.append(ref)
+                        promoted.append(value)
+                    if zc_refs:
+                        args = tuple(promoted)
+                reply = done = chosen.handle_request.remote(
+                    method, args, kwargs, model_id, *zc_refs)
+                span.note(request=tracing.request_of(reply))
+        # the span ends with the submit; the completion hook is after it
+        self._watch(done, id(chosen))
+        return reply
 
     def _watch(self, ref: ObjectRef, replica_key: int) -> None:
         """Decrement in-flight when the result lands. On the driver the
@@ -432,16 +446,23 @@ class ReplicaSet:
         immediately; raises ``BackpressureError`` on shed. In a
         non-driver process (worker-hosted proxy) there is no promise
         plane: falls back to a non-blocking direct dispatch."""
-        self._check_shed()
-        serve_stats.incr("requests")
-        bcfg = self.batch_cfg.get(method) or {}
-        if not self._driver_side:
-            chosen = self.begin(model_id, nowait=True)
+        with tracing.span("serve.router.assign") as span:
+            self._check_shed()
+            serve_stats.incr("requests")
+            bcfg = self.batch_cfg.get(method) or {}
+            if self._driver_side:
+                # parked, not dispatched: the span ends here and the
+                # flusher's `serve.router.flush` carries the dispatch
+                ref = self._assign_batched(method, value, model_id, bcfg)
+                span.note(request=tracing.request_of(ref))
+                return ref
+            chosen = self.begin(model_id, nowait=True, span=span)
             ref = chosen.handle_request.remote(method, (value,), {},
                                                model_id)
-            self._watch(ref, id(chosen))
-            return ref
-        return self._assign_batched(method, value, model_id, bcfg)
+            span.note(request=tracing.request_of(ref))
+        # the span ends with the submit; the completion hook is after it
+        self._watch(ref, id(chosen))
+        return ref
 
     def _assign_batched(self, method: str, value, model_id, bcfg):
         """Reserve a promise ref, park the request in its gather
@@ -620,8 +641,11 @@ class ReplicaSet:
         serve_stats.incr("batches")
         serve_stats.incr("batch_items", len(items))
         try:
-            bref = chosen.handle_request_batch.remote(
-                method, items, model_id, *zc_refs)
+            with tracing.span("serve.router.flush",
+                              items=len(items)) as span:
+                bref = chosen.handle_request_batch.remote(
+                    method, items, model_id, *zc_refs)
+                span.note(request=tracing.request_of(bref))
         except Exception as e:  # noqa: BLE001 - fanned per request
             self._settle_failed(key, reqs, id(chosen), e)
             return

@@ -14,10 +14,13 @@ import pickle
 import shutil
 import tempfile
 import threading
+import time
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional
 
+from ray_tpu._private import durable
 from ray_tpu.train.checkpoint import Checkpoint
+from ray_tpu.util import tracing
 
 
 class StopTrial(Exception):
@@ -108,50 +111,55 @@ def report(metrics: Dict[str, Any],
     ctx = get_context()
     if not ctx.report_dir:
         return  # local mode: nothing to deliver
-    if _stop_requested(ctx):
-        raise StopTrial()
-    ctx._report_seq += 1
-    payload: Dict[str, Any] = {"metrics": dict(metrics), "rank": ctx.rank,
-                               "seq": ctx._report_seq}
-    if checkpoint is not None:
-        # persist into the trial dir so it outlives the worker
-        dst = os.path.join(ctx.trial_dir,
-                           f"checkpoint_{ctx._report_seq:06d}_r{ctx.rank}")
-        if os.path.abspath(checkpoint.path) != os.path.abspath(dst):
-            shutil.copytree(checkpoint.path, dst, dirs_exist_ok=True)
-        payload["checkpoint_path"] = dst
-    # crash-atomic (shared durable helper): the trainer's drain loop
-    # must never observe a torn report file under the final name
-    from ray_tpu._private import durable
-    name = f"report_{ctx.rank:04d}_{ctx._report_seq:08d}.pkl"
-    durable.atomic_pickle(os.path.join(ctx.report_dir, name), payload)
-    # AFTER the report lands: an elastic re-form happens at a
-    # RANK-AGREED boundary — the RESIZE file carries the target report
-    # seq (stamped ahead of every rank's progress), and each rank
-    # stops at exactly that seq. Stopping at "whenever I next see the
-    # file" would let ranks leave at different steps and wedge the
-    # survivors' next collective.
-    resize_path = os.path.join(ctx.report_dir, "RESIZE")
-    if os.path.exists(resize_path):
-        try:
-            with open(resize_path) as f:
-                target_seq = int(f.read().strip() or 0)
-        except (OSError, ValueError):
-            target_seq = 0
-        if ctx._report_seq >= target_seq:
-            raise ElasticResize()
-    if ctx.sync_reports:
-        # Block until the controller acks this report (or tells us to
-        # stop). Bounded wait so a dead controller can't wedge the trial.
-        import time
-        ack = os.path.join(ctx.report_dir, name + ".ack")
-        deadline = time.monotonic() + 30.0
-        while time.monotonic() < deadline:
-            if _stop_requested(ctx):
-                raise StopTrial()
-            if os.path.exists(ack):
-                return
-            time.sleep(0.005)
+    with tracing.span("train.report") as span:
+        if _stop_requested(ctx):
+            raise StopTrial()
+        ctx._report_seq += 1
+        span.note(seq=ctx._report_seq)
+        payload: Dict[str, Any] = {"metrics": dict(metrics),
+                                   "rank": ctx.rank,
+                                   "seq": ctx._report_seq}
+        if checkpoint is not None:
+            # persist into the trial dir so it outlives the worker
+            dst = os.path.join(
+                ctx.trial_dir,
+                f"checkpoint_{ctx._report_seq:06d}_r{ctx.rank}")
+            if os.path.abspath(checkpoint.path) != os.path.abspath(dst):
+                shutil.copytree(checkpoint.path, dst, dirs_exist_ok=True)
+            payload["checkpoint_path"] = dst
+        # crash-atomic (shared durable helper): the trainer's drain loop
+        # must never observe a torn report file under the final name
+        name = f"report_{ctx.rank:04d}_{ctx._report_seq:08d}.pkl"
+        durable.atomic_pickle(os.path.join(ctx.report_dir, name), payload,
+                              span="train.report.write")
+        # AFTER the report lands: an elastic re-form happens at a
+        # RANK-AGREED boundary — the RESIZE file carries the target
+        # report seq (stamped ahead of every rank's progress), and each
+        # rank stops at exactly that seq. Stopping at "whenever I next
+        # see the file" would let ranks leave at different steps and
+        # wedge the survivors' next collective.
+        resize_path = os.path.join(ctx.report_dir, "RESIZE")
+        if os.path.exists(resize_path):
+            try:
+                with open(resize_path) as f:
+                    target_seq = int(f.read().strip() or 0)
+            except (OSError, ValueError):
+                target_seq = 0
+            if ctx._report_seq >= target_seq:
+                raise ElasticResize()
+        if ctx.sync_reports:
+            # Block until the controller acks this report (or tells us
+            # to stop). Bounded wait so a dead controller can't wedge
+            # the trial.
+            with tracing.span("train.report.ack_wait"):
+                ack = os.path.join(ctx.report_dir, name + ".ack")
+                deadline = time.monotonic() + 30.0
+                while time.monotonic() < deadline:
+                    if _stop_requested(ctx):
+                        raise StopTrial()
+                    if os.path.exists(ack):
+                        return
+                    time.sleep(0.005)
 
 
 def get_dataset_shard(name: str = "train"):

@@ -36,6 +36,7 @@ from ray_tpu.train._session import (
     shutdown_session,
 )
 from ray_tpu.train.checkpoint import Checkpoint
+from ray_tpu.util import tracing
 
 
 @dataclasses.dataclass
@@ -400,6 +401,8 @@ class DataParallelTrainer:
         # rank-major sorted, so a fresh rank-0 report sorts before
         # already-counted rank>=1 files and a count index would skip it
         # forever (losing rank-0 metrics/checkpoints).
+        entered = time.perf_counter_ns()
+        read = 0
         files = sorted(glob.glob(os.path.join(report_dir, "report_*.pkl")))
         for path in files:
             name = os.path.basename(path)
@@ -411,10 +414,16 @@ class DataParallelTrainer:
             except (EOFError, pickle.UnpicklingError, FileNotFoundError):
                 continue
             seen.add(name)
+            read += 1
             if payload["rank"] == 0:
                 history.append(payload["metrics"])
             if "checkpoint_path" in payload and payload["rank"] == 0:
                 latest_ckpt = Checkpoint(payload["checkpoint_path"])
+        if read:
+            # only a call that found something: the poll runs five
+            # times a second whatever the loop does
+            tracing.record("train.drain_reports", entered,
+                           time.perf_counter_ns(), files=read)
         return seen, latest_ckpt
 
     def _shard_datasets(self, n: int) -> List[Dict[str, List]]:
