@@ -44,9 +44,10 @@ class TransformerConfig:
     dtype: Any = jnp.bfloat16    # compute dtype
     remat: bool = True
     # Pallas flash attention (ops/flash_attention.py): fused blockwise
-    # kernel, no S×S in HBM — the TPU fast path (1.8x over dense at
-    # seq 4096 on v5e). Off by default: CPU tests run the interpret
-    # path, which is slower than dense XLA.
+    # kernel, no S×S in HBM — the TPU path both benchmark cells take
+    # (what it costs a step and how far it stands from its roofline:
+    # PERF.md §5). Off by default: CPU tests run the interpret path,
+    # which is slower than dense XLA.
     use_flash: bool = False
     use_moe: bool = False
     n_experts: int = 8

@@ -4,7 +4,10 @@ The reference has no attention kernels — attention enters via torch in
 workloads hosted on it [SURVEY.md §2.5]. Here the fused blockwise
 kernel is first-class: the MXU does the two matmuls per block, online
 softmax keeps running (max, normalizer) so the S×S score matrix never
-materializes in HBM (HBM bandwidth is the bottleneck, not FLOPs).
+materializes in HBM. Which of FLOPs and HBM bytes bounds a call
+depends on its shape (``benchmark/costs.py::flash_cost``; at S 4096
+it is the FLOPs), and how far the kernels stand from that bound is
+measured, not stated here: PERF.md §5.
 
 Forward is the Pallas kernel (grid over [batch×heads, query blocks],
 KV streamed through VMEM in blocks, saving only (O, LSE) residuals);
@@ -12,6 +15,19 @@ backward is a Pallas FlashAttention-2 backward — blockwise dq/dk/dv
 recomputed from (O, LSE), so no S×S probability matrix ever touches
 HBM in either direction. Gradients are exact (grad-checked against the
 dense reference in tests/test_attention.py, on real TPU lowering too).
+
+Arithmetic: every matmul takes its operands in the type the caller
+gave (the MXU's own for bf16) and accumulates in float32. Q·Kᵀ and
+dO·Vᵀ lose nothing by that (a product of two bf16 values is exact in
+float32); P and dS are rounded to the input type before their
+products, as the dense path rounds its probabilities to the value
+type. Softmax statistics, LSE, delta and the accumulators are float32
+whatever the input; float32 inputs keep float32 products throughout.
+
+Block sizes come from the shape (``_choose_blocks``) unless the caller
+names them. Every tile builds its mask: building it only on the tiles
+that cross the diagonal or the true length measured slower (PERF.md
+§6, PR 26).
 
 TPU alignment (Mosaic): dynamic VMEM loads must sit at provably
 8-aligned rows and block shapes must tile to (8, 128), so sequences
@@ -27,7 +43,7 @@ Layout everywhere: [B, S, N, H].
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -54,31 +70,119 @@ def mha_reference(q, k, v, *, causal: bool = True,
     return jnp.einsum("bnqk,bknh->bqnh", probs.astype(v.dtype), v)
 
 
-def _pad_seq(x, block: int):
-    """Pad axis 1 ([BN, S, H]) up to a multiple of ``block``."""
-    pad = (-x.shape[1]) % block
-    if pad == 0:
-        return x
-    return jnp.pad(x, ((0, 0), (0, pad), (0, 0)))
-
-
 _LANE = 128
 
 
-def _pad_head(x):
-    """Pad the head dim ([BN, S, H]) to a lane multiple: Mosaic slices
-    inside the kernel must be 128-aligned along lanes. Zero lanes are
-    inert — q·kᵀ and p·v are unchanged, and their output/grad columns
-    are zero (sliced away)."""
-    pad = (-x.shape[2]) % _LANE
-    if pad == 0:
-        return x
-    return jnp.pad(x, ((0, 0), (0, 0), (0, pad)))
+# --------------------------------------------------------------------------
+# Block sizes
+# --------------------------------------------------------------------------
+
+# What a kernel may hold in VMEM: Mosaic scopes 16 MiB to a kernel on a
+# v5e unless told otherwise, and the budget leaves a quarter of that to
+# what ``_vmem_bytes`` does not count (spills, relayouts, semaphores).
+_VMEM_BUDGET = 12 * 2 ** 20
+# No tile side beyond this: the diagonal's tiles are computed whole, so
+# a causal call does (n + 1) / n of its work at n blocks a side, and
+# past 512 that cost more than the longer tiles gave in all three
+# kernels (PERF.md §6, PR 26: the block table).
+_MAX_BLOCK = 512
+
+
+class _Blocks(NamedTuple):
+    """(block_q, block_k) of the kernels whose grid runs over query
+    blocks (forward, dq) and of the one whose grid runs over KV blocks
+    (dk/dv), and the padded lengths they all divide."""
+    by_q: Tuple[int, int]
+    by_kv: Tuple[int, int]
+    sqp: int
+    skp: int
+
+
+def _round_up(x: int, m: int) -> int:
+    return -(-x // m) * m
+
+
+def _vmem_bytes(block: int, tile: int, whole: int, hp: int,
+                itemsize: int) -> int:
+    """VMEM one grid step needs at ``block`` rows a grid block and
+    ``tile`` rows a loop tile: two operands of ``whole`` rows and at
+    most four blocked operands and results, each double-buffered by the
+    pipeline, two float32 accumulators, and three float32 score tiles
+    live at once (s or p, dp, ds)."""
+    return (2 * 2 * whole * hp * itemsize + 4 * 2 * block * hp * itemsize
+            + 2 * block * hp * 4 + 3 * block * tile * 4)
+
+
+def _choose_blocks(s_q: int, s_k: int, hp: int, itemsize: int) -> _Blocks:
+    """Block sizes from the shape. Sequences pad to a lane multiple and
+    no further; a kernel takes the largest multiples of 128 that divide
+    the padded lengths, stay at or under ``_MAX_BLOCK`` and fit
+    ``_VMEM_BUDGET``, the loop's tile giving way before the grid's
+    block. A sequence too long for its whole-length operands to fit
+    gets 128 × 128 and the compiler's own verdict."""
+    sqp, skp = _round_up(s_q, _LANE), _round_up(s_k, _LANE)
+
+    def pick(grid_len, loop_len):
+        sizes = [[b for b in range(min(n, _MAX_BLOCK), 0, -_LANE)
+                  if n % b == 0] for n in (grid_len, loop_len)]
+        for block in sizes[0]:
+            for tile in sizes[1]:
+                if _vmem_bytes(block, tile, loop_len, hp,
+                               itemsize) <= _VMEM_BUDGET:
+                    return block, tile
+        return _LANE, _LANE
+
+    return _Blocks(pick(sqp, skp), pick(skp, sqp)[::-1], sqp, skp)
+
+
+def _blocks_for(s_q: int, s_k: int, hp: int, itemsize: int,
+                block_q: Optional[int], block_k: Optional[int]) -> _Blocks:
+    """The chosen blocks, with a size the caller named taken as given
+    by every kernel (and the sequence padded to it)."""
+    chosen = _choose_blocks(s_q, s_k, hp, itemsize)
+    pairs = [(block_q or bq, block_k or bk)
+             for bq, bk in (chosen.by_q, chosen.by_kv)]
+    return _Blocks(*pairs,
+                   sqp=_round_up(s_q, block_q) if block_q else chosen.sqp,
+                   skp=_round_up(s_k, block_k) if block_k else chosen.skp)
 
 
 # --------------------------------------------------------------------------
 # Pallas forward kernel
 # --------------------------------------------------------------------------
+
+_NT = (((1,), (1,)), ((), ()))      # a · bᵀ
+_NN = (((1,), (0,)), ((), ()))      # a · b
+_MASKED = -1e30
+
+
+def _dot(a, b, dims):
+    """Operands as they are, float32 accumulation."""
+    return jax.lax.dot_general(a, b, dimension_numbers=dims,
+                               preferred_element_type=jnp.float32)
+
+
+def _visible(q0, k0, shape, q_axis: int, causal: bool,
+             true_sk: Optional[int]):
+    """Which entries of a score tile count: keys inside the true length
+    (``true_sk``; None where padded keys need no mask) and, if causal,
+    at or before their query. ``q_axis`` is the tile's query axis."""
+    q_pos = q0 + jax.lax.broadcasted_iota(jnp.int32, shape, q_axis)
+    k_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_axis)
+    mask = None if true_sk is None else k_pos < true_sk
+    if causal:
+        mask = q_pos >= k_pos if mask is None else mask & (q_pos >= k_pos)
+    return mask
+
+
+def _kv_tiles(qi, block_q: int, block_k: int, seq_k: int, causal: bool):
+    """KV tiles query block ``qi`` loops over: all of them, or if
+    causal those that reach the diagonal."""
+    n_kv = seq_k // block_k
+    if not causal:
+        return n_kv
+    return jnp.minimum(n_kv, pl.cdiv((qi + 1) * block_q, block_k))
+
 
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *,
                       causal: bool, sm_scale: float, block_k: int,
@@ -87,54 +191,34 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *,
     # o_ref: [block_q, H]; lse_ref: [1, block_q].
     # ``true_sk`` masks KV rows that exist only as block padding.
     block_q, head_dim = q_ref.shape
-    seq_k = k_ref.shape[0]
     qi = pl.program_id(1)
+    q = q_ref[:]
 
-    q = q_ref[:].astype(jnp.float32) * sm_scale
-    q_pos = qi * block_q + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0)
-
-    n_kv = seq_k // block_k
-
-    def body(j, carry):
+    def tile(j, carry):
         o, m, l = carry
         start = pl.multiple_of(j * block_k, block_k)
         k_blk = k_ref[pl.ds(start, block_k), :]
         v_blk = v_ref[pl.ds(start, block_k), :]
-        s = jax.lax.dot_general(
-            q, k_blk.astype(jnp.float32),
-            dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)      # [block_q, block_k]
-        k_pos = j * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
-        mask = k_pos < true_sk
-        if causal:
-            mask = mask & (q_pos >= k_pos)
-        s = jnp.where(mask, s, -1e30)
-        m_new = jnp.maximum(m, jnp.max(s, axis=1))
-        p = jnp.exp(s - m_new[:, None])
-        p = jnp.where(mask, p, 0.0)
+        s = _dot(q, k_blk, _NT) * sm_scale          # [block_q, block_k]
+        s = jnp.where(_visible(qi * block_q, j * block_k, s.shape, 0,
+                               causal, true_sk), s, _MASKED)
+        # Key 0 is visible to every query, so m is finite from the
+        # first tile on and a masked score's exp is exactly 0.
+        m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
         alpha = jnp.exp(m - m_new)
-        l_new = l * alpha + jnp.sum(p, axis=1)
-        pv = jax.lax.dot_general(
-            p, v_blk.astype(jnp.float32),
-            dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        o_new = o * alpha[:, None] + pv
+        l_new = l * alpha + jnp.sum(p, axis=1, keepdims=True)
+        o_new = o * alpha + _dot(p.astype(v_blk.dtype), v_blk, _NN)
         return o_new, m_new, l_new
 
-    o = jnp.zeros((block_q, head_dim), jnp.float32)
-    m = jnp.full((block_q,), -1e30, jnp.float32)
-    l = jnp.zeros((block_q,), jnp.float32)
-    if causal:
-        # only blocks at or before the diagonal contribute
-        n_iter = jnp.minimum(n_kv, pl.cdiv((qi + 1) * block_q, block_k))
-    else:
-        n_iter = n_kv
-    o, m, l = jax.lax.fori_loop(0, n_iter, body, (o, m, l))
+    o, m, l = jax.lax.fori_loop(
+        0, _kv_tiles(qi, block_q, block_k, k_ref.shape[0], causal), tile,
+        (jnp.zeros((block_q, head_dim), jnp.float32),
+         jnp.full((block_q, 1), _MASKED, jnp.float32),
+         jnp.zeros((block_q, 1), jnp.float32)))
     l_safe = jnp.maximum(l, 1e-30)
-    o_ref[:] = (o / l_safe[:, None]).astype(o_ref.dtype)
-    lse_ref[0, :] = m + jnp.log(l_safe)
+    o_ref[:] = (o / l_safe).astype(o_ref.dtype)
+    lse_ref[0, :] = (m + jnp.log(l_safe))[:, 0]
 
 
 def _check_blocks(block_q: int, block_k: int, sqp: int,
@@ -157,18 +241,28 @@ def _check_blocks(block_q: int, block_k: int, sqp: int,
             f"the whole padded sequence {sqp}) for TPU lowering")
 
 
+def _fold(x, seq: int):
+    """[B, S, N, H] -> [B·N, seq, H padded to lanes]: batch and heads
+    fold into the grid, the sequence pads to ``seq`` (masked by the
+    true lengths inside the kernels)."""
+    b, s, n, h = x.shape
+    x = x.transpose(0, 2, 1, 3).reshape(b * n, s, h)
+    return jnp.pad(x, ((0, 0), (0, seq - s), (0, _round_up(h, _LANE) - h)))
+
+
+def _unfold(x, like):
+    """The inverse of ``_fold`` for an array shaped like ``like``."""
+    b, s, n, h = like.shape
+    return x[:, :s, :h].reshape(b, n, s, h).transpose(0, 2, 1, 3)
+
+
 def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret):
     b, s_q, n, h = q.shape
     s_k = k.shape[1]
-    # fold batch and heads into the grid; [BN, S, H] layout per head;
-    # pad sequences to block multiples (masked by true lengths inside)
-    qt = _pad_head(_pad_seq(
-        q.transpose(0, 2, 1, 3).reshape(b * n, s_q, h), block_q))
-    kt = _pad_head(_pad_seq(
-        k.transpose(0, 2, 1, 3).reshape(b * n, s_k, h), block_k))
-    vt = _pad_head(_pad_seq(
-        v.transpose(0, 2, 1, 3).reshape(b * n, s_k, h), block_k))
-    sqp, skp, hp = qt.shape[1], kt.shape[1], qt.shape[2]
+    hp = _round_up(h, _LANE)
+    blocks = _blocks_for(s_q, s_k, hp, q.dtype.itemsize, block_q, block_k)
+    (block_q, block_k), sqp, skp = blocks.by_q, blocks.sqp, blocks.skp
+    qt, kt, vt = _fold(q, sqp), _fold(k, skp), _fold(v, skp)
     _check_blocks(block_q, block_k, sqp, interpret)
     grid = (b * n, sqp // block_q)
     kernel = functools.partial(_flash_fwd_kernel, causal=causal,
@@ -192,11 +286,10 @@ def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret):
         ],
         interpret=interpret,
     )(qt, kt, vt)
-    out = out[:, :s_q, :h].reshape(b, n, s_q, h).transpose(0, 2, 1, 3)
-    # lse stays PADDED [BN, sqp]: the only consumer (_flash_bwd, same
-    # block sizes) needs it padded anyway — slicing here would just be
-    # re-padded there.
-    return out, lse.reshape(b * n, sqp)
+    # lse stays PADDED [BN, sqp]: the only consumer (_flash_bwd, which
+    # pads to the same lengths) needs it padded anyway — slicing here
+    # would just be re-padded there.
+    return _unfold(out, q), lse.reshape(b * n, sqp)
 
 
 # Pallas BlockSpec blocks carry the leading singleton; squeeze inside.
@@ -222,6 +315,7 @@ _flash_fwd_kernel = _squeeze_kernel(_flash_fwd_kernel)
 #   dV_j = Σ_i P_ij^T dO_i
 #   dS_ij = P_ij ∘ (dO_i V_j^T − D_i) · scale
 #   dQ_i = Σ_j dS_ij K_j ;  dK_j = Σ_i dS_ij^T Q_i
+# The factor ``scale`` of dS is applied once, to the summed dQ and dK.
 
 
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
@@ -229,99 +323,65 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                          block_k: int, true_sk: int):
     # q/do/dq: [block_q, H]; k/v: [S_k_padded, H]; lse/delta: [1, block_q]
     block_q, head_dim = q_ref.shape
-    seq_k = k_ref.shape[0]
     qi = pl.program_id(1)
+    q = q_ref[:]
+    do = do_ref[:]
+    lse = lse_ref[0, :][:, None]
+    delta = delta_ref[0, :][:, None]
 
-    q = q_ref[:].astype(jnp.float32)
-    do = do_ref[:].astype(jnp.float32)
-    lse = lse_ref[0, :]
-    delta = delta_ref[0, :]
-    q_pos = qi * block_q + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0)
-    n_kv = seq_k // block_k
-
-    def body(j, dq):
+    def tile(j, dq):
         start = pl.multiple_of(j * block_k, block_k)
-        k_blk = k_ref[pl.ds(start, block_k), :].astype(jnp.float32)
-        v_blk = v_ref[pl.ds(start, block_k), :].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k_blk, dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale
-        k_pos = j * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
-        mask = k_pos < true_sk
-        if causal:
-            mask = mask & (q_pos >= k_pos)
-        p = jnp.where(mask, jnp.exp(s - lse[:, None]), 0.0)
-        dp = jax.lax.dot_general(
-            do, v_blk, dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds = p * (dp - delta[:, None]) * sm_scale
-        return dq + jax.lax.dot_general(
-            ds, k_blk, dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        k_blk = k_ref[pl.ds(start, block_k), :]
+        v_blk = v_ref[pl.ds(start, block_k), :]
+        p = jnp.exp(_dot(q, k_blk, _NT) * sm_scale - lse)
+        p = jnp.where(_visible(qi * block_q, j * block_k, p.shape, 0,
+                               causal, true_sk), p, 0.0)
+        ds = p * (_dot(do, v_blk, _NT) - delta)
+        return dq + _dot(ds.astype(k_blk.dtype), k_blk, _NN)
 
-    if causal:
-        n_iter = jnp.minimum(n_kv, pl.cdiv((qi + 1) * block_q, block_k))
-    else:
-        n_iter = n_kv
     dq = jax.lax.fori_loop(
-        0, n_iter, body, jnp.zeros((block_q, head_dim), jnp.float32))
-    dq_ref[:] = dq.astype(dq_ref.dtype)
+        0, _kv_tiles(qi, block_q, block_k, k_ref.shape[0], causal), tile,
+        jnp.zeros((block_q, head_dim), jnp.float32))
+    dq_ref[:] = (dq * sm_scale).astype(dq_ref.dtype)
 
 
 def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                           dk_ref, dv_ref, *, causal: bool, sm_scale: float,
-                          block_q: int, true_sq: int):
+                          block_q: int):
     # k/v/dk/dv: [block_k, H]; q/do: [S_q_padded, H]; lse/delta: [1, S_q]
+    # Score tiles are held transposed, [block_k, block_q]: every product
+    # is then a · bᵀ or a · b as the MXU takes them, and lse and delta
+    # broadcast along sublanes as they lie. Padded query rows are zero
+    # rows of q and dO with delta 0 and a finite lse, so they add exact
+    # zeros to dk and dv and need no mask.
     block_k, head_dim = k_ref.shape
-    seq_q = q_ref.shape[0]
+    n_q = q_ref.shape[0] // block_q
     ki = pl.program_id(1)
+    k = k_ref[:]
+    v = v_ref[:]
 
-    k = k_ref[:].astype(jnp.float32)
-    v = v_ref[:].astype(jnp.float32)
-    k_pos = ki * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1)
-    n_q = seq_q // block_q
-
-    def body(i, carry):
+    def tile(i, carry):
         dk, dv = carry
         start = pl.multiple_of(i * block_q, block_q)
-        q_blk = q_ref[pl.ds(start, block_q), :].astype(jnp.float32)
-        do_blk = do_ref[pl.ds(start, block_q), :].astype(jnp.float32)
-        lse_blk = lse_ref[0, pl.ds(start, block_q)]
-        delta_blk = delta_ref[0, pl.ds(start, block_q)]
-        s = jax.lax.dot_general(
-            q_blk, k, dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale
-        q_pos = i * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0)
-        mask = q_pos < true_sq          # padded query rows contribute 0
+        q_blk = q_ref[pl.ds(start, block_q), :]
+        do_blk = do_ref[pl.ds(start, block_q), :]
+        lse_blk = lse_ref[:, pl.ds(start, block_q)]         # [1, block_q]
+        delta_blk = delta_ref[:, pl.ds(start, block_q)]
+        p = jnp.exp(_dot(k, q_blk, _NT) * sm_scale - lse_blk)
         if causal:
-            mask = mask & (q_pos >= k_pos)
-        p = jnp.where(mask, jnp.exp(s - lse_blk[:, None]), 0.0)
-        dv = dv + jax.lax.dot_general(
-            p, do_blk, dimension_numbers=(((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(
-            do_blk, v, dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_blk[:, None]) * sm_scale
-        dk = dk + jax.lax.dot_general(
-            ds, q_blk, dimension_numbers=(((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+            p = jnp.where(_visible(i * block_q, ki * block_k, p.shape, 1,
+                                   True, None), p, 0.0)
+        dv = dv + _dot(p.astype(do_blk.dtype), do_blk, _NN)
+        ds = p * (_dot(v, do_blk, _NT) - delta_blk)
+        dk = dk + _dot(ds.astype(q_blk.dtype), q_blk, _NN)
         return dk, dv
 
-    if causal:
-        # first query block whose rows can attend to this kv block
-        i0 = (ki * block_k) // block_q
-    else:
-        i0 = 0
+    # causal: query blocks before this one cannot see this kv block
     dk, dv = jax.lax.fori_loop(
-        i0, n_q, body,
+        (ki * block_k) // block_q if causal else 0, n_q, tile,
         (jnp.zeros((block_k, head_dim), jnp.float32),
          jnp.zeros((block_k, head_dim), jnp.float32)))
-    dk_ref[:] = dk.astype(dk_ref.dtype)
+    dk_ref[:] = (dk * sm_scale).astype(dk_ref.dtype)
     dv_ref[:] = dv.astype(dv_ref.dtype)
 
 
@@ -333,29 +393,24 @@ def _flash_bwd(q, k, v, out, lse, g, causal, sm_scale, block_q, block_k,
                interpret):
     b, s_q, n, h = q.shape
     s_k = k.shape[1]
-    qt = _pad_head(_pad_seq(
-        q.transpose(0, 2, 1, 3).reshape(b * n, s_q, h), block_q))
-    kt = _pad_head(_pad_seq(
-        k.transpose(0, 2, 1, 3).reshape(b * n, s_k, h), block_k))
-    vt = _pad_head(_pad_seq(
-        v.transpose(0, 2, 1, 3).reshape(b * n, s_k, h), block_k))
-    dot = _pad_head(_pad_seq(
-        g.transpose(0, 2, 1, 3).reshape(b * n, s_q, h), block_q))
-    ot = _pad_head(_pad_seq(
-        out.transpose(0, 2, 1, 3).reshape(b * n, s_q, h), block_q))
-    sqp, skp, hp = qt.shape[1], kt.shape[1], qt.shape[2]
-    _check_blocks(block_q, block_k, sqp, interpret)
+    hp = _round_up(h, _LANE)
+    blocks = _blocks_for(s_q, s_k, hp, q.dtype.itemsize, block_q, block_k)
+    sqp, skp = blocks.sqp, blocks.skp
+    qt, kt, vt = _fold(q, sqp), _fold(k, skp), _fold(v, skp)
+    dot, ot = _fold(g, sqp), _fold(out, sqp)
     # delta = rowsum(dO ∘ O): cheap elementwise outside the kernels
     delta = jnp.sum(dot.astype(jnp.float32) * ot.astype(jnp.float32),
                     axis=-1)                              # [BN, S_q_pad]
     # Singleton middle axis: TPU blocks over the last two dims must
     # divide (8, 128) or equal the array dims — (1, block) over a 2-D
     # (BN, S) array does neither. lse arrives already padded to sqp
-    # (same block sizes as the forward).
+    # (the forward pads to the same lengths).
     assert lse.shape == (b * n, sqp), (lse.shape, sqp)
     lse3 = lse.reshape(b * n, 1, sqp)
     delta3 = delta.reshape(b * n, 1, sqp)
 
+    block_q, block_k = blocks.by_q
+    _check_blocks(block_q, block_k, sqp, interpret)
     dq_kernel = functools.partial(_flash_bwd_dq_kernel, causal=causal,
                                   sm_scale=sm_scale, block_k=block_k,
                                   true_sk=s_k)
@@ -375,9 +430,10 @@ def _flash_bwd(q, k, v, out, lse, g, causal, sm_scale, block_q, block_k,
         interpret=interpret,
     )(qt, kt, vt, dot, lse3, delta3)
 
+    block_q, block_k = blocks.by_kv
+    _check_blocks(block_q, block_k, sqp, interpret)
     dkv_kernel = functools.partial(_flash_bwd_dkv_kernel, causal=causal,
-                                   sm_scale=sm_scale, block_q=block_q,
-                                   true_sq=s_q)
+                                   sm_scale=sm_scale, block_q=block_q)
     dk, dv = pl.pallas_call(
         dkv_kernel,
         grid=(b * n, skp // block_k),
@@ -400,9 +456,7 @@ def _flash_bwd(q, k, v, out, lse, g, causal, sm_scale, block_q, block_k,
         interpret=interpret,
     )(qt, kt, vt, dot, lse3, delta3)
 
-    unfold = lambda x, s: x[:, :s, :h].reshape(b, n, s, h).transpose(
-        0, 2, 1, 3)
-    return unfold(dq, s_q), unfold(dk, s_k), unfold(dv, s_k)
+    return _unfold(dq, q), _unfold(dk, k), _unfold(dv, v)
 
 
 def _for_lowering_platform(fn, interpret: Optional[bool], *arrays):
@@ -423,9 +477,11 @@ def _for_lowering_platform(fn, interpret: Optional[bool], *arrays):
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def flash_attention(q, k, v, causal: bool = True,
                     sm_scale: Optional[float] = None,
-                    block_q: int = 128, block_k: int = 128,
+                    block_q: Optional[int] = None,
+                    block_k: Optional[int] = None,
                     interpret: Optional[bool] = None):
-    """Fused attention. [B,S,N,H] -> [B,S,N,H]."""
+    """Fused attention. [B,S,N,H] -> [B,S,N,H]. Block sizes left at
+    None are chosen from the shape (``_choose_blocks``)."""
     out, _res = _flash_vjp_fwd(q, k, v, causal, sm_scale, block_q,
                                block_k, interpret)
     return out

@@ -51,10 +51,18 @@ def _assert_kernel_not_interpreter(lowered):
     lowered.compile()
 
 
-@pytest.mark.parametrize("block", [128, 512])
-@pytest.mark.parametrize("direction", ["forward", "backward"])
-def test_flash_compiles_for_tpu(v5e, block, direction):
-    x = jax.ShapeDtypeStruct((B, S, N, H), jnp.bfloat16,
+# the benchmark's cells: the train cell's attention with gradients and a
+# serve prompt padded to 128, both at the blocks the kernel chooses
+TRAIN_CELL, SERVE_CELL = (1, 4096, 32, 128), (1, 128, 32, 128)
+
+
+@pytest.mark.parametrize("shape,block,direction", [
+    ((B, S, N, H), 128, "forward"), ((B, S, N, H), 128, "backward"),
+    ((B, S, N, H), 512, "forward"), ((B, S, N, H), 512, "backward"),
+    (TRAIN_CELL, None, "forward"), (TRAIN_CELL, None, "backward"),
+    (SERVE_CELL, None, "forward")])
+def test_flash_compiles_for_tpu(v5e, shape, block, direction):
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16,
                              sharding=SingleDeviceSharding(v5e[0]))
 
     def attend(q, k, v):        # interpret=None: chosen by the lowering
