@@ -6,8 +6,11 @@ pull in optax.
 """
 
 from ray_tpu.models.transformer import (  # noqa: F401
+    LayerSpec,
     TransformerConfig,
+    config_from_hf,
     forward,
+    forward_with_stats,
     init_params,
     loss_fn,
     param_specs,
@@ -23,7 +26,8 @@ from ray_tpu.models.vit import (  # noqa: F401
 _TRAINING = ("TrainState", "init_state", "make_optimizer",
              "make_train_step", "state_specs")
 
-__all__ = ["TransformerConfig", "ViTConfig", "forward", "init_params",
+__all__ = ["LayerSpec", "TransformerConfig", "ViTConfig", "config_from_hf",
+           "forward", "forward_with_stats", "init_params",
            "init_vit_params", "loss_fn", "param_specs", "vit_forward",
            "vit_loss_fn", "vit_param_specs", *_TRAINING]
 

@@ -43,7 +43,7 @@ Layout everywhere: [B, S, N, H].
 from __future__ import annotations
 
 import functools
-from typing import NamedTuple, Optional, Tuple
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -53,9 +53,13 @@ from jax.experimental import pallas as pl
 
 def mha_reference(q, k, v, *, causal: bool = True,
                   sm_scale: Optional[float] = None,
-                  q_offset: int = 0, kv_offset: int = 0):
+                  q_offset: int = 0, kv_offset: int = 0,
+                  window: Optional[int] = None):
     """Dense attention, [B,S,N,H]. Offsets shift absolute positions for
-    cross-shard causal masking (ring/ulysses callers)."""
+    cross-shard causal masking (ring/ulysses callers). ``window`` (with
+    ``causal``) hides keys ``window`` or more positions before their
+    query."""
+    _check_window(window, causal)
     if sm_scale is None:
         sm_scale = 1.0 / np.sqrt(q.shape[-1])
     logits = jnp.einsum("bqnh,bknh->bnqk", q, k,
@@ -65,9 +69,18 @@ def mha_reference(q, k, v, *, causal: bool = True,
         q_pos = q_offset + jnp.arange(s_q)
         k_pos = kv_offset + jnp.arange(s_k)
         mask = q_pos[:, None] >= k_pos[None, :]
+        if window is not None:
+            mask &= q_pos[:, None] - k_pos[None, :] < window
         logits = jnp.where(mask[None, None], logits, -1e30)
     probs = jax.nn.softmax(logits, axis=-1)
     return jnp.einsum("bnqk,bknh->bqnh", probs.astype(v.dtype), v)
+
+
+def _check_window(window: Optional[int], causal: bool) -> None:
+    if window is not None and (not causal or window < 1):
+        raise ValueError(f"a sliding window needs causal attention and "
+                         f"at least one visible key, got window={window} "
+                         f"causal={causal}")
 
 
 _LANE = 128
@@ -77,9 +90,10 @@ _LANE = 128
 # Block sizes
 # --------------------------------------------------------------------------
 
-# What a kernel may hold in VMEM: Mosaic scopes 16 MiB to a kernel on a
-# v5e unless told otherwise, and the budget leaves a quarter of that to
-# what ``_vmem_bytes`` does not count (spills, relayouts, semaphores).
+# What a kernel may hold in VMEM without asking: Mosaic scopes 16 MiB to
+# a kernel on a v5e unless told otherwise, and the budget leaves a
+# quarter of that to what ``_vmem_bytes`` does not count (spills,
+# relayouts, semaphores).
 _VMEM_BUDGET = 12 * 2 ** 20
 # No tile side beyond this: the diagonal's tiles are computed whole, so
 # a causal call does (n + 1) / n of its work at n blocks a side, and
@@ -89,11 +103,10 @@ _MAX_BLOCK = 512
 
 
 class _Blocks(NamedTuple):
-    """(block_q, block_k) of the kernels whose grid runs over query
-    blocks (forward, dq) and of the one whose grid runs over KV blocks
-    (dk/dv), and the padded lengths they all divide."""
-    by_q: Tuple[int, int]
-    by_kv: Tuple[int, int]
+    """The blocks all three kernels take, and the padded lengths they
+    divide."""
+    block_q: int
+    block_k: int
     sqp: int
     skp: int
 
@@ -113,36 +126,39 @@ def _vmem_bytes(block: int, tile: int, whole: int, hp: int,
             + 2 * block * hp * 4 + 3 * block * tile * 4)
 
 
-def _choose_blocks(s_q: int, s_k: int, hp: int, itemsize: int) -> _Blocks:
+def _choose_blocks(s_q: int, s_k: int) -> _Blocks:
     """Block sizes from the shape. Sequences pad to a lane multiple and
-    no further; a kernel takes the largest multiples of 128 that divide
-    the padded lengths, stay at or under ``_MAX_BLOCK`` and fit
-    ``_VMEM_BUDGET``, the loop's tile giving way before the grid's
-    block. A sequence too long for its whole-length operands to fit
-    gets 128 × 128 and the compiler's own verdict."""
+    no further; every kernel takes the largest multiples of 128 that
+    divide the padded lengths and stay at or under ``_MAX_BLOCK``.
+    Where that needs more VMEM than Mosaic scopes to a kernel by
+    default, the kernels ask for it (``_compiler_params``) and keep
+    their blocks: a 256-row tile measured 30 % slower than a 512-row
+    one (PERF.md §6, PR 26), a larger scope costs nothing."""
     sqp, skp = _round_up(s_q, _LANE), _round_up(s_k, _LANE)
-
-    def pick(grid_len, loop_len):
-        sizes = [[b for b in range(min(n, _MAX_BLOCK), 0, -_LANE)
-                  if n % b == 0] for n in (grid_len, loop_len)]
-        for block in sizes[0]:
-            for tile in sizes[1]:
-                if _vmem_bytes(block, tile, loop_len, hp,
-                               itemsize) <= _VMEM_BUDGET:
-                    return block, tile
-        return _LANE, _LANE
-
-    return _Blocks(pick(sqp, skp), pick(skp, sqp)[::-1], sqp, skp)
+    bq, bk = (max(b for b in range(_LANE, min(n, _MAX_BLOCK) + 1, _LANE)
+                  if n % b == 0) for n in (sqp, skp))
+    return _Blocks(bq, bk, sqp, skp)
 
 
-def _blocks_for(s_q: int, s_k: int, hp: int, itemsize: int,
-                block_q: Optional[int], block_k: Optional[int]) -> _Blocks:
+def _compiler_params(block: int, tile: int, whole: int, hp: int,
+                     itemsize: int) -> dict:
+    """Nothing where the kernel fits ``_VMEM_BUDGET`` (bf16 up to
+    6,144 x 128 at the largest blocks); past it, a VMEM limit a third
+    over what ``_vmem_bytes`` counts. A v5e core has 128 MiB."""
+    need = _vmem_bytes(block, tile, whole, hp, itemsize)
+    if need <= _VMEM_BUDGET:
+        return {}
+    from jax.experimental.pallas import tpu as pltpu
+    return {"compiler_params": pltpu.CompilerParams(
+        vmem_limit_bytes=need * 4 // 3)}
+
+
+def _blocks_for(s_q: int, s_k: int, block_q: Optional[int],
+                block_k: Optional[int]) -> _Blocks:
     """The chosen blocks, with a size the caller named taken as given
     by every kernel (and the sequence padded to it)."""
-    chosen = _choose_blocks(s_q, s_k, hp, itemsize)
-    pairs = [(block_q or bq, block_k or bk)
-             for bq, bk in (chosen.by_q, chosen.by_kv)]
-    return _Blocks(*pairs,
+    chosen = _choose_blocks(s_q, s_k)
+    return _Blocks(block_q or chosen.block_q, block_k or chosen.block_k,
                    sqp=_round_up(s_q, block_q) if block_q else chosen.sqp,
                    skp=_round_up(s_k, block_k) if block_k else chosen.skp)
 
@@ -163,30 +179,53 @@ def _dot(a, b, dims):
 
 
 def _visible(q0, k0, shape, q_axis: int, causal: bool,
-             true_sk: Optional[int]):
+             true_sk: Optional[int], window: Optional[int] = None):
     """Which entries of a score tile count: keys inside the true length
     (``true_sk``; None where padded keys need no mask) and, if causal,
-    at or before their query. ``q_axis`` is the tile's query axis."""
+    at or before their query and, under a window, fewer than ``window``
+    positions before it. ``q_axis`` is the tile's query axis."""
     q_pos = q0 + jax.lax.broadcasted_iota(jnp.int32, shape, q_axis)
     k_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_axis)
     mask = None if true_sk is None else k_pos < true_sk
     if causal:
         mask = q_pos >= k_pos if mask is None else mask & (q_pos >= k_pos)
+    if window is not None:
+        mask = mask & (q_pos - k_pos < window)
     return mask
 
 
-def _kv_tiles(qi, block_q: int, block_k: int, seq_k: int, causal: bool):
-    """KV tiles query block ``qi`` loops over: all of them, or if
-    causal those that reach the diagonal."""
+def _kv_tiles(qi, block_q: int, block_k: int, seq_k: int, causal: bool,
+              window: Optional[int] = None):
+    """(first, end) of the KV tiles query block ``qi`` loops over: all
+    of them, if causal those up to the diagonal, and under a window
+    only from the tile that holds the oldest key the block's first
+    query sees. Tiles outside are skipped, not masked."""
     n_kv = seq_k // block_k
     if not causal:
-        return n_kv
-    return jnp.minimum(n_kv, pl.cdiv((qi + 1) * block_q, block_k))
+        return 0, n_kv
+    end = jnp.minimum(n_kv, pl.cdiv((qi + 1) * block_q, block_k))
+    if window is None:
+        return 0, end
+    return jnp.maximum(0, qi * block_q - (window - 1)) // block_k, end
+
+
+def _q_tiles(ki, block_q: int, block_k: int, seq_q: int, causal: bool,
+             window: Optional[int] = None):
+    """(first, end) of the query tiles KV block ``ki`` loops over: the
+    mirror of ``_kv_tiles``."""
+    n_q = seq_q // block_q
+    if not causal:
+        return 0, n_q
+    first = (ki * block_k) // block_q
+    if window is None:
+        return first, n_q
+    return first, jnp.minimum(
+        n_q, pl.cdiv((ki + 1) * block_k - 1 + window, block_q))
 
 
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *,
                       causal: bool, sm_scale: float, block_k: int,
-                      true_sk: int):
+                      true_sk: int, window: Optional[int]):
     # q_ref: [block_q, H]; k_ref/v_ref: [S_k_padded, H];
     # o_ref: [block_q, H]; lse_ref: [1, block_q].
     # ``true_sk`` masks KV rows that exist only as block padding.
@@ -201,9 +240,13 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *,
         v_blk = v_ref[pl.ds(start, block_k), :]
         s = _dot(q, k_blk, _NT) * sm_scale          # [block_q, block_k]
         s = jnp.where(_visible(qi * block_q, j * block_k, s.shape, 0,
-                               causal, true_sk), s, _MASKED)
+                               causal, true_sk, window), s, _MASKED)
         # Key 0 is visible to every query, so m is finite from the
-        # first tile on and a masked score's exp is exactly 0.
+        # first tile on and a masked score's exp is exactly 0. Under a
+        # window a later row of the block may see nothing in the first
+        # tiles: it gathers exp(0) there, and alpha is exactly 0 at its
+        # first visible key (the diagonal at the latest), which wipes
+        # that.
         m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
         p = jnp.exp(s - m_new)
         alpha = jnp.exp(m - m_new)
@@ -212,7 +255,8 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *,
         return o_new, m_new, l_new
 
     o, m, l = jax.lax.fori_loop(
-        0, _kv_tiles(qi, block_q, block_k, k_ref.shape[0], causal), tile,
+        *_kv_tiles(qi, block_q, block_k, k_ref.shape[0], causal, window),
+        tile,
         (jnp.zeros((block_q, head_dim), jnp.float32),
          jnp.full((block_q, 1), _MASKED, jnp.float32),
          jnp.zeros((block_q, 1), jnp.float32)))
@@ -256,18 +300,18 @@ def _unfold(x, like):
     return x[:, :s, :h].reshape(b, n, s, h).transpose(0, 2, 1, 3)
 
 
-def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret):
+def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, window,
+               interpret):
     b, s_q, n, h = q.shape
     s_k = k.shape[1]
     hp = _round_up(h, _LANE)
-    blocks = _blocks_for(s_q, s_k, hp, q.dtype.itemsize, block_q, block_k)
-    (block_q, block_k), sqp, skp = blocks.by_q, blocks.sqp, blocks.skp
+    block_q, block_k, sqp, skp = _blocks_for(s_q, s_k, block_q, block_k)
     qt, kt, vt = _fold(q, sqp), _fold(k, skp), _fold(v, skp)
     _check_blocks(block_q, block_k, sqp, interpret)
     grid = (b * n, sqp // block_q)
     kernel = functools.partial(_flash_fwd_kernel, causal=causal,
                                sm_scale=sm_scale, block_k=block_k,
-                               true_sk=s_k)
+                               true_sk=s_k, window=window)
     out, lse = pl.pallas_call(
         kernel,
         grid=grid,
@@ -285,6 +329,7 @@ def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret):
             jax.ShapeDtypeStruct((b * n, 1, sqp), jnp.float32),
         ],
         interpret=interpret,
+        **_compiler_params(block_q, block_k, skp, hp, q.dtype.itemsize),
     )(qt, kt, vt)
     # lse stays PADDED [BN, sqp]: the only consumer (_flash_bwd, which
     # pads to the same lengths) needs it padded anyway — slicing here
@@ -320,7 +365,8 @@ _flash_fwd_kernel = _squeeze_kernel(_flash_fwd_kernel)
 
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                          dq_ref, *, causal: bool, sm_scale: float,
-                         block_k: int, true_sk: int):
+                         block_k: int, true_sk: int,
+                         window: Optional[int]):
     # q/do/dq: [block_q, H]; k/v: [S_k_padded, H]; lse/delta: [1, block_q]
     block_q, head_dim = q_ref.shape
     qi = pl.program_id(1)
@@ -335,19 +381,20 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         v_blk = v_ref[pl.ds(start, block_k), :]
         p = jnp.exp(_dot(q, k_blk, _NT) * sm_scale - lse)
         p = jnp.where(_visible(qi * block_q, j * block_k, p.shape, 0,
-                               causal, true_sk), p, 0.0)
+                               causal, true_sk, window), p, 0.0)
         ds = p * (_dot(do, v_blk, _NT) - delta)
         return dq + _dot(ds.astype(k_blk.dtype), k_blk, _NN)
 
     dq = jax.lax.fori_loop(
-        0, _kv_tiles(qi, block_q, block_k, k_ref.shape[0], causal), tile,
+        *_kv_tiles(qi, block_q, block_k, k_ref.shape[0], causal, window),
+        tile,
         jnp.zeros((block_q, head_dim), jnp.float32))
     dq_ref[:] = (dq * sm_scale).astype(dq_ref.dtype)
 
 
 def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                           dk_ref, dv_ref, *, causal: bool, sm_scale: float,
-                          block_q: int):
+                          block_q: int, window: Optional[int]):
     # k/v/dk/dv: [block_k, H]; q/do: [S_q_padded, H]; lse/delta: [1, S_q]
     # Score tiles are held transposed, [block_k, block_q]: every product
     # is then a · bᵀ or a · b as the MXU takes them, and lse and delta
@@ -355,7 +402,6 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     # rows of q and dO with delta 0 and a finite lse, so they add exact
     # zeros to dk and dv and need no mask.
     block_k, head_dim = k_ref.shape
-    n_q = q_ref.shape[0] // block_q
     ki = pl.program_id(1)
     k = k_ref[:]
     v = v_ref[:]
@@ -370,15 +416,17 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         p = jnp.exp(_dot(k, q_blk, _NT) * sm_scale - lse_blk)
         if causal:
             p = jnp.where(_visible(i * block_q, ki * block_k, p.shape, 1,
-                                   True, None), p, 0.0)
+                                   True, None, window), p, 0.0)
         dv = dv + _dot(p.astype(do_blk.dtype), do_blk, _NN)
         ds = p * (_dot(v, do_blk, _NT) - delta_blk)
         dk = dk + _dot(ds.astype(q_blk.dtype), q_blk, _NN)
         return dk, dv
 
-    # causal: query blocks before this one cannot see this kv block
+    # causal: query blocks before this one cannot see this kv block,
+    # nor, under a window, those wholly past it
     dk, dv = jax.lax.fori_loop(
-        (ki * block_k) // block_q if causal else 0, n_q, tile,
+        *_q_tiles(ki, block_q, block_k, q_ref.shape[0], causal, window),
+        tile,
         (jnp.zeros((block_k, head_dim), jnp.float32),
          jnp.zeros((block_k, head_dim), jnp.float32)))
     dk_ref[:] = (dk * sm_scale).astype(dk_ref.dtype)
@@ -390,12 +438,11 @@ _flash_bwd_dkv_kernel = _squeeze_kernel(_flash_bwd_dkv_kernel)
 
 
 def _flash_bwd(q, k, v, out, lse, g, causal, sm_scale, block_q, block_k,
-               interpret):
+               window, interpret):
     b, s_q, n, h = q.shape
     s_k = k.shape[1]
     hp = _round_up(h, _LANE)
-    blocks = _blocks_for(s_q, s_k, hp, q.dtype.itemsize, block_q, block_k)
-    sqp, skp = blocks.sqp, blocks.skp
+    block_q, block_k, sqp, skp = _blocks_for(s_q, s_k, block_q, block_k)
     qt, kt, vt = _fold(q, sqp), _fold(k, skp), _fold(v, skp)
     dot, ot = _fold(g, sqp), _fold(out, sqp)
     # delta = rowsum(dO ∘ O): cheap elementwise outside the kernels
@@ -409,11 +456,10 @@ def _flash_bwd(q, k, v, out, lse, g, causal, sm_scale, block_q, block_k,
     lse3 = lse.reshape(b * n, 1, sqp)
     delta3 = delta.reshape(b * n, 1, sqp)
 
-    block_q, block_k = blocks.by_q
     _check_blocks(block_q, block_k, sqp, interpret)
     dq_kernel = functools.partial(_flash_bwd_dq_kernel, causal=causal,
                                   sm_scale=sm_scale, block_k=block_k,
-                                  true_sk=s_k)
+                                  true_sk=s_k, window=window)
     dq = pl.pallas_call(
         dq_kernel,
         grid=(b * n, sqp // block_q),
@@ -428,12 +474,12 @@ def _flash_bwd(q, k, v, out, lse, g, causal, sm_scale, block_q, block_k,
         out_specs=pl.BlockSpec((1, block_q, hp), lambda bn, i: (bn, i, 0)),
         out_shape=jax.ShapeDtypeStruct((b * n, sqp, hp), q.dtype),
         interpret=interpret,
+        **_compiler_params(block_q, block_k, skp, hp, q.dtype.itemsize),
     )(qt, kt, vt, dot, lse3, delta3)
 
-    block_q, block_k = blocks.by_kv
-    _check_blocks(block_q, block_k, sqp, interpret)
     dkv_kernel = functools.partial(_flash_bwd_dkv_kernel, causal=causal,
-                                   sm_scale=sm_scale, block_q=block_q)
+                                   sm_scale=sm_scale, block_q=block_q,
+                                   window=window)
     dk, dv = pl.pallas_call(
         dkv_kernel,
         grid=(b * n, skp // block_k),
@@ -454,6 +500,7 @@ def _flash_bwd(q, k, v, out, lse, g, causal, sm_scale, block_q, block_k,
             jax.ShapeDtypeStruct((b * n, skp, hp), v.dtype),
         ],
         interpret=interpret,
+        **_compiler_params(block_k, block_q, sqp, hp, q.dtype.itemsize),
     )(qt, kt, vt, dot, lse3, delta3)
 
     return _unfold(dq, q), _unfold(dk, k), _unfold(dv, v)
@@ -474,37 +521,42 @@ def _for_lowering_platform(fn, interpret: Optional[bool], *arrays):
         default=functools.partial(fn, interpret=False))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
 def flash_attention(q, k, v, causal: bool = True,
                     sm_scale: Optional[float] = None,
                     block_q: Optional[int] = None,
                     block_k: Optional[int] = None,
-                    interpret: Optional[bool] = None):
+                    interpret: Optional[bool] = None,
+                    window: Optional[int] = None):
     """Fused attention. [B,S,N,H] -> [B,S,N,H]. Block sizes left at
-    None are chosen from the shape (``_choose_blocks``)."""
+    None are chosen from the shape (``_choose_blocks``). ``window``
+    (static, with ``causal``): key ``j`` counts for query ``i`` iff
+    ``0 <= i - j < window``; the tiles wholly outside are skipped."""
     out, _res = _flash_vjp_fwd(q, k, v, causal, sm_scale, block_q,
-                               block_k, interpret)
+                               block_k, interpret, window)
     return out
 
 
-def _flash_vjp_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret):
+def _flash_vjp_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret,
+                   window):
+    _check_window(window, causal)
     if sm_scale is None:
         sm_scale = 1.0 / np.sqrt(q.shape[-1])
     out, lse = _for_lowering_platform(
         functools.partial(_flash_fwd, causal=causal, sm_scale=sm_scale,
-                          block_q=block_q, block_k=block_k),
+                          block_q=block_q, block_k=block_k, window=window),
         interpret, q, k, v)
     return out, (q, k, v, out, lse)
 
 
-def _flash_vjp_bwd(causal, sm_scale, block_q, block_k, interpret,
+def _flash_vjp_bwd(causal, sm_scale, block_q, block_k, interpret, window,
                    residuals, g):
     q = residuals[0]
     if sm_scale is None:
         sm_scale = 1.0 / np.sqrt(q.shape[-1])
     return _for_lowering_platform(
         functools.partial(_flash_bwd, causal=causal, sm_scale=sm_scale,
-                          block_q=block_q, block_k=block_k),
+                          block_q=block_q, block_k=block_k, window=window),
         interpret, *residuals, g)
 
 

@@ -1,108 +1,320 @@
-"""Mixture-of-Experts with expert parallelism over an ICI axis.
+"""Routed experts: one layer that is told which experts it holds.
 
 ABSENT from the reference (delegated to hosted frameworks,
-SURVEY.md §2.5 "Expert parallel"). TPU-native design: capacity-based
-top-k routing, dense dispatch/combine einsums (MXU-friendly one-hots,
-no gather/scatter), and a pair of ``all_to_all`` exchanges over the
-expert axis — send each token to the device that owns its expert,
-bring the FFN output back. Built as a per-shard function for
-``jax.shard_map``; the expert weight tables shard their leading E dim
-over the same axis.
+SURVEY.md §2.5 "Expert parallel"). The layer routes every token over
+all ``E`` published experts (sigmoid scores in float32, a bias that
+selects and does not weigh, top-k, weights normalised over the chosen
+and scaled), and computes the part of the result that its own experts
+``held = (first, count)`` give. Experts it does not hold add nothing:
+what they would have added lies on the chips that hold them. No
+capacity, no dropped token.
 
-Shapes (per shard): tokens [T, D]; wi/wg [E_local, D, F];
-wo [E_local, F, D]; router [D, E_global].
+Static shapes: the ``T x k`` (token, expert) pairs are sorted by
+expert, the held experts first, so the rows of held expert ``g`` are
+the slice ``[starts[g], ends[g])`` of the sorted rows and everything
+past ``ends[-1]`` belongs to experts held elsewhere. ``gmm`` (a Pallas
+kernel: rows grouped by expert times that expert's matrix) visits
+only the row tiles that hold a held expert's rows; the others are
+skipped, not computed and discarded, and their output rows are left
+unwritten (the combine selects, it does not multiply by zero).
+
+Shapes: tokens ``m [T, D]``; ``router [D, E]``, ``bias [E]``;
+``wg, wi [count, D, F]``, ``wo [count, F, D]``.
+
+Forward only: ``gmm`` has no backward kernel yet and says so by name
+when a gradient is asked of it.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Optional, Sequence, Union
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import Mesh, PartitionSpec as P
 
-AxisName = Union[str, Sequence[str]]
+from ray_tpu.ops.flash_attention import _for_lowering_platform, _round_up
+
+_HIGHEST = lax.Precision.HIGHEST
+# Row tile and column tile of ``gmm`` unless the caller names them. An
+# expert of the benchmark's cell sees 130-260 rows a prefill, so a row
+# tile of 256 keeps most experts to one or two visits; a column tile as
+# wide as the expert (3,072) reads each row tile once, which measured
+# fastest of seven pairs (PERF.md §6, PR 28).
+_TILE_M, _TILE_N = 256, 3072
+# Mosaic's default scope, and the most asked of a v5e core's 128 MiB. A
+# call took 1.56 times what ``_gmm_vmem`` counts (float32 operands, my
+# chip run, PR 28): the limit asked for is 1.75 times the count.
+_VMEM_DEFAULT, _VMEM_MOST, _VMEM_MARGIN = 16 * 2 ** 20, 96 * 2 ** 20, 1.75
 
 
-def _top_k_routing(h, router_w, n_experts: int, top_k: int,
-                   capacity: int):
-    """Returns dispatch [T,E,C] one-hot and combine [T,E,C] weights."""
-    logits = h.astype(jnp.float32) @ router_w.astype(jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)                  # [T,E]
-    top_w, top_i = lax.top_k(probs, top_k)                   # [T,k]
-    top_w = top_w / jnp.sum(top_w, axis=-1, keepdims=True)
-    # expert assignment mask per routing slot: [k,T,E]
-    slot_onehot = jax.nn.one_hot(top_i.T, n_experts, dtype=jnp.float32)
-    # position of each token within its expert's queue, counted over
-    # slots-major order (slot 0 of all tokens first, then slot 1, ...)
-    flat = slot_onehot.reshape(-1, n_experts)                # [k*T,E]
-    pos = jnp.cumsum(flat, axis=0) - flat                    # [k*T,E]
-    pos = pos.reshape(top_k, -1, n_experts)                  # [k,T,E]
-    keep = (pos < capacity) * slot_onehot                    # [k,T,E]
-    pos_onehot = jax.nn.one_hot(
-        jnp.sum(pos * slot_onehot, axis=-1).astype(jnp.int32), capacity,
-        dtype=jnp.float32)                                   # [k,T,C]
-    # dispatch[t,e,c] = 1 iff token t occupies slot c of expert e
-    dispatch = jnp.einsum("kte,ktc->tec", keep, pos_onehot)
-    combine = jnp.einsum("kte,kt,ktc->tec", keep, top_w.T, pos_onehot)
-    return dispatch, combine
+def _gmm_vmem(tile_m: int, tile_n: int, k: int, itemsize: int) -> int:
+    """Row tile, matrix slice and result tile, each double-buffered,
+    and the float32 product."""
+    return (2 * tile_m * k * itemsize + 2 * k * tile_n * itemsize
+            + 2 * tile_m * tile_n * itemsize + 2 * tile_m * tile_n * 4)
 
 
-def moe_mlp_shard(h, router_w, wi, wg, wo, *,
-                  axis_name: Optional[AxisName] = "ep",
-                  n_experts: int, top_k: int = 2,
-                  capacity_factor: float = 2.0):
-    """Per-shard expert-parallel SwiGLU MoE (call inside shard_map).
+# --------------------------------------------------------------------------
+# Routing
+# --------------------------------------------------------------------------
 
-    With ``axis_name=None`` runs single-shard (all experts local) —
-    the same code path, minus the exchanges.
-    """
-    t, d = h.shape
-    ep = lax.axis_size(axis_name) if axis_name is not None else 1
-    e_local = wi.shape[0]
-    assert e_local * ep == n_experts, (e_local, ep, n_experts)
-    capacity = max(1, int(np.ceil(t * top_k / n_experts
-                                  * capacity_factor)))
-    dispatch, combine = _top_k_routing(h, router_w, n_experts, top_k,
-                                       capacity)
-    dt = h.dtype
-    x = jnp.einsum("tec,td->ecd", dispatch.astype(dt), h)     # [E,C,D]
-    if ep > 1:
-        # -> [E_local, ep*C, D]: tokens from every shard for my experts
-        x = lax.all_to_all(x, axis_name, split_axis=0, concat_axis=1,
-                           tiled=True)
-    gate = jax.nn.silu(jnp.einsum("ecd,edf->ecf", x, wg.astype(dt)))
-    up = jnp.einsum("ecd,edf->ecf", x, wi.astype(dt))
-    out = jnp.einsum("ecf,efd->ecd", gate * up, wo.astype(dt))
-    if ep > 1:
-        out = lax.all_to_all(out, axis_name, split_axis=1, concat_axis=0,
-                             tiled=True)                      # [E,C,D]
-    return jnp.einsum("tec,ecd->td", combine.astype(dt), out)
+def route(m, router, bias, *, top_k: int, route_scale: float):
+    """-> (experts [T, k] int32, weights [T, k] float32). Scores are
+    sigmoids in float32 at full precision; ``bias`` moves the selection
+    only; the weights are the chosen scores over their sum, scaled."""
+    scores = jax.nn.sigmoid(jnp.dot(
+        m.astype(jnp.float32), router.astype(jnp.float32),
+        precision=_HIGHEST))
+    _, experts = lax.top_k(scores + bias.astype(jnp.float32), top_k)
+    chosen = jnp.take_along_axis(scores, experts, axis=-1)
+    weights = route_scale * chosen / (
+        jnp.sum(chosen, axis=-1, keepdims=True) + 1e-20)
+    return experts.astype(jnp.int32), weights
 
 
-def make_moe_fn(mesh: Mesh, *, n_experts: int, top_k: int = 2,
-                capacity_factor: float = 2.0,
-                token_axes: AxisName = ("dp", "fsdp", "sp"),
-                ep_axis: Optional[str] = None):
-    """Build a global-arrays MoE fn over the mesh.
+def _sorted_by_expert(experts, n_experts: int, held, rows: int):
+    """The pairs in expert order, the held experts first. -> (token of
+    each sorted row [rows], sorted position of each pair [T, k], starts
+    and ends [count] of the held experts' rows). ``rows`` pads the
+    sorted list (padding sorts last and belongs to no expert)."""
+    first, count = held
+    t, k = experts.shape
+    key = ((experts - first) % n_experts).reshape(-1)
+    key = jnp.pad(key, (0, rows - t * k), constant_values=n_experts)
+    order = jnp.argsort(key, stable=True)
+    position = jnp.zeros((rows,), jnp.int32).at[order].set(
+        jnp.arange(rows, dtype=jnp.int32))[:t * k].reshape(t, k)
+    sorted_key = key[order]
+    groups = jnp.arange(count, dtype=key.dtype)
+    starts = jnp.searchsorted(sorted_key, groups, side="left")
+    ends = jnp.searchsorted(sorted_key, groups, side="right")
+    token = jnp.minimum(order // k, t - 1).astype(jnp.int32)
+    return token, position, starts.astype(jnp.int32), ends.astype(jnp.int32)
 
-    Tokens shard over ``token_axes``; expert tables shard E over the
-    same devices (standard TPU MoE: ep reuses the data axes rather
-    than a dedicated mesh dimension, SURVEY.md §2.5 / mesh.py). Pass
-    ``ep_axis`` to use a dedicated axis instead.
-    """
-    axis = ep_axis if ep_axis is not None else token_axes
-    ep = int(np.prod([mesh.shape[a] for a in
-                      ((axis,) if isinstance(axis, str) else axis)]))
-    body = functools.partial(
-        moe_mlp_shard, axis_name=axis, n_experts=n_experts,
-        top_k=top_k, capacity_factor=capacity_factor)
-    tok_spec = P(token_axes, None)
-    ew_spec = P(token_axes if ep_axis is None else ep_axis, None, None)
+
+# --------------------------------------------------------------------------
+# Grouped matmul
+# --------------------------------------------------------------------------
+
+def _visits(starts, ends, tile_m: int, tiles_m: int):
+    """The (row tile, group) pairs the kernel visits, in order: each
+    group's tiles from the one that holds its first row to the one that
+    holds its last, so a tile that two groups share is visited once for
+    each. ``tiles_m + groups - 1`` bounds their number; the visits past
+    the real ones repeat the last real one (no block changes, nothing
+    is fetched) and are skipped by the kernel."""
+    groups = starts.shape[0]
+    first_tile = starts // tile_m
+    tiles = jnp.where(ends > starts,
+                      (ends - 1) // tile_m - first_tile + 1, 0)
+    upto = jnp.cumsum(tiles)
+    real = upto[-1]
+    v = jnp.arange(tiles_m + groups - 1, dtype=jnp.int32)
+    v = jnp.minimum(v, jnp.maximum(real - 1, 0))
+    group = jnp.minimum(jnp.searchsorted(upto, v, side="right"),
+                        groups - 1).astype(jnp.int32)
+    tile = first_tile[group] + v - (upto - tiles)[group]
+    return (jnp.clip(tile, 0, tiles_m - 1).astype(jnp.int32), group,
+            real.astype(jnp.int32).reshape(1))
+
+
+def _gmm_kernel(tile_ref, group_ref, starts_ref, ends_ref, real_ref,
+                lhs_ref, rhs_ref, out_ref):
+    # lhs_ref [tile_m, K]; rhs_ref [1, K, tile_n]; out_ref [tile_m, tile_n]
+    v = pl.program_id(1)
+
+    @pl.when(v < real_ref[0])
+    def _():
+        group = group_ref[v]
+        tile_m = lhs_ref.shape[0]
+        product = lax.dot_general(
+            lhs_ref[...], rhs_ref[0], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        row = tile_ref[v] * tile_m + lax.broadcasted_iota(
+            jnp.int32, product.shape, 0)
+        mine = (row >= starts_ref[group]) & (row < ends_ref[group])
+        # rows of the tile that are another group's keep what that
+        # group's visit wrote (or will write over whatever is here)
+        out_ref[...] = jnp.where(mine, product.astype(out_ref.dtype),
+                                 out_ref[...])
+
+
+def _gmm_call(lhs, rhs, starts, ends, *, tile_m, tile_n, interpret):
+    m, k = lhs.shape
+    groups, _k, n = rhs.shape
+    tiles_m = m // tile_m
+    tile, group, real = _visits(starts, ends, tile_m, tiles_m)
+    need = _gmm_vmem(tile_m, tile_n, k, lhs.dtype.itemsize)
+    call = pl.pallas_call(
+        _gmm_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5,
+            grid=(n // tile_n, tiles_m + groups - 1),
+            in_specs=[
+                pl.BlockSpec((tile_m, k),
+                             lambda j, v, tile, *_: (tile[v], 0)),
+                pl.BlockSpec((1, k, tile_n),
+                             lambda j, v, tile, group, *_: (group[v], 0, j)),
+            ],
+            out_specs=pl.BlockSpec((tile_m, tile_n),
+                                   lambda j, v, tile, *_: (tile[v], j)),
+        ),
+        out_shape=jax.ShapeDtypeStruct((m, n), lhs.dtype),
+        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=max(_VMEM_DEFAULT,
+                                 int(need * _VMEM_MARGIN))),
+    )
+
+    # A device profile names an operation by its HLO instruction, and a
+    # Mosaic call takes the name of the innermost jitted function round
+    # it: this one's name is the kernel's name in every trace.
+    def moe_gmm(*operands):
+        return call(*operands)
+
+    return jax.jit(moe_gmm)(tile, group, starts, ends, real, lhs, rhs)
+
+
+def _gmm(lhs, rhs, starts, ends, tile_m, tile_n, interpret):
+    m, k = lhs.shape
+    n = rhs.shape[-1]
+    tile_m = min(tile_m or _TILE_M, m)
+    # the widest column tile that divides and fits, else the whole
+    tile_n = tile_n or next(
+        (t for t in range(min(n, _TILE_N), 0, -128) if n % t == 0
+         and _gmm_vmem(tile_m, t, k, lhs.dtype.itemsize) * _VMEM_MARGIN
+         <= _VMEM_MOST), n)
+    if m % tile_m or n % tile_n:
+        raise ValueError(f"gmm: {m} rows and {n} columns are not whole "
+                         f"tiles of ({tile_m}, {tile_n})")
+    return _for_lowering_platform(
+        functools.partial(_gmm_call, tile_m=tile_m, tile_n=tile_n),
+        interpret, lhs, rhs, starts.astype(jnp.int32),
+        ends.astype(jnp.int32))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def gmm(lhs, rhs, starts, ends, tile_m: Optional[int] = None,
+        tile_n: Optional[int] = None, interpret: Optional[bool] = None):
+    """Grouped matmul. ``lhs [M, K]`` holds rows grouped so that group
+    ``g``'s are ``[starts[g], ends[g])`` (disjoint, in rising order);
+    ``rhs [G, K, N]``. -> ``[M, N]`` with ``lhs[r] @ rhs[g]`` in the
+    rows of group ``g``; a row of no group is left unwritten (read it
+    through a select). Operands as given, float32 accumulation. ``M``
+    is a multiple of the row tile and ``N`` of the column tile."""
+    return _gmm(lhs, rhs, starts, ends, tile_m, tile_n, interpret)
+
+
+def _gmm_fwd(lhs, rhs, starts, ends, tile_m, tile_n, interpret):
+    return _gmm(lhs, rhs, starts, ends, tile_m, tile_n, interpret), None
+
+
+def _gmm_bwd(tile_m, tile_n, interpret, residuals, g):
+    raise NotImplementedError(
+        "ray_tpu.ops.moe.gmm has no backward kernel: the routed layer "
+        "runs forward only (serving); training through it needs the "
+        "transposed grouped matmuls (ROADMAP R4)")
+
+
+gmm.defvjp(_gmm_fwd, _gmm_bwd)
+
+
+def gmm_reference(lhs, rhs, starts, ends):
+    """The same by an einsum over the groups; rows of no group are 0."""
+    row = jnp.arange(lhs.shape[0])[None, :]
+    member = (row >= starts[:, None]) & (row < ends[:, None])   # [G, M]
+    return jnp.einsum("gm,mk,gkn->mn", member.astype(lhs.dtype), lhs, rhs,
+                      preferred_element_type=jnp.float32).astype(lhs.dtype)
+
+
+# --------------------------------------------------------------------------
+# The layer
+# --------------------------------------------------------------------------
+
+def routed_experts(m, router, bias, wg, wi, wo, *, held: Tuple, top_k: int,
+                   route_scale: float, tile_m: Optional[int] = None,
+                   tile_n: Optional[int] = None,
+                   interpret: Optional[bool] = None):
+    """``m [T, D]`` -> (the held experts' weighted part of the routed
+    result [T, D], rows routed to each held expert [count] int32).
+    ``held = (first, count)``: this call holds experts ``first ..
+    first + count - 1`` of the ``router.shape[1]`` published ones
+    (``first`` may be traced: an axis index under ``shard_map``)."""
+    t, _d = m.shape
+    n_experts = router.shape[1]
+    count = wg.shape[0]
+    if held[1] != count:
+        raise ValueError(f"held names {held[1]} experts, the weights "
+                         f"hold {count}")
+    experts, weights = route(m, router, bias, top_k=top_k,
+                             route_scale=route_scale)
+    tile_m = min(tile_m or _TILE_M, _round_up(t * top_k, 8))
+    rows = _round_up(t * top_k, tile_m)
+    token, position, starts, ends = _sorted_by_expert(
+        experts, n_experts, held, rows)
+    x = m[token]                                            # [rows, D]
+    dt = m.dtype
+    run = functools.partial(gmm, starts=starts, ends=ends, tile_m=tile_m,
+                            tile_n=tile_n, interpret=interpret)
+    hidden = jax.nn.silu(run(x, wg.astype(dt))) * run(x, wi.astype(dt))
+    y = run(hidden, wo.astype(dt))                          # [rows, D]
+    mine = position < ends[-1]                              # [T, k]
+    part = jnp.where(mine[..., None], y[position].astype(jnp.float32), 0.0)
+    out = jnp.sum(part * weights[..., None], axis=1).astype(dt)
+    return out, ends - starts
+
+
+def route_counts(rows_by_layer, tokens: int, top_k: int) -> dict:
+    """The counts of one forward's ``model.moe.route`` record, from the
+    rows each held expert of each routed layer was given
+    (``rows_by_layer [layers, count]``, on the host)."""
+    rows = np.asarray(rows_by_layer)
+    return {"layers": int(rows.shape[0]),
+            "rows_total": int(rows.shape[0]) * tokens * top_k,
+            "rows_held": int(rows.sum()),
+            "load_max": int(rows.max(initial=0)),
+            "load_mean": float(rows.mean()) if rows.size else 0.0}
+
+
+def record_route(rows_by_layer, tokens: int, top_k: int, start_ns: int,
+                 end_ns: int, request: Optional[str] = None) -> None:
+    """One ``model.moe.route`` record for a forward whose row counts
+    came back with its logits; ``start_ns`` and ``end_ns`` are the
+    forward's own two readings of ``time.perf_counter_ns()``."""
+    from ray_tpu.util import tracing
+    if np.asarray(rows_by_layer).size:
+        tracing.record("model.moe.route", start_ns, end_ns, request,
+                       **route_counts(rows_by_layer, tokens, top_k))
+
+
+# --------------------------------------------------------------------------
+# Experts divided over a mesh axis
+# --------------------------------------------------------------------------
+
+def make_moe_fn(mesh: Mesh, *, top_k: int, route_scale: float,
+                ep_axis: str = "tp"):
+    """The layer with its experts divided over ``ep_axis``: every shard
+    is given all the tokens, computes its own experts' part, and the
+    parts are summed over the axis. -> fn(m, router, bias, wg, wi, wo)
+    -> (routed result [T, D], rows of every expert [E])."""
+    def body(m, router, bias, wg, wi, wo):
+        count = wg.shape[0]
+        part, rows = routed_experts(
+            m, router, bias, wg, wi, wo,
+            held=(lax.axis_index(ep_axis) * count, count), top_k=top_k,
+            route_scale=route_scale)
+        return lax.psum(part.astype(jnp.float32), ep_axis).astype(
+            m.dtype), rows
+
+    whole, split = P(None, None), P(ep_axis, None, None)
     return jax.shard_map(
         body, mesh=mesh,
-        in_specs=(tok_spec, P(None, None), ew_spec, ew_spec, ew_spec),
-        out_specs=tok_spec, check_vma=False), ep
+        in_specs=(whole, whole, P(None), split, split, split),
+        out_specs=(whole, P(ep_axis)), check_vma=False)

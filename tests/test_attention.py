@@ -19,6 +19,7 @@ from ray_tpu.ops.flash_attention import (
     _VMEM_BUDGET,
     _blocks_for,
     _choose_blocks,
+    _compiler_params,
     _vmem_bytes,
 )
 from ray_tpu.parallel.mesh import MeshSpec, make_mesh
@@ -126,6 +127,53 @@ def test_flash_float32_with_chosen_blocks(causal, s):
                                    atol=2e-4, rtol=2e-4)
 
 
+# window under, equal to and over the length; a ragged length; blocks
+# shorter and longer than the window, so that tiles are skipped at both
+# ends of the loop in all three kernels
+@pytest.mark.parametrize("s,window,blocks", [
+    (256, 64, (64, 64)), (256, 100, (64, 64)), (256, 100, (128, 64)),
+    (256, 100, (64, 128)), (256, 256, (64, 64)), (256, 1000, (64, 64)),
+    (160, 48, (64, 64)), (160, 1, (64, 64)), (512, 130, (None, None))])
+def test_flash_window_matches_reference(s, window, blocks):
+    q, k, v = _qkv(b=1, s=s, n=2, h=64)
+    w = jax.random.normal(jax.random.PRNGKey(7), q.shape, jnp.float32)
+    got = _out_and_grads(
+        lambda q, k, v: flash_attention(q, k, v, True, None, *blocks,
+                                        True, window), q, k, v, w)
+    want = _out_and_grads(
+        lambda q, k, v: mha_reference(q, k, v, window=window), q, k, v, w)
+    np.testing.assert_allclose(np.asarray(got[0]), np.asarray(want[0]),
+                               atol=2e-5, rtol=2e-5)
+    for g, r in zip(got[1:], want[1:]):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(r),
+                                   atol=2e-4, rtol=2e-4)
+    if window >= s:     # a window that hides nothing is causal attention
+        full = flash_attention(q, k, v, True, None, *blocks, True)
+        np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(full))
+
+
+def test_window_reference_by_hand():
+    """Query i sees keys i-window+1 .. i: with one-hot values the output
+    row is the softmax's weights, zero outside that band."""
+    s, window = 8, 3
+    q = jnp.zeros((1, s, 1, s))
+    out = np.asarray(mha_reference(q, q, jnp.eye(s)[None, :, None, :],
+                                   window=window))[0, :, 0]
+    for i in range(s):
+        seen = range(max(0, i - window + 1), i + 1)
+        want = np.zeros(s)
+        want[list(seen)] = 1.0 / len(seen)
+        np.testing.assert_allclose(out[i], want, atol=1e-6)
+
+
+def test_window_needs_causal():
+    q, k, v = _qkv(b=1, s=128, n=1, h=64)
+    with pytest.raises(ValueError, match="sliding window"):
+        flash_attention(q, k, v, False, None, 64, 64, True, 32)
+    with pytest.raises(ValueError, match="sliding window"):
+        mha_reference(q, k, v, causal=False, window=32)
+
+
 def test_flash_float32_products_stay_float32():
     """The operand type follows the input: no bf16 appears in a
     float32 call's program, forward or backward."""
@@ -142,27 +190,28 @@ def test_flash_float32_products_stay_float32():
 _CHOOSER_SHAPES = [
     (4096, 4096, 2), (128, 128, 2), (256, 256, 2), (512, 512, 2),
     (96, 96, 4), (160, 160, 4), (4096, 4096, 4), (640, 640, 2),
-    (1408, 1408, 2), (512, 4096, 2), (16384, 16384, 2)]
+    (1408, 1408, 2), (512, 4096, 2), (6144, 6144, 2), (8192, 8192, 2),
+    (16384, 16384, 2)]
 
 
 @pytest.mark.parametrize("s_q,s_k,itemsize", _CHOOSER_SHAPES)
 def test_chosen_blocks(s_q, s_k, itemsize):
     hp = 128
-    blocks = _choose_blocks(s_q, s_k, hp, itemsize)
+    bq, bk, sqp, skp = _choose_blocks(s_q, s_k)
     # padded no further than the 128 x 128 blocks of before
-    assert blocks.sqp == -(-s_q // 128) * 128
-    assert blocks.skp == -(-s_k // 128) * 128
-    for bq, bk in (blocks.by_q, blocks.by_kv):
-        assert bq % 128 == 0 and bk % 128 == 0
-        assert blocks.sqp % bq == 0 and blocks.skp % bk == 0
-    (bq, bk), (bq2, bk2) = blocks.by_q, blocks.by_kv
-    fits = (_vmem_bytes(bq, bk, blocks.skp, hp, itemsize) <= _VMEM_BUDGET
-            and _vmem_bytes(bk2, bq2, blocks.sqp, hp,
-                            itemsize) <= _VMEM_BUDGET)
-    if s_q <= 4096:
-        assert fits
-    else:       # whole-length K/V alone is over the budget: smallest tiles
-        assert blocks.by_q == blocks.by_kv == (128, 128)
+    assert sqp == -(-s_q // 128) * 128 and skp == -(-s_k // 128) * 128
+    # the largest blocks that divide the padded lengths, up to 512, in
+    # every kernel; what they need past the default scope is asked for
+    for n, block in ((sqp, bq), (skp, bk)):
+        assert block % 128 == 0 and n % block == 0
+        assert not any(n % b == 0 for b in range(block + 128, 513, 128))
+    need = _vmem_bytes(bq, bk, skp, hp, itemsize)
+    params = _compiler_params(bq, bk, skp, hp, itemsize)
+    if need <= _VMEM_BUDGET:
+        assert params == {}
+    else:
+        assert params["compiler_params"].vmem_limit_bytes > need
+    assert (need <= _VMEM_BUDGET) == (s_k * itemsize <= 12288)
 
 
 def test_chosen_blocks_at_the_cells_shapes():
@@ -170,16 +219,13 @@ def test_chosen_blocks_at_the_cells_shapes():
     cell's S 4,096 takes what measured fastest there (PERF.md §6, PR
     26) in all three kernels."""
     for s in (128, 256, 512):
-        assert _choose_blocks(s, s, 128, 2).by_q == (s, s)
-    train = _choose_blocks(4096, 4096, 128, 2)
-    assert train.by_q == train.by_kv == (512, 512)
+        assert _choose_blocks(s, s)[:2] == (s, s)
+    assert _choose_blocks(4096, 4096)[:2] == (512, 512)
 
 
 def test_named_blocks_are_taken_as_given():
-    blocks = _blocks_for(160, 160, 128, 4, 64, 64)
-    assert blocks == ((64, 64), (64, 64), 192, 192)
-    blocks = _blocks_for(4096, 4096, 128, 2, 128, None)
-    assert blocks == ((128, 512), (128, 512), 4096, 4096)
+    assert _blocks_for(160, 160, 64, 64) == (64, 64, 192, 192)
+    assert _blocks_for(4096, 4096, 128, None) == (128, 512, 4096, 4096)
 
 
 def test_flash_backward_never_materializes_s2():
@@ -266,27 +312,30 @@ def test_ring_with_tp_axis():
 
 
 def test_moe_expert_parallel_matches_local():
-    """EP dispatch over the mesh == same routing computed on one shard
-    (high capacity so nothing drops)."""
+    """The experts divided over a mesh axis, each shard computing its
+    own experts' part for all the tokens and the parts summed, equal
+    the same layer holding every expert on one shard: no pair dropped,
+    none counted twice."""
     import jax
-    from ray_tpu.ops.moe import moe_mlp_shard, make_moe_fn
+    from ray_tpu.ops.moe import make_moe_fn, routed_experts
 
     rng = np.random.RandomState(0)
-    T, D, F, E, K = 64, 16, 32, 4, 2
+    T, D, F, E, K = 64, 16, 128, 8, 2
     h = jnp.asarray(rng.randn(T, D), jnp.float32)
-    router = jnp.asarray(rng.randn(D, E) * 0.1, jnp.float32)
+    router = jnp.asarray(rng.randn(D, E) * 0.5, jnp.float32)
+    bias = jnp.asarray(rng.randn(E) * 0.2, jnp.float32)
     wi = jnp.asarray(rng.randn(E, D, F) * 0.1, jnp.float32)
     wg = jnp.asarray(rng.randn(E, D, F) * 0.1, jnp.float32)
     wo = jnp.asarray(rng.randn(E, F, D) * 0.1, jnp.float32)
 
-    local = moe_mlp_shard(h, router, wi, wg, wo, axis_name=None,
-                          n_experts=E, top_k=K, capacity_factor=float(E))
+    local, rows = routed_experts(h, router, bias, wg, wi, wo, held=(0, E),
+                                 top_k=K, route_scale=2.0)
+    assert int(rows.sum()) == T * K
 
-    mesh = make_mesh(MeshSpec.auto(4), jax.devices()[:4])
-    moe_fn, ep = make_moe_fn(mesh, n_experts=E, top_k=K,
-                             capacity_factor=float(E))
-    assert ep == 4
+    mesh = make_mesh(MeshSpec(tp=4), jax.devices()[:4])
+    moe_fn = make_moe_fn(mesh, top_k=K, route_scale=2.0)
     with mesh:
-        dist = jax.jit(moe_fn)(h, router, wi, wg, wo)
+        dist, dist_rows = jax.jit(moe_fn)(h, router, bias, wg, wi, wo)
     np.testing.assert_allclose(np.asarray(dist), np.asarray(local),
                                atol=1e-5, rtol=1e-5)
+    np.testing.assert_array_equal(np.asarray(dist_rows), np.asarray(rows))

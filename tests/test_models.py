@@ -1,5 +1,5 @@
 """Flagship transformer: sharded train step, ring-attention parity,
-MoE path, and the driver entry hooks."""
+a routed layer in the pattern, and the driver entry hooks."""
 
 import jax
 import jax.numpy as jnp
@@ -70,15 +70,71 @@ def test_train_step_learns_on_sp_mesh():
     assert np.isfinite(losses).all()
 
 
+def _moe_cfg(**kw):
+    from ray_tpu.models import LayerSpec
+    return _cfg(layers=(LayerSpec(), LayerSpec(experts=True)), n_experts=4,
+                experts_held=(0, 4), expert_top_k=2, d_ff_expert=64,
+                n_shared_experts=1, **kw)
+
+
 def test_moe_forward_and_grads():
-    cfg = _cfg(use_moe=True, n_experts=4, expert_top_k=2)
+    """A routed layer in the pattern: the forward is finite and counts
+    every (token, expert) pair once; a gradient through the routed
+    layer names what is missing (the grouped matmul's backward)."""
+    import pytest
+
+    from ray_tpu.models import forward_with_stats
+    cfg = _moe_cfg()
     tokens = _tokens(b=2, s=32)
     params = init_params(jax.random.PRNGKey(1), cfg)
-    loss, grads = jax.value_and_grad(loss_fn)(params, {"tokens": tokens},
-                                              cfg)
-    assert np.isfinite(float(loss))
-    flat = jax.tree.leaves(grads)
-    assert all(np.isfinite(np.asarray(g)).all() for g in flat)
+    assert "router" in params["blocks"][1] and "wi" in params["blocks"][0]
+    logits, stats = forward_with_stats(params, tokens, cfg)
+    assert np.isfinite(np.asarray(logits)).all()
+    assert stats["moe_rows"].shape == (1, 4)
+    assert int(stats["moe_rows"].sum()) == 2 * 32 * 2
+    with pytest.raises(NotImplementedError, match="gmm has no backward"):
+        jax.grad(loss_fn)(params, {"tokens": tokens}, cfg)
+
+
+def test_mistral_forward_is_bit_equal_to_the_parents():
+    """The default pattern is the dense block repeated: its logits on
+    one seed are those of the forward before the layer pattern (the
+    digest was taken at commit 2e83d2e with this test's inputs)."""
+    import hashlib
+    cfg = _cfg()
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    logits = np.asarray(jax.jit(lambda p, t: forward(p, t, cfg))(
+        params, _tokens()))
+    assert hashlib.sha256(logits.tobytes()).hexdigest() == PARENT_DIGEST
+
+
+PARENT_DIGEST = "f7bf2612c21c94304fa53efc70b2448d84ff1daaa3d8f791102e6e593c598931"
+
+
+def test_layers_of_one_kind_are_traced_once():
+    """The layers that share a spec share one checkpointed function, so
+    jax traces the block once for all of them (a function made anew a
+    layer is traced anew: a 12-layer serve program then took 3.6 s a
+    shape to set up where it had taken 0.7, PERF.md §6 PR 28)."""
+    from ray_tpu.models import LayerSpec
+    from ray_tpu.models.transformer import _attention
+    calls = []
+
+    def counting(q, k, v, **kw):
+        calls.append(kw)
+        return _attention(q, k, v, **kw)
+
+    cfg = _cfg(n_layers=4, remat=True)
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    jax.make_jaxpr(lambda p, t: forward(p, t, cfg, attn_fn=counting))(
+        params, _tokens())
+    assert calls == [{}]
+    del calls[:]
+    mixed = _cfg(n_layers=4, remat=True, layers=(
+        LayerSpec(window=8), LayerSpec(), LayerSpec(window=8), LayerSpec()))
+    jax.make_jaxpr(lambda p, t: forward(p, t, mixed, attn_fn=counting))(
+        params, _tokens())
+    assert calls == [{"window": 8}, {}]
 
 
 def test_graft_entry_hooks():

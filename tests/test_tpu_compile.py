@@ -60,7 +60,9 @@ TRAIN_CELL, SERVE_CELL = (1, 4096, 32, 128), (1, 128, 32, 128)
     ((B, S, N, H), 128, "forward"), ((B, S, N, H), 128, "backward"),
     ((B, S, N, H), 512, "forward"), ((B, S, N, H), 512, "backward"),
     (TRAIN_CELL, None, "forward"), (TRAIN_CELL, None, "backward"),
-    (SERVE_CELL, None, "forward")])
+    (SERVE_CELL, None, "forward"),
+    # whole-length K and V past the default VMEM scope, with gradients
+    ((1, 8192, 8, 128), None, "backward")])
 def test_flash_compiles_for_tpu(v5e, shape, block, direction):
     x = jax.ShapeDtypeStruct(shape, jnp.bfloat16,
                              sharding=SingleDeviceSharding(v5e[0]))
@@ -72,6 +74,40 @@ def test_flash_compiles_for_tpu(v5e, shape, block, direction):
         lambda q, k, v: jnp.sum(attend(q, k, v).astype(jnp.float32)),
         argnums=(0, 1, 2))
     _assert_kernel_not_interpreter(jax.jit(fn).lower(x, x, x))
+
+
+# the Trinity cell's longest prefill: 48 heads of 128 over 16,384
+# positions, whose whole-length K and V are past the default VMEM scope
+LONG_CELL = (1, 16384, 48, 128)
+
+
+@pytest.mark.parametrize("window", [4096, None])
+def test_long_windowed_flash_compiles_for_tpu(v5e, window):
+    x = jax.ShapeDtypeStruct(LONG_CELL, jnp.bfloat16,
+                             sharding=SingleDeviceSharding(v5e[0]))
+    _assert_kernel_not_interpreter(jax.jit(
+        lambda q, k, v: flash_attention(q, k, v, window=window)
+    ).lower(x, x, x))
+
+
+def test_grouped_matmul_compiles_for_tpu(v5e):
+    """The routed layer's kernel at the cell's widths: every (token,
+    expert) row of a 16,384-token prefill, 32 held experts of 3,072 x
+    3,072; its HLO instruction carries the kernel's name, which is how
+    a device profile knows it."""
+    from ray_tpu.ops import gmm
+    one = SingleDeviceSharding(v5e[0])
+    lhs = jax.ShapeDtypeStruct((65536, 3072), jnp.bfloat16, sharding=one)
+    rhs = jax.ShapeDtypeStruct((32, 3072, 3072), jnp.bfloat16, sharding=one)
+    rows = jax.ShapeDtypeStruct((32,), jnp.int32, sharding=one)
+    lowered = jax.jit(gmm).lower(lhs, rhs, rows, rows)
+    # (the visits are found by a search, which is a loop of its own: the
+    # kernel is told from the interpreter by the compiled call)
+    call = next(line for line in lowered.compile().as_text().splitlines()
+                if "tpu_custom_call" in line)
+    operands = call.split(" custom-call(")[1].split("), custom_call_target")[0]
+    assert operands.count("%") == 7
+    assert call.strip().split(" = ")[0].lstrip("ROT %").startswith("moe_gmm")
 
 
 def test_flash_compiles_under_a_mesh(v5e):

@@ -127,6 +127,29 @@ def test_record_takes_both_ends():
     assert got.counts == {"status": 200, "bytes_out": 7}
 
 
+def test_moe_route_record_counts_the_rows_a_forward_routed(recorder_off):
+    """One `model.moe.route` record a forward, from the rows each held
+    expert of each routed layer was given; nothing with the recorder
+    off, nothing for a model without routed layers."""
+    import numpy as np
+
+    from ray_tpu.ops.moe import record_route, route_counts
+    rows = np.array([[3, 0, 5], [1, 1, 2]])     # two layers, three held
+    assert route_counts(rows, tokens=16, top_k=2) == {
+        "layers": 2, "rows_total": 64, "rows_held": 12, "load_max": 5,
+        "load_mean": 2.0}
+    record_route(rows, 16, 2, 10, 20)
+    assert tracing.spans() == []
+    get_config().apply_system_config({"event_log_enabled": True})
+    record_route(np.zeros((0, 3), np.int32), 16, 2, 10, 20)
+    assert tracing.spans() == []
+    record_route(rows, 16, 2, 10, 20, "req-3")
+    (got,) = tracing.spans()
+    assert (got.name, got.start_ns, got.end_ns, got.request) == (
+        "model.moe.route", 10, 20, "req-3")
+    assert got.counts["rows_held"] == 12 and got.counts["load_max"] == 5
+
+
 def test_ring_is_bounded_and_drops_the_oldest():
     for i in range(tracing.RING_SPANS + 10):
         tracing.record("x", i, i + 1)
