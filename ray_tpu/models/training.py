@@ -11,6 +11,8 @@ torch-DDP-over-NCCL path in Ray Train (SURVEY.md §2.5).
 from __future__ import annotations
 
 import functools
+import math
+import time
 from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
@@ -19,8 +21,10 @@ import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ray_tpu.models.transformer import (
-    TransformerConfig, init_params, loss_fn, param_specs)
+    KEEP_LAYER, RematPlan, TransformerConfig, init_params, loss_fn,
+    param_specs, remat_plan)
 from ray_tpu.parallel.mesh import tree_shardings
+from ray_tpu.util import tracing
 
 
 class TrainState(NamedTuple):
@@ -83,28 +87,81 @@ def init_state(key: jax.Array, cfg: TransformerConfig,
     return jax.jit(_init, out_shardings=shardings)(key)
 
 
+def _memory_limit(device) -> Optional[int]:
+    """The device's memory in bytes; None where it reports none (the
+    CPU does not; a described device that is not attached raises)."""
+    try:
+        stats = device.memory_stats()
+    except jax.errors.JaxRuntimeError:
+        return None
+    return (stats or {}).get("bytes_limit")
+
+
+def _plan_remat(cfg: TransformerConfig, tx, mesh: Optional[Mesh],
+                state: TrainState, tokens, flash_here: bool,
+                levels: Optional[Tuple[int, ...]]) -> RematPlan:
+    """``remat_plan`` for the step being traced, from what the trace can
+    see: the batch's shape, the state's bytes on one device (laid out
+    as ``state_specs`` lays it, which is how ``init_state`` places it)
+    and the memory limit of the device the step runs on. A device that
+    reports none (the CPU, a described topology) and attention that is
+    not the flash kernel chosen here (ring, a caller's own) get the
+    plan that recomputes every layer. Leaves one ``train.remat_plan``
+    record in the span ring."""
+    leaves = jax.tree.leaves(state)
+    shapes = [leaf.shape for leaf in leaves]
+    if mesh is not None:
+        shardings = tree_shardings(mesh, state_specs(cfg, tx, state.params))
+        shapes = [h.shard_shape(shape) for h, shape in
+                  zip(jax.tree.leaves(shardings), shapes)]
+    held = sum(math.prod(shape) * leaf.dtype.itemsize
+               for shape, leaf in zip(shapes, leaves))
+    devices = mesh.local_devices if mesh is not None else jax.devices()
+    limit = _memory_limit(devices[0]) if flash_here and devices else None
+    plan = remat_plan(cfg, *tokens.shape, held, limit,
+                      dict(mesh.shape) if mesh is not None else None,
+                      levels)
+    now = time.perf_counter_ns()
+    tracing.record(
+        "train.remat_plan", now, now, layers=cfg.n_layers,
+        layers_kept_whole=plan.levels.count(KEEP_LAYER),
+        kept_bytes=plan.kept_bytes, budget_bytes=plan.budget_bytes,
+        recompute_flops=plan.recompute_flops,
+        layer_forward_flops=plan.layer_forward_flops)
+    return plan
+
+
 def make_train_step(cfg: TransformerConfig,
                     tx: optax.GradientTransformation,
                     mesh: Optional[Mesh] = None,
                     attn_fn=None,
                     donate: bool = True,
-                    batch_keys: Tuple[str, ...] = ("tokens",)):
+                    batch_keys: Tuple[str, ...] = ("tokens",),
+                    remat_levels: Optional[Tuple[int, ...]] = None):
     """Returns jitted (state, batch) -> (state, metrics). ``batch_keys``
     must name every key of the batch dict (e.g. add "loss_mask") so the
     sharding pytree matches. With an sp>1 mesh and no explicit
     ``attn_fn``, attention runs as ring attention over the sp axis;
     with ``cfg.use_flash`` on any other mesh, as the flash kernel on
-    each device's batch/head shard."""
+    each device's batch/head shard. Under ``cfg.remat`` each layer
+    keeps for the backward pass what ``remat_plan`` finds room for when
+    the step is traced; ``remat_levels`` given are taken as they are
+    (tests)."""
+    sp = mesh.shape.get("sp", 1) if mesh is not None else 1
+    flash_here = attn_fn is None and cfg.use_flash and sp == 1
     if attn_fn is None and mesh is not None:
         from ray_tpu.ops import make_attention_fn
-        if mesh.shape.get("sp", 1) > 1:
+        if sp > 1:
             attn_fn = make_attention_fn(mesh, impl="ring")
         elif cfg.use_flash:
             attn_fn = make_attention_fn(mesh, impl="flash")
 
     def train_step(state: TrainState, batch: Dict[str, jax.Array]):
+        levels = _plan_remat(cfg, tx, mesh, state, batch["tokens"],
+                             flash_here, remat_levels).levels \
+            if cfg.remat else None
         loss, grads = jax.value_and_grad(loss_fn)(
-            state.params, batch, cfg, attn_fn)
+            state.params, batch, cfg, attn_fn, levels)
         updates, opt_state = tx.update(grads, state.opt_state, state.params)
         params = optax.apply_updates(state.params, updates)
         metrics = {

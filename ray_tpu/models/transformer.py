@@ -8,7 +8,11 @@ only the mesh. Design notes:
 - compute in bfloat16, params/optimizer in float32 (MXU-friendly); a
   serving replica may hold the params in bfloat16 as published;
 - static shapes everywhere; no data-dependent Python control flow;
-- per-block rematerialisation via ``jax.checkpoint`` (HBM for FLOPs);
+- ``remat=True`` keeps for the backward pass what the device has room
+  for beside the step's state and recomputes the rest, layer by layer
+  (``remat_plan``: arithmetic on shapes and the device's memory limit
+  when the train step is traced; with no limit known, every layer is
+  recomputed under ``jax.checkpoint``); ``remat=False`` keeps all;
 - one ``forward`` over a **layer pattern** (``TransformerConfig.layers``,
   one ``LayerSpec`` a layer): each layer's attention sees every earlier
   key or a sliding window of them, rotates its queries and keys (RoPE)
@@ -35,11 +39,12 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -277,6 +282,17 @@ def param_specs(cfg: TransformerConfig) -> Dict:
 # Forward
 # --------------------------------------------------------------------------
 
+# What a layer under ``remat`` keeps for its backward pass beside its
+# input, by level: nothing (the backward runs the layer's forward
+# again), the attention kernel's results with its inputs (the forward
+# kernel and the q/k/v projections are not run again), those and the
+# gate and up projections' results, everything (the layer is not
+# wrapped). ``remat_plan`` chooses a level a layer.
+_ATTN_KEPT = ("attn_q", "attn_k", "attn_v", "attn_out", "attn_lse")
+REMAT_KEEPS = ((), _ATTN_KEPT, _ATTN_KEPT + ("mlp_gate", "mlp_up"))
+KEEP_LAYER = len(REMAT_KEEPS)
+
+
 def rms_norm(x, scale, eps=1e-6):
     var = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=-1, keepdims=True)
     return (x * jax.lax.rsqrt(var + eps)).astype(x.dtype) * scale.astype(x.dtype)
@@ -312,8 +328,8 @@ def _attention(q, k, v, *, causal: bool = True,
 
 
 def _swiglu(h, wg, wi, wo, dt):
-    gate = jax.nn.silu(h @ wg.astype(dt))
-    up = h @ wi.astype(dt)
+    gate = jax.nn.silu(checkpoint_name(h @ wg.astype(dt), "mlp_gate"))
+    up = checkpoint_name(h @ wi.astype(dt), "mlp_up")
     return (gate * up) @ wo.astype(dt)
 
 
@@ -357,6 +373,10 @@ def _layer_forward(block, x, positions, spec: LayerSpec,
     if spec.rope:
         q = rope(q, positions, cfg.rope_theta)
         k = rope(k, positions, cfg.rope_theta)
+    # named at their KV heads, before the repeat: what a remat plan
+    # keeps of them is a quarter of what the kernel is given
+    q, k, v = (checkpoint_name(a, name) for a, name in
+               ((q, "attn_q"), (k, "attn_k"), (v, "attn_v")))
     # GQA: repeat kv heads up to n_heads.
     rep = cfg.n_heads // cfg.n_kv_heads
     if rep > 1:
@@ -383,10 +403,155 @@ def _layer_forward(block, x, positions, spec: LayerSpec,
     return x + f, rows
 
 
+class RematPlan(NamedTuple):
+    """What each layer keeps for the backward pass, and the counts (one
+    device's bytes and FLOPs) the choice was made from."""
+    levels: Tuple[int, ...]     # a layer: index into REMAT_KEEPS, or KEEP_LAYER
+    kept_bytes: int             # all layers' kept activations
+    budget_bytes: int           # the room they had, at the loss
+    peak_bytes: int             # the step's counted peak, state included
+    recompute_flops: int        # forward FLOPs the backward runs again
+    layer_forward_flops: int    # the mean layer's forward
+
+
+# Room left between the counted peak and the device's limit. The count
+# is the activations and gradients the algorithm holds; the compiled
+# step adds what no shape gives: its own code (90 MB at the benchmark's
+# train cell), the scheduler's choice of what overlaps (the count reads
+# 0.21 GB under to 0.18 GB over libtpu's total at that cell's shapes
+# with 1-3 layers at every level) and the allocator's fragmentation.
+# 4 % of a 16 GB chip is 0.64 GB.
+REMAT_MARGIN = 0.04
+
+
+def _layer_params(cfg: TransformerConfig, spec: LayerSpec) -> int:
+    """The layer's matrices' parameters (norm scales left out)."""
+    d, hd = cfg.d_model, cfg.head_dim
+    attention = d * hd * ((2 + cfg.attn_gate) * cfg.n_heads
+                          + 2 * cfg.n_kv_heads)
+    if not spec.experts:
+        return attention + 3 * d * cfg.d_ff
+    return attention + d * cfg.n_experts + 3 * d * cfg.d_ff_expert * (
+        cfg.experts_held[1] + cfg.n_shared_experts)
+
+
+def _layer_counts(cfg: TransformerConfig, spec: LayerSpec, b: int, s: int,
+                  tp: int):
+    """One layer on one device, ``b`` sequences of ``s`` tokens, widths
+    over ``tp`` shards -> (bytes kept by level, forward FLOPs the
+    backward runs again by level, the forward's FLOPs). Level 0 keeps
+    the layer's input alone; KEEP_LAYER what XLA holds of an unwrapped
+    layer: the input, both norms' results and the sum between them, q
+    and the *repeated* k and v as the kernel was given them, its
+    result and log-sum-exp, gate and up (the silu and the product fuse
+    into the down projection)."""
+    it = jnp.dtype(cfg.dtype).itemsize
+    t, d, hd = b * s, cfg.d_model, cfg.head_dim
+    n, kv, f = (max(w // tp, 1) for w in
+                (cfg.n_heads, cfg.n_kv_heads, cfg.d_ff))
+    wide, heads, lse = t * d * it, t * n * hd * it, b * n * s * 4
+    attn_kept = 2 * heads + 2 * t * kv * hd * it + lse
+    mlp_kept = 2 * t * f * it
+    extra = (cfg.qk_norm * (heads + t * kv * hd * it)
+             + cfg.attn_gate * 2 * heads + cfg.sandwich_norm * 2 * wide)
+    kept = (wide, wide + attn_kept, wide + attn_kept + mlp_kept,
+            4 * wide + 4 * heads + lse + mlp_kept + extra)
+    seen = s / 2 if spec.window is None or spec.window >= s else spec.window
+    qkv = 2 * t * d * hd * ((1 + cfg.attn_gate) * n + 2 * kv)
+    attention = 2 * 2 * t * n * hd * seen
+    out = 2 * t * n * hd * d
+    mlp_in, mlp_out = 2 * 2 * t * d * f, 2 * t * f * d
+    forward = qkv + attention + out + mlp_in + mlp_out
+    # the layer's result is the next layer's kept input, so no level
+    # runs the down projection again
+    again = (forward - mlp_out, out + mlp_in, out, 0)
+    return kept, tuple(int(a) for a in again), int(forward)
+
+
+def remat_plan(cfg: TransformerConfig, batch: int, seq: int,
+               held_bytes: int, limit_bytes: Optional[int],
+               shards: Optional[Dict[str, int]] = None,
+               levels: Optional[Tuple[int, ...]] = None) -> RematPlan:
+    """What ``remat=True`` keeps: as much as the device has room for
+    beside the step's own state, no device touched. ``batch`` and
+    ``seq`` are the whole batch's, ``shards`` the mesh's axis sizes
+    (``mesh.shape``), ``held_bytes`` the parameters' and the optimizer
+    state's bytes on one device, ``limit_bytes`` that device's memory
+    (None: not known, and every layer is recomputed as before).
+    ``levels`` given are counted and not chosen (tests).
+
+    Levels are ranked by the recompute time a byte saves: the flash
+    kernel's results with its inputs (at the benchmark's train cell
+    0.34 TFLOP for 84 MB, a third of it the kernel at half the MXU's
+    rate), then gate and up (0.96 TFLOP for 235 MB), then the rest of
+    the layer (0.14 TFLOP for 151 MB). Every layer is raised to one
+    level before any is raised to the next, the first layers first (at
+    that cell a plan that keeps the first layers whole ran 1.3-4.6 ms a
+    step faster than its mirror image and compiled 0.07-0.09 GB
+    smaller, PERF.md §6 PR 29), so at most two levels are in use and
+    ``forward_with_stats`` traces two functions a kind of layer. A
+    raise is taken while the step's counted peak stays under the limit
+    less ``REMAT_MARGIN``. The peak is the largest of these moments:
+    at the loss, everything kept plus three float32 arrays the size of
+    the logits (the logits, their shifted copy, their gradient); in
+    the backward pass at each layer, what the earlier layers keep,
+    this layer whole (kept or recomputed) and the gradients from this
+    layer on, which wait in the compute type until the clip has seen
+    them all; at the end, every gradient. Layers of routed experts
+    keep nothing (they cannot train yet), and dense S x S attention is
+    not counted: without ``use_flash`` nothing is kept."""
+    shards = shards or {}
+    tp = shards.get("tp", 1)
+    # fsdp shards the parameters; whether it divides the activations is
+    # the partitioner's choice, and at the 12-layer 2x2 share libtpu's
+    # keeps the weights where they lie and all-reduces whole-batch
+    # activations. Counted undivided, which errs to the safe side.
+    b = max(batch // shards.get("dp", 1), 1)
+    s = max(seq // shards.get("sp", 1), 1)
+    it = jnp.dtype(cfg.dtype).itemsize
+    counts = [_layer_counts(cfg, spec, b, s, tp) for spec in cfg.layers]
+    split = shards.get("fsdp", 1) * tp          # ways a matrix is split
+    table = cfg.vocab_size * cfg.d_model * it // split
+    layer_grads = [_layer_params(cfg, spec) * it // split
+                   for spec in cfg.layers]
+    logits = 3 * b * s * (cfg.vocab_size // tp) * 4
+
+    def peak(levels):
+        kept = [c[0][lv] for c, lv in zip(counts, levels)]
+        before = sum(kept)                  # what layers before i keep
+        high = before + logits                          # at the loss
+        grads = table                                   # the unembedding's
+        for i in reversed(range(cfg.n_layers)):
+            before -= kept[i]
+            grads += layer_grads[i]
+            high = max(high, before + counts[i][0][KEEP_LAYER] + grads)
+        return held_bytes + max(high, grads + table + kept[0])
+
+    room = int(limit_bytes * (1 - REMAT_MARGIN)) if limit_bytes else 0
+    if levels is None:
+        levels = [0] * cfg.n_layers
+        raises = [(level, i) for level in range(1, KEEP_LAYER + 1)
+                  for i in range(cfg.n_layers)
+                  if not cfg.layers[i].experts]
+        for level, i in raises if room and cfg.use_flash else ():
+            trial = levels[:i] + [level] + levels[i + 1:]
+            if peak(trial) > room:
+                break
+            levels = trial
+    return RematPlan(
+        levels=tuple(levels),
+        kept_bytes=sum(c[0][lv] for c, lv in zip(counts, levels)),
+        budget_bytes=max(0, room - held_bytes - logits),
+        peak_bytes=peak(levels),
+        recompute_flops=sum(c[1][lv] for c, lv in zip(counts, levels)),
+        layer_forward_flops=sum(c[2] for c in counts) // cfg.n_layers)
+
+
 def forward_with_stats(params, tokens: jax.Array, cfg: TransformerConfig,
                        positions: Optional[jax.Array] = None,
                        attn_fn=None,
-                       logit_positions: Optional[jax.Array] = None):
+                       logit_positions: Optional[jax.Array] = None,
+                       remat_levels: Optional[Tuple[int, ...]] = None):
     """tokens [B, S] int32 -> (logits, stats). Logits are [B, S, V],
     or [B, V] at ``logit_positions [B]`` where given (a prefill needs
     the last position's alone). ``stats["moe_rows"]`` [routed layers,
@@ -394,7 +559,10 @@ def forward_with_stats(params, tokens: jax.Array, cfg: TransformerConfig,
     ``ops.moe.record_route`` turns into the ``model.moe.route`` record
     once they are on the host with the logits. An ``attn_fn`` given
     from outside is called ``attn_fn(q, k, v)``, with ``window=`` on a
-    layer that has one."""
+    layer that has one. ``remat_levels``, one a layer, say what each
+    keeps for a backward pass (``REMAT_KEEPS``; None: nothing under
+    ``cfg.remat``, everything without); a forward alone is the same
+    program at every level."""
     if positions is None:
         positions = jnp.broadcast_to(
             jnp.arange(tokens.shape[1], dtype=jnp.int32)[None, :],
@@ -409,19 +577,32 @@ def forward_with_stats(params, tokens: jax.Array, cfg: TransformerConfig,
                 q, k, v, causal=True, window=window)
         else:
             attn_fn = _attention
-    # One function a kind of layer, shared by the layers of that kind:
-    # jax traces a checkpointed function once for all the layers that
-    # call it (a function made anew for each layer is traced anew, and
-    # a twelve-layer program then takes four times as long to set up).
+    # One function a kind of layer and level, shared by the layers of
+    # that kind and level: jax traces a checkpointed function once for
+    # all the layers that call it (a function made anew for each layer
+    # is traced anew, and a twelve-layer program then takes four times
+    # as long to set up).
+    if remat_levels is None:
+        remat_levels = (0 if cfg.remat else KEEP_LAYER,) * cfg.n_layers
     layer_fns, moe_rows = {}, []
-    for block, spec in zip(params["blocks"], cfg.layers):
-        blk = layer_fns.get(spec)
+    for block, spec, level in zip(params["blocks"], cfg.layers,
+                                  remat_levels):
+        blk = layer_fns.get((spec, level))
         if blk is None:
             blk = functools.partial(_layer_forward, spec=spec, cfg=cfg,
                                     attn_fn=attn_fn)
-            if cfg.remat:
-                blk = jax.checkpoint(blk, static_argnums=())
-            layer_fns[spec] = blk
+            if level < KEEP_LAYER:
+                blk = jax.checkpoint(
+                    blk, static_argnums=(),
+                    policy=jax.checkpoint_policies.save_only_these_names(
+                        *REMAT_KEEPS[level]) if level else None)
+            elif cfg.remat:
+                # a layer the plan keeps whole: one jitted function a
+                # kind, traced once like the checkpointed ones (twelve
+                # bare layers take three times as long to lower);
+                # ``remat=False`` stays the bare function it was
+                blk = jax.jit(blk)
+            layer_fns[spec, level] = blk
         x, rows = blk(block, x, positions)
         if rows is not None:
             moe_rows.append(rows)
@@ -444,13 +625,16 @@ def forward(params, tokens: jax.Array, cfg: TransformerConfig,
 
 
 def loss_fn(params, batch: Dict[str, jax.Array],
-            cfg: TransformerConfig, attn_fn=None) -> jax.Array:
+            cfg: TransformerConfig, attn_fn=None,
+            remat_levels: Optional[Tuple[int, ...]] = None) -> jax.Array:
     """Next-token cross-entropy. batch: tokens [B,S]; optional
     loss_mask [B,S]. The forward runs on the full S (keeps the seq dim
     divisible by the sp axis for ring attention); the shift to next-
-    token targets happens on the logits."""
+    token targets happens on the logits. ``remat_levels``: what each
+    layer keeps for this gradient (``remat_plan``)."""
     tokens = batch["tokens"]
-    logits = forward(params, tokens, cfg, attn_fn=attn_fn)[:, :-1]
+    logits = forward_with_stats(params, tokens, cfg, attn_fn=attn_fn,
+                                remat_levels=remat_levels)[0][:, :-1]
     targets = tokens[:, 1:]
     logp = jax.nn.log_softmax(logits, axis=-1)
     nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]

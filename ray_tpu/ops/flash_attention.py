@@ -48,6 +48,7 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 
 
@@ -546,6 +547,11 @@ def _flash_vjp_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret,
         functools.partial(_flash_fwd, causal=causal, sm_scale=sm_scale,
                           block_q=block_q, block_k=block_k, window=window),
         interpret, q, k, v)
+    # a remat policy that keeps these two (and q, k, v, which the layer
+    # names) has every residual, so the backward never runs the
+    # forward kernel again (models/transformer.py::remat_plan)
+    out = checkpoint_name(out, "attn_out")
+    lse = checkpoint_name(lse, "attn_lse")
     return out, (q, k, v, out, lse)
 
 

@@ -1,9 +1,12 @@
 """Flagship transformer: sharded train step, ring-attention parity,
 a routed layer in the pattern, and the driver entry hooks."""
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ray_tpu.models import (
@@ -167,3 +170,181 @@ def test_use_flash_matches_dense_forward():
     out_d = forward(params, tokens, cfg_d)
     out_f = forward(params, tokens, cfg_f)
     assert float(jnp.max(jnp.abs(out_d - out_f))) < 2e-2
+
+
+# ---------------------------------------------------------------------------
+# What ``remat=True`` keeps (``remat_plan``)
+# ---------------------------------------------------------------------------
+
+def _mistral(n_layers, **kw):
+    """The benchmark's train configuration: published Mistral-7B widths."""
+    return TransformerConfig(
+        vocab_size=32_000, d_model=4096, n_layers=n_layers, n_heads=32,
+        n_kv_heads=8, d_ff=14_336, max_seq_len=4096, dtype=jnp.bfloat16,
+        remat=True, use_flash=True, **kw)
+
+
+def _limit_with_room(room):
+    """The smallest limit whose room (limit less the margin) is `room`."""
+    from ray_tpu.models.transformer import REMAT_MARGIN
+    limit = int(room / (1 - REMAT_MARGIN))
+    while int(limit * (1 - REMAT_MARGIN)) < room:
+        limit += 1
+    return limit
+
+
+@pytest.mark.parametrize("case", [
+    "train_cell", "share_2x2", "no_limit", "dense_attention", "full_chip"])
+def test_remat_plan_table(case):
+    from ray_tpu.models.transformer import KEEP_LAYER, remat_plan
+    chip = 16e9                                 # benchmark/costs.CHIP_PEAKS
+    if case == "train_cell":
+        # 3 layers, 1 x 4,096 tokens, 11.0 GB of f32 Adam state held
+        plan = remat_plan(_mistral(3), 1, 4096, 10_997_827_072, chip)
+        assert plan.levels == (KEEP_LAYER,) * 3
+        assert plan.recompute_flops == 0
+        assert plan.kept_bytes <= plan.budget_bytes
+        assert 14.0e9 < plan.peak_bytes < 14.3e9        # AOT: 14.155
+    elif case == "share_2x2":
+        # 12 layers over fsdp=2 x tp=2: 8.64 GB a chip, 2 x 4,096 tokens
+        args = (_mistral(12), 2, 4096, 8_639_415_808)
+        shards = {"dp": 1, "fsdp": 2, "tp": 2, "sp": 1}
+        plan = remat_plan(*args, chip, shards)
+        whole = remat_plan(*args, None, shards, (KEEP_LAYER,) * 12)
+        assert 0 < plan.kept_bytes < whole.kept_bytes
+        assert plan.kept_bytes <= plan.budget_bytes
+        assert plan.peak_bytes <= chip
+        assert 0 < plan.recompute_flops < 12 * plan.layer_forward_flops
+        assert len(set(plan.levels)) <= 2       # two functions a kind
+    elif case == "no_limit":
+        plan = remat_plan(_mistral(3), 1, 4096, 10_997_827_072, None)
+        assert plan.levels == (0, 0, 0) and plan.budget_bytes == 0
+        assert 12.9e9 < plan.peak_bytes < 13.2e9        # AOT: 13.010
+    elif case == "dense_attention":
+        cfg = dataclasses.replace(_mistral(3), use_flash=False)
+        assert remat_plan(cfg, 1, 4096, 0, chip).levels == (0, 0, 0)
+    else:
+        # a chip the state fills gets exactly today's program
+        plan = remat_plan(_mistral(3), 1, 4096, 13_500_000_000, chip)
+        assert plan.levels == (0, 0, 0)
+
+
+@pytest.mark.parametrize("levels", [
+    (2, 1, 1), (2, 2, 1), (2, 2, 2), (3, 2, 2), (3, 3, 2), (3, 3, 3)])
+def test_remat_plan_falls_a_level_one_byte_short(levels):
+    """A limit with room for exactly this plan's counted peak gives it;
+    one byte less gives the plan one raise below. (Up to (1, 1, 1) the
+    peak is the backward pass's and a raise does not move it.)"""
+    from ray_tpu.models.transformer import remat_plan
+    args = (_mistral(3), 1, 4096, 10_997_827_072)
+    peak = remat_plan(*args, None, None, levels).peak_bytes
+    assert remat_plan(*args, _limit_with_room(peak)).levels == levels
+    below = remat_plan(*args, _limit_with_room(peak - 1)).levels
+    raised = [i for i in range(3) if below[i] != levels[i]]
+    assert len(raised) == 1 and below[raised[0]] == levels[raised[0]] - 1
+
+
+def _kernel_results(jaxpr):
+    """The number of results of every ``pallas_call`` in the program.
+    The forward and dk/dv kernels give two, the dq kernel one."""
+    from jax._src import core
+    found = [len(e.outvars) for e in jaxpr.eqns
+             if e.primitive.name == "pallas_call"]
+    for sub in core.subjaxprs(jaxpr):
+        found += _kernel_results(sub)
+    return found
+
+
+def _flash_cfg(**kw):
+    return _cfg(n_layers=3, use_flash=True, **kw)
+
+
+@pytest.mark.parametrize("levels", [
+    (0, 0, 0), (1, 1, 1), (2, 2, 2), (3, 3, 3), (1, 2, 2), (2, 3, 3)])
+def test_remat_levels_give_the_gradient_of_no_remat(levels):
+    """Kept and recomputed values are the same numbers: loss and
+    gradients in float32 equal those of ``remat=False`` to the last
+    bit, whatever each layer keeps."""
+    params = init_params(jax.random.PRNGKey(0), _flash_cfg())
+    batch = {"tokens": _tokens(b=2)}
+    want = jax.jit(jax.value_and_grad(
+        lambda p: loss_fn(p, batch, _flash_cfg(remat=False))))(params)
+    got = jax.jit(jax.value_and_grad(
+        lambda p: loss_fn(p, batch, _flash_cfg(remat=True),
+                          remat_levels=levels)))(params)
+    for a, b in zip(jax.tree.leaves(want), jax.tree.leaves(got)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("levels,forwards", [
+    ((0, 0, 0), 6), ((1, 1, 1), 3), ((2, 2, 2), 3), ((3, 3, 3), 3),
+    ((0, 0, 1), 5), ((0, 1, 1), 4)])
+def test_kept_kernel_results_spare_the_second_forward(levels, forwards):
+    """The gradient's program calls the attention forward once a layer
+    where the kernel's results are kept and twice where not, and the
+    layers of one kind and level share one traced function."""
+    from ray_tpu.ops.flash_attention import flash_attention
+    calls = []
+
+    def counting(q, k, v, **kw):
+        calls.append(kw)
+        return flash_attention(q, k, v, True, None, None, None, True, **kw)
+
+    cfg = _flash_cfg(remat=True)
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    program = jax.make_jaxpr(jax.grad(
+        lambda p: loss_fn(p, {"tokens": _tokens(b=2)}, cfg, counting,
+                          levels)))(params)
+    assert len(calls) == len(set(levels))
+    # the forward kernel alone gives two results (out, lse), dq one
+    results = _kernel_results(program.jaxpr)
+    assert results.count(2) - 3 == forwards and results.count(1) == 3
+
+
+# sha256 of the lowered train step at commit 00046a2 (the parent of the
+# remat plan), function-name counters left out
+PARENT_STEP_DIGESTS = {
+    False: "d54009dfbe25db5aaada559a095cab9f480bcabbfe8e7b1034b7676ca6a54356",
+    True: "20972a414781800131bf55589fb49ec4de537a667fcc71e87a523bea75512fa7",
+}
+
+
+@pytest.mark.parametrize("use_flash", [False, True])
+def test_train_step_without_a_memory_figure_is_the_parents(use_flash):
+    """The CPU reports no memory limit, so ``remat=True`` recomputes
+    every layer as before: the step lowers to the parent's program."""
+    import hashlib
+    import re
+    cfg = _cfg(remat=True, use_flash=use_flash)
+    tx = make_optimizer(lr=1e-2, total_steps=50)
+    state = jax.eval_shape(lambda k: init_state(k, cfg, tx),
+                           jax.random.PRNGKey(0))
+    tokens = jax.ShapeDtypeStruct((4, 64), jnp.int32)
+    text = make_train_step(cfg, tx).lower(state, {"tokens": tokens}).as_text()
+    text = re.sub(r"(@[A-Za-z_]\w*?)_\d+\b", r"\1", text)
+    assert hashlib.sha256(text.encode()).hexdigest() == \
+        PARENT_STEP_DIGESTS[use_flash]
+
+
+def test_train_step_records_its_remat_plan():
+    """One ``train.remat_plan`` record when the step is traced, under a
+    mesh too (the state's bytes through its shardings)."""
+    from ray_tpu.util import tracing
+    cfg = _cfg(remat=True, use_flash=True)
+    mesh = make_mesh(MeshSpec(fsdp=2, tp=2), jax.devices()[:4])
+    tx = make_optimizer(lr=1e-2, total_steps=50)
+    tracing.clear()
+    with mesh:
+        state = init_state(jax.random.PRNGKey(0), cfg, tx, mesh)
+        step = make_train_step(cfg, tx, mesh)
+        tokens = jax.device_put(_tokens(), NamedSharding(
+            mesh, P(("dp", "fsdp"), "sp")))
+        state, metrics = step(state, {"tokens": tokens})
+        state, metrics = step(state, {"tokens": tokens})
+    assert np.isfinite(float(metrics["loss"]))
+    records = [s.counts for s in tracing.spans()
+               if s.name == "train.remat_plan"]
+    assert len(records) == 1
+    assert records[0]["layers"] == 2 and records[0]["layers_kept_whole"] == 0
+    assert records[0]["recompute_flops"] > 0
+    assert records[0]["budget_bytes"] == 0

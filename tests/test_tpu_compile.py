@@ -134,3 +134,40 @@ def test_scheduler_kernel_compiles_for_tpu(v5e):
         arg((n,), jnp.bool_), arg((k, r), jnp.float32),
         arg((k,), jnp.int32), arg((k,), jnp.int32), arg((), jnp.float32),
         num_classes=k).compile()
+
+
+@pytest.mark.parametrize("limit", [16e9, None])
+def test_train_step_fits_as_the_remat_plan_counts(v5e, limit):
+    """The benchmark's train cell whole (3 layers at published
+    Mistral-7B widths, 1 x 4,096 tokens, f32 Adam) under the plan that
+    ``remat_plan`` gives for a 16 GB chip (benchmark/costs.CHIP_PEAKS)
+    and under "recompute every layer", which a device with no memory
+    figure gets: libtpu's total stands within the plan's margin of the
+    plan's own count, and under the limit. An activation that grows in
+    the layer fails here and not on the chip."""
+    from ray_tpu.models import (
+        TransformerConfig, init_state, make_optimizer, make_train_step)
+    from ray_tpu.models.transformer import (
+        KEEP_LAYER, REMAT_MARGIN, remat_plan)
+    one = SingleDeviceSharding(v5e[0])
+    cfg = TransformerConfig(
+        vocab_size=32_000, d_model=4096, n_layers=3, n_heads=32,
+        n_kv_heads=8, d_ff=14_336, max_seq_len=4096, dtype=jnp.bfloat16,
+        remat=True, use_flash=True)
+    tx = make_optimizer(lr=3e-4, weight_decay=0.1, warmup_steps=0,
+                        total_steps=10_000)
+    state = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one),
+        jax.eval_shape(lambda k: init_state(k, cfg, tx),
+                       jax.random.PRNGKey(0)))
+    held = sum(s.size * s.dtype.itemsize for s in jax.tree.leaves(state))
+    plan = remat_plan(cfg, 1, 4096, held, limit)
+    assert plan.levels == ((KEEP_LAYER,) * 3 if limit else (0, 0, 0))
+    tokens = jax.ShapeDtypeStruct((1, 4096), jnp.int32, sharding=one)
+    step = make_train_step(cfg, tx, donate=True, remat_levels=plan.levels)
+    memory = step.lower(state, {"tokens": tokens}).compile().memory_analysis()
+    total = (memory.argument_size_in_bytes + memory.output_size_in_bytes
+             - memory.alias_size_in_bytes + memory.temp_size_in_bytes)
+    # 14.155 GB kept whole, 13.010 GB recomputed (PERF.md §6, PR 29)
+    assert abs(total - plan.peak_bytes) < REMAT_MARGIN * 16e9
+    assert total + memory.generated_code_size_in_bytes < 16e9
