@@ -7,7 +7,8 @@ One process owns the chip(s): a driver calls ``ray_tpu.init()``, the
 scheduler places work (device kernel for deep queues), CPU tasks run in
 process workers that never open the device, ``TPU``-demand tasks and
 actors run on the driver's in-process lane, ``ray_tpu.train`` and
-``ray_tpu.serve`` run the flagship-width model on that lane. Every
+``ray_tpu.serve`` run the benchmark's model (Mistral-7B-v0.1 at its
+published widths, two layers) on that lane. Every
 phase checks what came out against something that shares no code with
 it; any failure is an exception, so the script cannot reach its last
 line with a phase broken. Without a TPU it stops in the first phase.
@@ -29,11 +30,29 @@ import urllib.request
 
 import numpy as np
 
-# bench.py's flagship (the one model width this repo has measured):
-# 671M parameters, no remat, Pallas flash attention.
-FLAGSHIP = dict(vocab_size=32_768, d_model=2048, n_layers=8, n_heads=16,
-                n_kv_heads=16, d_ff=8192, max_seq_len=2048, remat=False,
-                use_flash=True)
+_PUBLISHED = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                          "benchmark", "configs", "mistral-7b-v0.1-l3.json")
+
+
+def _flagship() -> dict:
+    """The benchmark's model, Mistral-7B-v0.1 at its published widths,
+    as ``TransformerConfig`` fields: read from the benchmark's own file
+    and cut in depth alone, to two layers with the embedding and the
+    head (698M parameters, 8.4 GB of float32 train state: what one chip
+    holds beside a 4 x 2,048-token step with every activation kept).
+    No remat, Pallas flash attention."""
+    from ray_tpu.models import config_from_hf
+
+    with open(_PUBLISHED) as f:
+        published = json.load(f)
+    cfg = dataclasses.replace(
+        config_from_hf({**published, "num_hidden_layers": 2},
+                       max_seq_len=2048),
+        remat=False, use_flash=True)
+    return {f.name: getattr(cfg, f.name) for f in dataclasses.fields(cfg)}
+
+
+FLAGSHIP = _flagship()
 
 
 @dataclasses.dataclass(frozen=True)
@@ -42,7 +61,7 @@ class Sizes:
     these; the program has no option for it."""
 
     cpu_tasks: int = 300
-    # BASELINE.json's north-star scheduling problem (bench.py:20-24)
+    # BASELINE.json's north-star scheduling problem
     sched_nodes: int = 10_000
     sched_tasks: int = 1_000_000
     native_sample: int = 8192
@@ -56,7 +75,6 @@ class Sizes:
     attn_shape: tuple = (2, 2048, 4, 128)      # [B, S, N, H]
     serve_seq: int = 128
     serve_requests: int = 8
-    mesh_layers: int = 4                       # --chips 4: depth cut
 
 
 _BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
@@ -216,7 +234,7 @@ _N_RES = 4      # CPU, TPU, memory, custom
 
 
 def _cluster_arrays(rng, n_nodes):
-    """bench.py's synthetic cluster (same draws for the same seed)."""
+    """A synthetic cluster (same draws for the same seed)."""
     total = np.zeros((n_nodes, _N_RES), np.float32)
     total[:, 0] = rng.choice([256, 256, 384], n_nodes)
     total[:, 1] = rng.choice([0, 4, 8, 8], n_nodes)
@@ -288,7 +306,7 @@ def _check_dense_schedule(ds, avail, total, demands, counts) -> dict:
 
 def _native_placed(avail, total, alive, demands, sample) -> np.ndarray:
     """Per-class placed counts of the native C++ policy on ``sample``
-    tasks cycling the classes (bench.py's CPU baseline call)."""
+    tasks cycling the classes."""
     import ctypes as ct
 
     from ray_tpu._private.native_loader import scheduler_lib
@@ -620,7 +638,7 @@ def phase_mesh(sz: Sizes, devices) -> dict:
     from ray_tpu.models import TransformerConfig
     from ray_tpu.parallel.mesh import MeshSpec, make_mesh
 
-    cfg = TransformerConfig(**{**sz.model, "n_layers": sz.mesh_layers})
+    cfg = TransformerConfig(**sz.model)
     tokens = np.random.RandomState(0).randint(
         0, cfg.vocab_size, (sz.batch, sz.seq), np.int32)
     t0 = time.perf_counter()
@@ -628,7 +646,7 @@ def phase_mesh(sz: Sizes, devices) -> dict:
         cfg, None, jax.device_put(tokens, devices[0]), devices)
     whole = placed_one[0]
     assert placed_one[1:] == [0] * (len(devices) - 1), placed_one
-    out = {"layers": sz.mesh_layers,
+    out = {"layers": cfg.n_layers,
            "one_device": {"losses": want, "state_bytes": whole,
                           "memory_stats_bytes_in_use": in_use_one,
                           "seconds": round(time.perf_counter() - t0, 1)}}

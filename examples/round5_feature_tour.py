@@ -1,6 +1,6 @@
 """Round-5 feature tour: detached actor services, elastic training,
 async-actor call cancellation, a multi-slice mesh, and a rolling serve
-redeploy — every plane VERDICT r4 asked for, driven end to end.
+redeploy — every plane of that round, driven end to end.
 
     python examples/round5_feature_tour.py
 

@@ -1,10 +1,11 @@
 """Where the chip-owning process keeps JAX's persistent compile cache.
 
-A cold TPU process pays ~30 s for the flagship train step and ~10 s
-for the scheduler kernel at 10k nodes; the cache directory is part of
-the cache key's lookup, so it must not move between runs. Called where
+A cold TPU process compiles for tens of seconds (the train cell's
+``setup_s`` reads 23 s warm and 65 s cold: ledger, PR 24); the cache
+directory is part of the cache key's lookup, so it must not move
+between runs. Called where
 a process first touches JAX for the device: ``ray_tpu.init()``'s TPU
-detection, ``chip_smoke.py``, ``bench.py``.
+detection and ``chip_smoke.py``.
 """
 
 from __future__ import annotations

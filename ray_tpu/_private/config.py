@@ -268,13 +268,6 @@ _D("serve_autoscale_ewma_alpha", float, 0.5,
    "Smoothing factor of the serve autoscaler's load EWMA (weight of "
    "the newest interval sample; 1.0 = instantaneous load, the "
    "pre-serve-plane behavior).")
-_D("serve_http_ingress", str, "async",
-   "HTTP ingress backend: 'async' (selector event loop — "
-   "non-blocking HTTP/1.1 with keep-alive and pipelining, requests "
-   "ride the router's promise-ref batched path, completion callbacks "
-   "write responses; docs/serve.md §Ingress) or 'threaded' (the "
-   "legacy stdlib thread-per-request server, kept for comparison "
-   "and as an escape hatch).")
 _D("serve_http_pipeline_max", int, 128,
    "Per-connection cap on pipelined requests awaiting responses at "
    "the async ingress. A connection at the cap stops being READ from "

@@ -311,8 +311,8 @@ class NodeManagerGroup:
         self._wake = threading.Event()
         self._shutdown = False
         # Wire-plane stats (data-plane fast path observability): frames
-        # vs payloads through the owner's submit paths — the bench's
-        # rpc_frame_avg_batch / rpc_bytes_per_task inputs.
+        # vs payloads through the owner's submit paths, which stats.py
+        # exports as ray_tpu_rpc_batch_size{channel}.
         from ray_tpu._private import wire_stats
         self.wire_stats = wire_stats
         # hot-path accumulator held once (wire_stats.channel docstring)
@@ -1775,7 +1775,8 @@ class NodeManagerGroup:
         worker records missed steal targets and drops a later-arriving
         exec for them (replying stolen), and the target is remembered
         here so ``_on_tasks_stolen`` falls through to the interrupt
-        path when the reply omits it (ADVICE r5)."""
+        path when the reply omits it (else a task reported cancelled
+        would still run its side effects)."""
         with self._lock:
             rt = self._running.get(task_id)
             if rt is None:
@@ -1854,7 +1855,7 @@ class NodeManagerGroup:
                 worker.rescue_steal_ids = set()
             # Cancel-steal targets this reply ANSWERS but did not take:
             # trusting the miss would let a cancelled task run its side
-            # effects (ADVICE r5). Two cases: the task is EXECUTING
+            # effects. Two cases: the task is EXECUTING
             # (pipe head) — fall through to the interrupt path; or its
             # exec frame is still in transit — the worker's
             # pending-steal intake drops it on arrival and answers

@@ -114,15 +114,6 @@ def serve_store(server: RpcServer,
     """
     ch = stats if stats is not None else wire_stats.channel("object_serve")
 
-    def fetch_object(ctx, oid_bytes: bytes, offset: int, length: int):
-        # Legacy single-source protocol: bytes, or None when gone.
-        view = get_view(oid_bytes)
-        if view is None:
-            return None
-        data = bytes(view[offset:offset + length])
-        ch.record(1, len(data))
-        return data
-
     def fetch_chunk(ctx, oid_bytes: bytes, offset: int, length: int):
         """Pull-engine protocol: ``("ok", bytes)`` for a sealed (or
         already-received in-flight) range, ``("wait", filled)`` while
@@ -149,44 +140,9 @@ def serve_store(server: RpcServer,
         if free_fn is not None:
             free_fn(oid_bytes)
 
-    server.register("fetch_object", fetch_object)
     server.register("fetch_chunk", fetch_chunk)
     server.register("object_info", object_info)
     server.register("free_object", free_object)
-
-
-# ---------------------------------------------------------------------------
-# legacy single-source client (bench baseline + minimal wire client)
-
-
-def pull_object(client: RpcClient, oid_bytes: bytes, size: int,
-                chunk_size: Optional[int] = None,
-                timeout: float = 60.0) -> bytes:
-    """Pull a whole object from ONE peer in bounded chunks. The
-    PullManager is the production path (dedup, retries, striping,
-    re-route); this stays as the minimal wire client and the bench's
-    pre-broadcast baseline."""
-    if chunk_size is None:
-        chunk_size = get_config().object_chunk_size_bytes
-    buf = bytearray(size)
-    off = 0
-    oid_hex = oid_bytes.hex()
-    while off < size:
-        n = min(chunk_size, size - off)
-        data = client.call("fetch_object", oid_bytes, off, n,
-                           timeout=timeout)
-        if not data:
-            # None: the peer freed the object between chunks; b"": a
-            # truncated read. Both surface typed — with the object and
-            # the offset reached — BEFORE any buffer write or offset
-            # advance.
-            raise ObjectSourceLostError(
-                f"peer no longer serves object {oid_hex[:16]} "
-                f"(offset {off}/{size})",
-                object_id_hex=oid_hex, offset=off)
-        buf[off:off + len(data)] = data
-        off += len(data)
-    return bytes(buf)
 
 
 class PeerClients:

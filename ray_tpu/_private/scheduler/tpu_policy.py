@@ -432,7 +432,7 @@ class TpuSchedulingPolicy(ISchedulingPolicy):
                            else cfg.scheduler_spread_threshold)
         self._view = _DenseView()
 
-    # -- dense fast path (used by schedule_batch and by bench.py) ---------
+    # -- dense fast path (used by schedule_batch and chip_smoke.py) -------
 
     def schedule_dense(
         self,

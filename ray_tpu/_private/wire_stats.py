@@ -6,8 +6,7 @@ binary fast path) counting frames vs payloads vs bytes. The ratio
 payloads/frames is the realized coalescing factor — the number the
 batching knobs (``submit_coalesce_*``, ``task_done_coalesce_*``,
 ``worker_reply_flush_*``) exist to move — and bytes/payload is the
-wire cost per task. bench.py reports both (``rpc_frame_avg_batch``,
-``rpc_bytes_per_task``) and stats.py exports them as
+wire cost per task. stats.py exports them as
 ``ray_tpu_rpc_batch_size{channel}``.
 
 Counters are plain ints bumped under the GIL without a lock: they sit

@@ -334,14 +334,15 @@ def status() -> dict:
 def start(http: bool = True, proxy_location: str = "worker"):
     """Start serve, optionally with the HTTP ingress.
 
-    ``proxy_location``:
-    - "worker" (default): the ingress runs in a WORKER process (the
-      reference's proxy-actor topology) — HTTP parsing and response
-      serialization stay off the driver's scheduling threads; the
-      controller pushes route-table updates to it. This is the
-      production topology and the one BASELINE.md's serve numbers use.
-    - "driver": threaded server in the driver process — TEST-ONLY
-      convenience (no worker spawn): ingress threads compete with the
+    ``proxy_location`` places the one event-loop ingress
+    (``_private/ingress.py``):
+    - "worker" (default): in a WORKER process (the reference's
+      proxy-actor topology) — HTTP parsing and response serialization
+      stay off the driver's scheduling threads; the controller pushes
+      route-table updates to it. The deployable placement, and the one
+      every serve cell of the benchmark measures.
+    - "driver": the same loop in the driver process, with no worker
+      spawn — for tests and notebooks: its thread competes with the
       driver's scheduling loop for CPU.
     """
     global _worker_proxy

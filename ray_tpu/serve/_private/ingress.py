@@ -2,11 +2,10 @@
 
 Reference: ``python/ray/serve/_private/proxy.py`` (the dedicated async
 proxy — uvicorn/ASGI event loop in front of the router) [UNVERIFIED —
-mount empty, SURVEY.md §0]. The stdlib thread-per-request server
-(http_proxy.py, kept as the ``threaded`` backend) parks one thread in
-a blocking ``get`` per request — at wire speed the front door, not the
-router, becomes the bottleneck (ROADMAP open item 3). This module
-replaces it with ONE event-loop thread and zero per-request threads:
+mount empty, SURVEY.md §0]. A thread-per-request server parks one
+thread in a blocking ``get`` per request, so the front door and not
+the router becomes the bottleneck. This module is ONE event-loop
+thread and zero per-request threads:
 
 - **Non-blocking HTTP/1.1** with keep-alive and pipelining: many
   requests ride one connection; responses are written strictly in
@@ -1173,7 +1172,7 @@ class AsyncIngress:
                             _render(504, "Gateway Timeout", blob,
                                     slot.keep_alive))
 
-    # -- lifecycle (mirrors _CountingHTTPServer's surface) -------------
+    # -- lifecycle ------------------------------------------------------
 
     def inflight(self) -> int:
         return max(0, self._active)

@@ -280,7 +280,7 @@ def test_async_actor_restart_replays(rt):
 
 
 def test_async_actor_throughput_smoke(rt):
-    # not a perf gate (bench.py carries that); just assert the batched
+    # not a perf gate; just assert the batched
     # async path sustains a few thousand calls quickly
     @ray_tpu.remote
     class C:

@@ -43,7 +43,7 @@ def test_phases_rehearse_on_cpu_at_tiny_size(monkeypatch):
                    n_kv_heads=2, d_ff=256, max_seq_len=128, remat=False,
                    use_flash=True),
         batch=2, seq=128, attn_shape=(1, 256, 2, 128), serve_seq=16,
-        serve_requests=2, mesh_layers=2)
+        serve_requests=2)
     devices = jax.devices()
     ray_tpu.shutdown()
     try:

@@ -159,8 +159,8 @@ def test_many_async_actor_calls(rt):
 
 
 def test_many_shuffle_blocks(rt):
-    """1k-block random_shuffle through the two-level plane (VERDICT r4
-    missing #6 / BASELINE eval config 4 scale): completes under the
+    """1k-block random_shuffle through the two-level plane (BASELINE
+    eval config 4 scale): completes under the
     byte-backpressure budgets with peak live refs bounded at
     O(N^1.5), nowhere near one-level N^2."""
     import threading

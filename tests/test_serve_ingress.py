@@ -419,69 +419,6 @@ def test_first_token_gauge_populated(serve_instance):
 
 
 # ---------------------------------------------------------------------------
-# threaded backend keeps the same typed contracts
-
-def test_threaded_backend_stream_typed_terminal(serve_instance):
-    """The legacy thread-per-request backend (serve_http_ingress=
-    threaded) emits the SAME typed terminal record and closes the
-    connection — no anonymous {"error": ...} chunk."""
-    from ray_tpu.serve._private.http_proxy import HttpProxy
-
-    @serve.deployment
-    class Gen:
-        def __call__(self, n):
-            yield {"i": 0}
-            raise ValueError("threaded boom")
-
-    serve.run(Gen.bind())
-    proxy = HttpProxy(serve._controller, backend="threaded")
-    try:
-        host, port = proxy.address
-        s = socket.create_connection((host, port), timeout=30)
-        try:
-            s.sendall(_post("Gen", 1, stream=True))
-            f = s.makefile("rb")
-            status, hdrs = _read_stream_head(f)
-            assert status == 200
-            records = [json.loads(c) for c in _iter_chunks(f)]
-            assert records[0] == {"i": 0}
-            term = records[-1]
-            assert term["terminal"] is True
-            assert term["error_type"] == "ValueError"
-            assert f.read(1) == b""     # errored stream closes the conn
-        finally:
-            s.close()
-        assert serve_stats.snapshot()["stream_errors"] >= 1
-    finally:
-        proxy.shutdown()
-
-
-def test_threaded_backend_typed_unary_errors(serve_instance):
-    from ray_tpu.serve._private.http_proxy import HttpProxy
-
-    @serve.deployment
-    class Boom:
-        def __call__(self, x):
-            raise KeyError("missing")
-
-    serve.run(Boom.bind())
-    proxy = HttpProxy(serve._controller, backend="threaded")
-    try:
-        host, port = proxy.address
-        s = socket.create_connection((host, port), timeout=30)
-        try:
-            s.sendall(_post("Boom", 1))
-            status, hdrs, body = _read_response(s.makefile("rb"))
-            assert status == 500
-            assert hdrs["x-rtpu-error-type"] == "KeyError"
-            assert json.loads(body)["error_type"] == "KeyError"
-        finally:
-            s.close()
-    finally:
-        proxy.shutdown()
-
-
-# ---------------------------------------------------------------------------
 # slow tier: the ingress suite under the runtime sanitizer
 
 @pytest.mark.slow

@@ -20,9 +20,6 @@ from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 from ray_tpu.ops import flash_attention, make_attention_fn
 from ray_tpu.parallel.mesh import MeshSpec, make_mesh
 
-# the flagship's attention shape (bench.py, chip_smoke.py)
-B, S, N, H = 4, 2048, 16, 128
-
 
 @pytest.fixture(scope="module")
 def v5e():
@@ -52,13 +49,15 @@ def _assert_kernel_not_interpreter(lowered):
 
 
 # the benchmark's cells: the train cell's attention with gradients and a
-# serve prompt padded to 128, both at the blocks the kernel chooses
+# serve prompt padded to 128, both at the blocks the kernel chooses; the
+# long Mistral cell's other two padded lengths at named blocks
 TRAIN_CELL, SERVE_CELL = (1, 4096, 32, 128), (1, 128, 32, 128)
+LONG_1K, LONG_2K = (1, 1024, 32, 128), (1, 2048, 32, 128)
 
 
 @pytest.mark.parametrize("shape,block,direction", [
-    ((B, S, N, H), 128, "forward"), ((B, S, N, H), 128, "backward"),
-    ((B, S, N, H), 512, "forward"), ((B, S, N, H), 512, "backward"),
+    (LONG_1K, 128, "forward"), (LONG_1K, 512, "forward"),
+    (LONG_2K, 128, "forward"), (LONG_2K, 512, "forward"),
     (TRAIN_CELL, None, "forward"), (TRAIN_CELL, None, "backward"),
     (SERVE_CELL, None, "forward"),
     # whole-length K and V past the default VMEM scope, with gradients
@@ -115,7 +114,7 @@ def test_flash_compiles_under_a_mesh(v5e):
     kernel runs per device on its batch/head shard."""
     mesh = make_mesh(MeshSpec(fsdp=2, tp=2), v5e)
     x = jax.ShapeDtypeStruct(
-        (B, S, N, H), jnp.bfloat16,
+        (2, 4096, 32, 128), jnp.bfloat16,   # the 2x2 cell's step
         sharding=NamedSharding(mesh, P(("dp", "fsdp"), None, "tp", None)))
     attend = make_attention_fn(mesh, impl="flash")
     _assert_kernel_not_interpreter(jax.jit(attend).lower(x, x, x))
