@@ -16,7 +16,11 @@ past ``ends[-1]`` belongs to experts held elsewhere. ``gmm`` (a Pallas
 kernel: rows grouped by expert times that expert's matrix) visits
 only the row tiles that hold a held expert's rows; the others are
 skipped, not computed and discarded, and their output rows are left
-unwritten (the combine selects, it does not multiply by zero).
+unwritten (the combine does not read them: it selects, it does not
+multiply by zero). The stages round the kernel (gather, activation,
+combine) pass over a static prefix of the sorted rows, the shortest of
+a short ladder that holds ``ends[-1]`` rows, chosen on the device for
+each call.
 
 Shapes: tokens ``m [T, D]``; ``router [D, E]``, ``bias [E]``;
 ``wg, wi [count, D, F]``, ``wo [count, F, D]``.
@@ -238,6 +242,75 @@ def gmm_reference(lhs, rhs, starts, ends):
 # The layer
 # --------------------------------------------------------------------------
 
+def _ladder(rows: int, count: int, n_experts: int,
+            tile_m: int) -> Tuple[int, ...]:
+    """The row counts a call chooses among, from shapes alone: whole
+    row tiles that double from twice the even share of the rows
+    (``rows * count / n_experts``) up to ``rows`` itself, the worst
+    case, which holds whatever the router does. A call that holds half
+    the experts or more has that one rung."""
+    rung = _round_up(-(-2 * rows * count // n_experts), tile_m)
+    rungs = []
+    while rung < rows:
+        rungs.append(rung)
+        rung *= 2
+    return (*rungs, rows)
+
+
+def _rung(held_rows, ladder: Tuple[int, ...]):
+    """Index of the smallest rung that holds ``held_rows`` rows: the
+    device's scalar and the host's counts alike."""
+    return sum(held_rows > rung for rung in ladder[:-1])
+
+
+# The ladder of each call traced in this process, by its (pairs, held
+# experts): ``route_counts`` is told neither the published experts nor
+# the row tile, and names the rung the device took from this.
+_LADDERS = {}
+
+
+# Jitted, so that the layers of a model that call it at the same shapes
+# share one trace of the rungs (nine kernels, not nine a layer).
+@functools.partial(jax.jit, static_argnames=("ladder", "tile_m", "tile_n",
+                                             "interpret"))
+def _held_part(m, token, position, starts, ends, weights, wg, wi, wo, *,
+               ladder: Tuple[int, ...], tile_m: int, tile_n: Optional[int],
+               interpret: Optional[bool]):
+    """The sorted pairs through the held experts and back to their
+    tokens, over the smallest rung of ``ladder`` that holds the held
+    experts' ``ends[-1]`` rows. -> [T, D] in ``m``'s type."""
+    # the row tile and the groups' bounds are the same on every rung, so
+    # each held row's products are too
+    run = functools.partial(gmm, starts=starts, ends=ends, tile_m=tile_m,
+                            tile_n=tile_n, interpret=interpret)
+
+    def part_over(r: int):
+        x = m[token[:r]]                                    # [r, D]
+        hidden = jax.nn.silu(run(x, wg)) * run(x, wi)
+        y = run(hidden, wo)                                 # [r, D]
+        # The combine selects by index, not by row: a pair whose expert
+        # is held elsewhere reads a row of zeros past y's own, which
+        # costs the gather next to nothing (0.6 ms for 3.2-3.4 at 16,384
+        # tokens where such a pair reads a row of y and a select drops
+        # it). The gathered rows stand as they are before the sum, so
+        # that they are laid out for it in m's type and not in float32
+        # (1.3 ms for 1.9). PERF.md §6, PR 31: bit for bit the sum it was.
+        y = jnp.concatenate([y, jnp.zeros((8, y.shape[1]), y.dtype)])
+        pairs = lax.optimization_barrier(
+            y[jnp.where(position < ends[-1], position, r)])  # [T, k, D]
+        return jnp.sum(pairs.astype(jnp.float32) * weights[..., None],
+                       axis=1).astype(m.dtype)
+
+    if len(ladder) == 1:
+        return part_over(ladder[0])
+    # the sum is the same text on every rung, and XLA would lift it out
+    # of the branches to after them, handing it all T x k gathered rows
+    # in float32: it stays where the rows are
+    return lax.switch(
+        _rung(ends[-1], ladder),
+        [lambda r=r: lax.optimization_barrier(part_over(r)) for r in ladder])
+
+
 def routed_experts(m, router, bias, wg, wi, wo, *, held: Tuple, top_k: int,
                    route_scale: float, tile_m: Optional[int] = None,
                    tile_n: Optional[int] = None,
@@ -246,7 +319,12 @@ def routed_experts(m, router, bias, wg, wi, wo, *, held: Tuple, top_k: int,
     result [T, D], rows routed to each held expert [count] int32).
     ``held = (first, count)``: this call holds experts ``first ..
     first + count - 1`` of the ``router.shape[1]`` published ones
-    (``first`` may be traced: an axis index under ``shard_map``)."""
+    (``first`` may be traced: an axis index under ``shard_map``).
+
+    The held experts' rows are the first ``ends[-1]`` of the sorted
+    rows, so gather, matmuls, activation and combine pass over the
+    smallest rung of ``_ladder`` that holds them, chosen on the device;
+    the top rung is every row, so no row is ever left out."""
     t, _d = m.shape
     n_experts = router.shape[1]
     count = wg.shape[0]
@@ -259,26 +337,30 @@ def routed_experts(m, router, bias, wg, wi, wo, *, held: Tuple, top_k: int,
     rows = _round_up(t * top_k, tile_m)
     token, position, starts, ends = _sorted_by_expert(
         experts, n_experts, held, rows)
-    x = m[token]                                            # [rows, D]
     dt = m.dtype
-    run = functools.partial(gmm, starts=starts, ends=ends, tile_m=tile_m,
-                            tile_n=tile_n, interpret=interpret)
-    hidden = jax.nn.silu(run(x, wg.astype(dt))) * run(x, wi.astype(dt))
-    y = run(hidden, wo.astype(dt))                          # [rows, D]
-    mine = position < ends[-1]                              # [T, k]
-    part = jnp.where(mine[..., None], y[position].astype(jnp.float32), 0.0)
-    out = jnp.sum(part * weights[..., None], axis=1).astype(dt)
+    ladder = _LADDERS[t * top_k, count] = _ladder(rows, count, n_experts,
+                                                  tile_m)
+    out = _held_part(m, token, position, starts, ends, weights,
+                     wg.astype(dt), wi.astype(dt), wo.astype(dt),
+                     ladder=ladder, tile_m=tile_m, tile_n=tile_n,
+                     interpret=interpret)
     return out, ends - starts
 
 
 def route_counts(rows_by_layer, tokens: int, top_k: int) -> dict:
     """The counts of one forward's ``model.moe.route`` record, from the
     rows each held expert of each routed layer was given
-    (``rows_by_layer [layers, count]``, on the host)."""
+    (``rows_by_layer [layers, count]``, on the host). ``rows_computed``:
+    the rung each layer took, summed (the worst case for a layer that
+    this process did not trace)."""
     rows = np.asarray(rows_by_layer)
-    return {"layers": int(rows.shape[0]),
-            "rows_total": int(rows.shape[0]) * tokens * top_k,
+    layers, count = rows.shape
+    ladder = _LADDERS.get((tokens * top_k, count), (tokens * top_k,))
+    taken = [ladder[_rung(int(held), ladder)] for held in rows.sum(axis=1)]
+    return {"layers": layers,
+            "rows_total": layers * tokens * top_k,
             "rows_held": int(rows.sum()),
+            "rows_computed": sum(taken),
             "load_max": int(rows.max(initial=0)),
             "load_mean": float(rows.mean()) if rows.size else 0.0}
 

@@ -193,21 +193,31 @@ def test_gmm_refuses_rows_that_are_not_whole_tiles():
                    16, None, True).shape == (64, 384)
 
 
-def test_the_shares_add_up():
+# tokens x top_k = 96 pairs in row tiles of 8, each share 2 of 8 experts:
+# the ladder is (48, 96). `boost` is added to the bias of share 1's two
+# experts: 1.5 gives it more than the lower rung's rows, 20 every pair.
+@pytest.mark.parametrize("boost,tile_m,rungs", [
+    (0.0, None, [96] * 4),      # one row tile: one rung, as before PR 31
+    (0.0, 8, [48, 48, 96, 48]),    # the reference bias skews share 2
+    (1.5, 8, [48, 96, 48, 48]),
+    (20.0, 8, [48, 96, 48, 48])])
+def test_the_shares_add_up(boost, tile_m, rungs):
     """The routed parts that all four shares give, plus the shared
     expert once, equal the uncut reference layer's MLP; and the program's
-    routed layer gives each share's part."""
+    routed layer gives each share's part, at whatever rung of its ladder
+    the router's skew puts a share: no rung drops a pair."""
     whole_config = dict(TINY, num_experts=8,
                         expert_parallel={"size": 1, "rank": 0})
     whole, weights, _tokens = _setup(whole_config)
     assert whole == ref.uncut(ref.Sizes.from_config(TINY))
     p = weights["blocks"][1]
+    p["router_bias"] = p["router_bias"].at[2:4].add(boost)
     m = jax.random.normal(jax.random.PRNGKey(5), (2 * SEQ, 64), jnp.float32)
     shared = ref._swiglu(m, p["shared_wg"], p["shared_wi"], p["shared_wo"],
                          "f32")
     uncut = ref.routed_part(p, m, whole, "f32") + shared
     total = shared
-    every_row = 0
+    every_row, taken = 0, []
     for rank in range(4):
         share_w, share_sz = ref.share_of(weights, whole, 2 * rank, 2)
         sp = share_w["blocks"][1]
@@ -215,14 +225,204 @@ def test_the_shares_add_up():
         mine, rows = moe.routed_experts(
             m, sp["router"], sp["router_bias"], sp["experts_wg"],
             sp["experts_wi"], sp["experts_wo"], held=(2 * rank, 2),
-            top_k=2, route_scale=2.448)
+            top_k=2, route_scale=2.448, tile_m=tile_m)
         np.testing.assert_allclose(np.asarray(mine), np.asarray(part),
                                    atol=2e-5)
         total = total + part
         every_row += int(np.sum(rows))
+        taken.append(moe.route_counts(np.asarray(rows)[None], 2 * SEQ,
+                                      2)["rows_computed"])
     np.testing.assert_allclose(np.asarray(total), np.asarray(uncut),
                                atol=2e-5)
     assert every_row == 2 * SEQ * 2     # no pair dropped, none counted twice
+    assert taken == rungs
+
+
+@pytest.mark.parametrize("boost,rungs", [
+    (0.0, [256] * 4), (0.5, [256, 512, 256, 256]),
+    (20.0, [256, 512, 256, 256])])
+def test_the_shares_add_up_over_a_mesh_axis(boost, rungs):
+    """`make_moe_fn` on four CPU devices, 2 of 8 experts a shard, 512
+    pairs in row tiles of 256 (ladder 256, 512): each shard takes its
+    own rung, the branch holds no collective, and the summed parts
+    equal the layer that holds every expert (one rung, no branch)."""
+    from ray_tpu.parallel.mesh import MeshSpec, make_mesh
+    rng = np.random.RandomState(0)
+    T, D, F, E, K = 256, 16, 128, 8, 2
+    h = jnp.asarray(rng.randn(T, D), jnp.float32)
+    router = jnp.asarray(rng.randn(D, E) * 0.5, jnp.float32)
+    bias = jnp.asarray(rng.randn(E) * 0.2, jnp.float32).at[2:4].add(boost)
+    wg, wi = (jnp.asarray(rng.randn(E, D, F) * 0.1, jnp.float32)
+              for _ in range(2))
+    wo = jnp.asarray(rng.randn(E, F, D) * 0.1, jnp.float32)
+    local, rows = moe.routed_experts(h, router, bias, wg, wi, wo,
+                                     held=(0, E), top_k=K, route_scale=2.0)
+    mesh = make_mesh(MeshSpec(tp=4), jax.devices()[:4])
+    with mesh:
+        dist, dist_rows = jax.jit(moe.make_moe_fn(
+            mesh, top_k=K, route_scale=2.0))(h, router, bias, wg, wi, wo)
+    np.testing.assert_allclose(np.asarray(dist), np.asarray(local),
+                               atol=1e-5, rtol=1e-5)
+    np.testing.assert_array_equal(np.asarray(dist_rows), np.asarray(rows))
+    assert int(dist_rows.sum()) == T * K
+    by_shard = np.asarray(dist_rows).reshape(4, 1, 2)
+    assert [moe.route_counts(r, T, K)["rows_computed"]
+            for r in by_shard] == rungs
+
+
+# The cell's proportions at a small size: 32 of 256 experts held (not the
+# first 32), top-4, 64 tokens = 256 pairs in row tiles of 8: the even
+# share is 32 rows and the ladder (64, 128, 256).
+CELL = dict(T=64, D=32, F=64, E=256, first=64, count=32, K=4, tile_m=8)
+
+
+def _cell_layer(bias_on_held, seed=0):
+    """-> the layer's arguments; `bias_on_held [count]` is added to the
+    held experts' selection bias."""
+    c = CELL
+    key = jax.random.split(jax.random.PRNGKey(seed), 5)
+    m = jax.random.normal(key[0], (c["T"], c["D"]), jnp.float32)
+    router = jax.random.normal(key[1], (c["D"], c["E"])) * c["D"] ** -0.5
+    bias = jnp.zeros((c["E"],)).at[c["first"]:c["first"] + c["count"]].add(
+        jnp.asarray(bias_on_held, jnp.float32))
+    wg, wi = (jax.random.normal(k, (c["count"], c["D"], c["F"])) * 0.2
+              for k in key[2:4])
+    wo = jax.random.normal(key[4], (c["count"], c["F"], c["D"])) * 0.2
+    return m, router, bias, wg, wi, wo
+
+
+def _cell_call(args, **more):
+    c = CELL
+    return moe.routed_experts(
+        *args, held=(c["first"], c["count"]), top_k=c["K"], route_scale=2.448,
+        tile_m=c["tile_m"], interpret=True, **more)
+
+
+@pytest.fixture
+def own_traces():
+    """The rungs are traced inside a jitted function, which keeps its
+    traces by shape: a test that swaps one of the module's functions
+    under it starts from none and leaves none behind."""
+    moe._held_part.clear_cache()
+    yield
+    moe._held_part.clear_cache()
+
+
+ROUTERS = {     # bias on the held experts -> the rows they are given
+    "no_row_held": np.full(32, -20.0),
+    "even": np.zeros(32),
+    # one held expert draws ten times the mean, as in the cell
+    "one_hot_expert": np.r_[0.25, np.zeros(31)],
+    "past_the_first_rung": np.full(32, 0.07),       # 90 rows
+    "past_the_second_rung": np.full(32, 0.12),      # 143 rows
+    # every token's four choices are held: only the top rung fits
+    "every_pair_held": np.r_[np.full(4, 20.0), np.zeros(28)],
+}
+
+
+@pytest.mark.parametrize("name,smallest", [
+    ("no_row_held", 64), ("even", 64), ("one_hot_expert", 64),
+    ("past_the_first_rung", 128), ("past_the_second_rung", 256),
+    ("every_pair_held", 256)])
+def test_every_rung_gives_the_top_rungs_result(monkeypatch, own_traces, name,
+                                               smallest):
+    """Forced onto each rung that holds the held rows, the layer gives
+    the top rung's result (the parent's: every row) bit for bit; left
+    alone it takes the smallest of them."""
+    args = _cell_layer(ROUTERS[name])
+    ladder = moe._ladder(256, 32, 256, 8)
+    assert ladder == (64, 128, 256)
+    alone, rows = _cell_call(args)
+    held = int(np.sum(rows))
+    if name == "one_hot_expert":
+        assert 8 < np.max(rows) / np.mean(rows) < 14
+    assert held == {"no_row_held": 0, "every_pair_held": 256}.get(name, held)
+    assert min(rung for rung in ladder if rung >= held) == smallest
+    monkeypatch.setattr(moe, "_rung", lambda _held, _ladder: 2)
+    moe._held_part.clear_cache()
+    top, _rows = _cell_call(args)
+    np.testing.assert_array_equal(np.asarray(alone), np.asarray(top))
+    for index, rung in enumerate(ladder[:-1]):
+        if rung >= held:
+            monkeypatch.setattr(moe, "_rung", lambda _held, _ladder: index)
+            moe._held_part.clear_cache()
+            forced, forced_rows = _cell_call(args)
+            np.testing.assert_array_equal(np.asarray(forced),
+                                          np.asarray(top))
+            np.testing.assert_array_equal(np.asarray(forced_rows),
+                                          np.asarray(rows))
+
+
+@pytest.mark.parametrize("name", sorted(ROUTERS))
+def test_route_counts_names_the_rung_the_jitted_layer_took(
+        monkeypatch, own_traces, name):
+    """The host's `rows_computed` against the device's own choice: the
+    row count of the operands that reached the kernel in the branch
+    that ran."""
+    seen, kernel = [], moe.gmm
+
+    def watched(lhs, *rest, **more):
+        jax.debug.callback(lambda: seen.append(lhs.shape[0]))
+        return kernel(lhs, *rest, **more)
+
+    monkeypatch.setattr(moe, "gmm", watched)
+    _out, rows = jax.jit(lambda args: _cell_call(args))(
+        _cell_layer(ROUTERS[name]))
+    jax.effects_barrier()
+    counts = moe.route_counts(np.asarray(rows)[None], CELL["T"], CELL["K"])
+    assert len(seen) == 3 and set(seen) == {counts["rows_computed"]}
+    assert counts["rows_total"] == 256
+    assert counts["rows_held"] <= counts["rows_computed"] <= 256
+
+
+@pytest.mark.parametrize("rows,count,n_experts,tile_m,ladder", [
+    # the cell's four padded lengths, 32 of 256 experts, top-4
+    (24576, 32, 256, 256, (6144, 12288, 24576)),
+    (32768, 32, 256, 256, (8192, 16384, 32768)),
+    (49152, 32, 256, 256, (12288, 24576, 49152)),
+    (65536, 32, 256, 256, (16384, 32768, 65536)),
+    (65536, 128, 256, 256, (65536,)),       # half the experts: one rung
+    (65536, 256, 256, 256, (65536,)),       # all of them
+    (1024, 3, 8, 256, (768, 1024)),         # no power of two
+    (96, 2, 8, 8, (48, 96)), (96, 2, 8, 96, (96,))])
+def test_the_ladder_follows_from_shapes(rows, count, n_experts, tile_m,
+                                        ladder):
+    assert moe._ladder(rows, count, n_experts, tile_m) == ladder
+    assert all(rung % tile_m == 0 for rung in ladder)
+    for held in (0, 1, ladder[0], min(ladder[0] + 1, rows), rows):
+        taken = ladder[moe._rung(held, ladder)]
+        assert taken >= held and taken == min(r for r in ladder if r >= held)
+
+
+def _conditionals(jaxpr) -> int:
+    """`cond` equations of a jaxpr, outside its Pallas kernels (whose
+    `pl.when` is one)."""
+    found = 0
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            continue
+        found += eqn.primitive.name == "cond"
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else (value,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    found += _conditionals(sub)
+    return found
+
+
+@pytest.mark.parametrize("count,branches", [(8, 0), (4, 0), (2, 1), (1, 1)])
+def test_a_call_that_holds_half_the_experts_traces_no_conditional(
+        count, branches):
+    """With every expert held (`count == n_experts`) or half of them
+    the layer is the program it was: one rung, no branch."""
+    rng = np.random.RandomState(0)
+    args = (rng.randn(64, 16), rng.randn(16, 8), np.zeros(8),
+            rng.randn(count, 16, 32), rng.randn(count, 16, 32),
+            rng.randn(count, 32, 16))
+    traced = jax.make_jaxpr(lambda *a: moe.routed_experts(
+        *a, held=(0, count), top_k=2, route_scale=1.0, tile_m=8,
+        interpret=True))(*map(jnp.float32, args))
+    assert _conditionals(traced.jaxpr) == branches
 
 
 def test_padding_rows_leave_real_rows_unchanged():

@@ -89,6 +89,20 @@ def test_long_windowed_flash_compiles_for_tpu(v5e, window):
     ).lower(x, x, x))
 
 
+def _gmm_calls(text):
+    """The compiled program's grouped-matmul calls: (instruction name,
+    operand count) of each."""
+    calls = []
+    for line in text.splitlines():
+        if "tpu_custom_call" in line and " custom-call(" in line:
+            name = line.strip().split(" = ")[0].lstrip("ROT %")
+            operands = line.split(" custom-call(")[1].split(
+                "), custom_call_target")[0]
+            if name.startswith("moe_gmm"):
+                calls.append((name, operands.count("%")))
+    return calls
+
+
 def test_grouped_matmul_compiles_for_tpu(v5e):
     """The routed layer's kernel at the cell's widths: every (token,
     expert) row of a 16,384-token prefill, 32 held experts of 3,072 x
@@ -102,11 +116,72 @@ def test_grouped_matmul_compiles_for_tpu(v5e):
     lowered = jax.jit(gmm).lower(lhs, rhs, rows, rows)
     # (the visits are found by a search, which is a loop of its own: the
     # kernel is told from the interpreter by the compiled call)
-    call = next(line for line in lowered.compile().as_text().splitlines()
-                if "tpu_custom_call" in line)
-    operands = call.split(" custom-call(")[1].split("), custom_call_target")[0]
-    assert operands.count("%") == 7
-    assert call.strip().split(" = ")[0].lstrip("ROT %").startswith("moe_gmm")
+    (call,) = _gmm_calls(lowered.compile().as_text())
+    assert call[0].startswith("moe_gmm") and call[1] == 7
+
+
+@pytest.mark.parametrize("tokens", [6144, 8192, 12288, 16384])
+def test_routed_layer_compiles_with_its_ladder(v5e, tokens):
+    """The Trinity cell's routed layer (32 of 256 experts of 3,072 x
+    3,072, top-4) at the cell's four padded lengths: one conditional
+    over three rungs, and in each branch the three grouped matmuls under
+    the kernel's name, 7 operands to one result, which is how a device
+    profile knows them."""
+    from ray_tpu.ops.moe import routed_experts
+    one = SingleDeviceSharding(v5e[0])
+
+    def arg(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one)
+
+    text = jax.jit(lambda *a: routed_experts(
+        *a, held=(0, 32), top_k=4, route_scale=2.448)).lower(
+        arg(tokens, 3072), arg(3072, 256), arg(256), arg(32, 3072, 3072),
+        arg(32, 3072, 3072), arg(32, 3072, 3072)).compile().as_text()
+    assert text.count(" conditional(") == 1
+    calls = _gmm_calls(text)
+    assert len(calls) == 9 and {n for _name, n in calls} == {7}
+    # every rung's operands are there: rows / 4, rows / 2, rows
+    for rung in (tokens, 2 * tokens, 4 * tokens):
+        assert f"bf16[{rung},3072]" in text
+
+
+def test_trinity_prefill_fits_as_before_the_ladder(v5e):
+    """The cell's longest program whole (16,384 tokens through one dense
+    and four routed layers, weights in bfloat16): a conditional a routed
+    layer, its branches sharing their temporaries, so libtpu's total
+    stays at the parent's 10.785 GB (8.644 of weights + 2.141 of
+    temporaries; PERF.md §6, PR 31) but for the conditional's result,
+    which is held while a branch runs."""
+    import dataclasses
+    import json
+
+    from ray_tpu.models import config_from_hf, forward_with_stats, init_params
+    here = os.path.join(os.path.dirname(__file__), "..", "benchmark",
+                        "configs", "trinity-large-preview-l5-ep8.json")
+    with open(here) as f:
+        config = json.load(f)
+    one = SingleDeviceSharding(v5e[0])
+    cfg = dataclasses.replace(config_from_hf(config, 16384), use_flash=True,
+                              remat=False)
+    params = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, jnp.bfloat16, sharding=one),
+        jax.eval_shape(lambda k: init_params(k, cfg), jax.random.PRNGKey(0)))
+
+    def answer(p, t, last):     # as benchmark/drivers/serve_prefill.py asks
+        logits, stats = forward_with_stats(p, t, cfg, logit_positions=last)
+        return jax.lax.top_k(logits[0], 8), stats["moe_rows"]
+
+    compiled = jax.jit(answer).lower(
+        params, jax.ShapeDtypeStruct((1, 16384), jnp.int32, sharding=one),
+        jax.ShapeDtypeStruct((1,), jnp.int32, sharding=one)).compile()
+    text = compiled.as_text()
+    assert text.count(" conditional(") == 4
+    assert len(_gmm_calls(text)) == 4 * 9
+    memory = compiled.memory_analysis()
+    total = (memory.argument_size_in_bytes + memory.output_size_in_bytes
+             - memory.alias_size_in_bytes + memory.temp_size_in_bytes)
+    assert memory.argument_size_in_bytes == 8_643_876_352
+    assert total <= 10_785_099_264 + 2 * 16384 * 3072     # one [T, D] more
 
 
 def test_flash_compiles_under_a_mesh(v5e):

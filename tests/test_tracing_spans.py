@@ -135,9 +135,10 @@ def test_moe_route_record_counts_the_rows_a_forward_routed(recorder_off):
 
     from ray_tpu.ops.moe import record_route, route_counts
     rows = np.array([[3, 0, 5], [1, 1, 2]])     # two layers, three held
+    # (no layer of these shapes was traced here: the static worst case)
     assert route_counts(rows, tokens=16, top_k=2) == {
-        "layers": 2, "rows_total": 64, "rows_held": 12, "load_max": 5,
-        "load_mean": 2.0}
+        "layers": 2, "rows_total": 64, "rows_held": 12, "rows_computed": 64,
+        "load_max": 5, "load_mean": 2.0}
     record_route(rows, 16, 2, 10, 20)
     assert tracing.spans() == []
     get_config().apply_system_config({"event_log_enabled": True})
@@ -148,6 +149,7 @@ def test_moe_route_record_counts_the_rows_a_forward_routed(recorder_off):
     assert (got.name, got.start_ns, got.end_ns, got.request) == (
         "model.moe.route", 10, 20, "req-3")
     assert got.counts["rows_held"] == 12 and got.counts["load_max"] == 5
+    assert got.counts["rows_computed"] == 64
 
 
 def test_ring_is_bounded_and_drops_the_oldest():
