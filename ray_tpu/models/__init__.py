@@ -7,6 +7,7 @@ pull in optax.
 
 from ray_tpu.models.transformer import (  # noqa: F401
     LayerSpec,
+    SparseSizes,
     TransformerConfig,
     config_from_hf,
     forward,
@@ -14,6 +15,7 @@ from ray_tpu.models.transformer import (  # noqa: F401
     init_params,
     loss_fn,
     param_specs,
+    record_sparse_visits,
 )
 from ray_tpu.models.vit import (  # noqa: F401
     ViTConfig,
@@ -26,10 +28,11 @@ from ray_tpu.models.vit import (  # noqa: F401
 _TRAINING = ("TrainState", "init_state", "make_optimizer",
              "make_train_step", "state_specs")
 
-__all__ = ["LayerSpec", "TransformerConfig", "ViTConfig", "config_from_hf",
-           "forward", "forward_with_stats", "init_params",
-           "init_vit_params", "loss_fn", "param_specs", "vit_forward",
-           "vit_loss_fn", "vit_param_specs", *_TRAINING]
+__all__ = ["LayerSpec", "SparseSizes", "TransformerConfig", "ViTConfig",
+           "config_from_hf", "forward", "forward_with_stats", "init_params",
+           "init_vit_params", "loss_fn", "param_specs",
+           "record_sparse_visits", "vit_forward", "vit_loss_fn",
+           "vit_param_specs", *_TRAINING]
 
 
 def __getattr__(name):

@@ -20,12 +20,23 @@ only the mesh. Design notes:
   beside shared ones (``ray_tpu.ops.moe``: this chip's experts' part,
   no token dropped). The default pattern is "full causal, RoPE, dense"
   repeated: GQA, RoPE, RMSNorm, SwiGLU, the contemporary dense block;
+- a layer's **mixer** is part of its spec: softmax attention as above,
+  ``lightning`` (linear attention with a per-head exponential decay, a
+  chunked scan that carries a state and no keys:
+  ``ray_tpu.ops.lightning_attention``) or ``sparse`` (every query
+  attends to the ``top_k`` key blocks it scores highest, its own
+  window and the first block, once the sequence outgrows
+  ``SparseSizes.dense_len``: ``ray_tpu.ops.sparse_attention``). A spec
+  may name its own number of KV heads;
 - model-wide switches for what some families add to every layer:
   RMSNorm on queries and keys by head, a sigmoid gate on the attention
   output, norms after attention and MLP as well as before (sandwich),
-  an embedding scale; ``head_dim`` and ``rms_norm_eps`` are fields;
+  an RMSNorm over a lightning layer's merged heads, an embedding scale,
+  a residual scale and a logit scale (muP); ``head_dim`` and
+  ``rms_norm_eps`` are fields;
 - ``config_from_hf`` reads a published ``config.json``'s keys (the
-  ``mistral`` and ``afmoe`` families) into a ``TransformerConfig``;
+  ``mistral``, ``afmoe`` and ``minicpm_sala`` families) into a
+  ``TransformerConfig``;
 - attention runs through ``ray_tpu.ops.attention`` which dispatches to
   the ring-attention path when the mesh has a nontrivial ``sp`` axis.
 
@@ -54,6 +65,31 @@ class LayerSpec:
     window: Optional[int] = None    # keys a query sees; None: all before it
     rope: bool = True               # False: no position encoding (NoPE)
     experts: bool = False           # routed + shared experts, else dense MLP
+    mixer: str = "softmax"          # or "lightning", "sparse"
+    kv_heads: Optional[int] = None  # None: the model's n_kv_heads
+
+
+MIXERS = ("softmax", "lightning", "sparse")
+
+
+@dataclasses.dataclass(frozen=True)
+class SparseSizes:
+    """The sizes of a ``sparse`` layer (InfLLM-V2 as MiniCPM4 ships it;
+    ``ops/sparse_attention.py`` says what each does)."""
+    kernel: int = 32        # tokens a compressed key is the mean of
+    stride: int = 16        # tokens between compressed keys
+    block: int = 64         # keys a block
+    top_k: int = 64         # blocks a query attends to, forced included
+    window: int = 2048      # the latest keys, always taken (whole blocks)
+    init_blocks: int = 1    # the first blocks, always taken
+    dense_len: int = 8192   # up to this length: plain causal attention
+
+    def __post_init__(self):
+        if (self.kernel % self.stride or self.block % self.stride
+                or self.window % self.block or self.window < self.block
+                or self.init_blocks + self.window // self.block
+                > self.top_k):
+            raise ValueError(f"sparse sizes do not fit together: {self}")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -82,6 +118,10 @@ class TransformerConfig:
     attn_gate: bool = False      # attention output * sigmoid(h @ wgate)
     sandwich_norm: bool = False  # norms after attention and MLP too
     embed_scale: float = 1.0     # the embedding's multiplier (muP)
+    residual_scale: float = 1.0  # each half-layer's result, before the add
+    logit_scale: float = 1.0     # the final norm's result, before the head
+    mixer_out_norm: bool = False  # RMSNorm over a lightning layer's heads
+    sparse: SparseSizes = SparseSizes()     # the ``sparse`` layers' sizes
     # Routed experts, for the layers whose spec asks for them. The
     # router is ``n_experts`` wide (the published count) whatever is
     # held here: ``experts_held = (first, count)``.
@@ -102,6 +142,10 @@ class TransformerConfig:
         if len(self.layers) != self.n_layers:
             raise ValueError(f"{len(self.layers)} layer specs for "
                              f"{self.n_layers} layers")
+        for spec in self.layers:
+            if spec.mixer not in MIXERS or (
+                    spec.mixer != "softmax" and spec.window is not None):
+                raise ValueError(f"no such layer: {spec}")
         if any(spec.experts for spec in self.layers):
             first, count = self.experts_held
             if not (0 < self.expert_top_k <= self.n_experts
@@ -120,11 +164,17 @@ def config_from_hf(config: dict, max_seq_len: int) -> TransformerConfig:
     family (``layer_types`` of window and global layers, the global
     ones without RoPE; leading dense layers, then routed experts beside
     shared ones; sandwich norms, q/k norms, gated attention, muP
-    embedding scale). A window that no sequence of ``max_seq_len``
-    outgrows is causal attention and is dropped. ``expert_parallel``
-    ``{"size", "rank"}``, where given, says that ``num_experts`` counts
-    the experts held here, the ``rank``-th of ``size`` equal shares of
-    the router's width."""
+    embedding scale) and the ``minicpm_sala`` family (``mixer_types`` of
+    ``minicpm4`` block-sparse and ``lightning-attn`` linear-attention
+    layers, each with its own KV heads and RoPE switch; q/k norms, an
+    output gate, an output norm on the lightning layers, MiniCPM's muP
+    scalars; ``sparse_config``, where given, names the sparse layers'
+    sizes, and ``published.num_hidden_layers`` the depth the residual
+    scale is reckoned from where the file holds a slice). A window that
+    no sequence of ``max_seq_len`` outgrows is causal attention and is
+    dropped. ``expert_parallel`` ``{"size", "rank"}``, where given, says
+    that ``num_experts`` counts the experts held here, the ``rank``-th
+    of ``size`` equal shares of the router's width."""
     family = config["model_type"]
     n_layers = config["num_hidden_layers"]
     window = config.get("sliding_window")
@@ -142,9 +192,11 @@ def config_from_hf(config: dict, max_seq_len: int) -> TransformerConfig:
     if family == "mistral":
         return TransformerConfig(
             **common, layers=(LayerSpec(window=window),) * n_layers)
+    if family == "minicpm_sala":
+        return _sala_config(config, common)
     if family != "afmoe":
-        raise ValueError(f"config_from_hf knows the model types 'mistral' "
-                         f"and 'afmoe', not {family!r}")
+        raise ValueError(f"config_from_hf knows the model types 'mistral', "
+                         f"'afmoe' and 'minicpm_sala', not {family!r}")
     share = config.get("expert_parallel", {"size": 1, "rank": 0})
     held = config["num_experts"]
     layers = tuple(
@@ -163,6 +215,39 @@ def config_from_hf(config: dict, max_seq_len: int) -> TransformerConfig:
         d_ff_expert=config["moe_intermediate_size"],
         n_shared_experts=config.get("num_shared_experts", 0),
         route_scale=float(config.get("route_scale", 1.0)))
+
+
+# MiniCPM4's ``sparse_config`` keys as ``SparseSizes`` names them
+_SPARSE_KEYS = {"kernel_size": "kernel", "kernel_stride": "stride",
+                "block_size": "block", "topk": "top_k",
+                "window_size": "window", "init_blocks": "init_blocks",
+                "dense_len": "dense_len"}
+
+
+def _sala_config(config: dict, common: dict) -> TransformerConfig:
+    kinds = {"minicpm4": LayerSpec(mixer="sparse",
+                                   rope=config["attn_use_rope"]),
+             "lightning-attn": LayerSpec(mixer="lightning",
+                                         rope=config["lightning_use_rope"],
+                                         kv_heads=config["lightning_nkv"])}
+    if (config["lightning_nh"] != config["num_attention_heads"]
+            or config["lightning_head_dim"] != config["head_dim"]
+            or config["use_output_gate"] != config["attn_use_output_gate"]):
+        raise ValueError("config_from_hf reads a minicpm_sala model whose "
+                         "two kinds of layer share heads, head size and "
+                         "the output gate")
+    depth = config.get("published", {}).get("num_hidden_layers",
+                                            config["num_hidden_layers"])
+    return TransformerConfig(
+        **common, layers=tuple(kinds[k] for k in config["mixer_types"]),
+        qk_norm=config["qk_norm"], attn_gate=config["use_output_gate"],
+        mixer_out_norm=config["use_output_norm"],
+        embed_scale=float(config["scale_emb"]),
+        residual_scale=float(config["scale_depth"] / np.sqrt(depth)),
+        logit_scale=config["dim_model_base"] / config["hidden_size"],
+        sparse=SparseSizes(**{
+            _SPARSE_KEYS[key]: size for key, size in
+            config.get("sparse_config", {}).items()}))
 
 
 # --------------------------------------------------------------------------
@@ -187,11 +272,12 @@ def init_params(key: jax.Array, cfg: TransformerConfig) -> Dict:
     }
     for i, spec in enumerate(cfg.layers):
         bk = jax.random.split(keys[i + 1], 8)
+        kv = spec.kv_heads or cfg.n_kv_heads
         block = {
             "attn_norm": ones(d),
             "wq": _dense_init(bk[0], (d, cfg.n_heads, hd)),
-            "wk": _dense_init(bk[1], (d, cfg.n_kv_heads, hd)),
-            "wv": _dense_init(bk[2], (d, cfg.n_kv_heads, hd)),
+            "wk": _dense_init(bk[1], (d, kv, hd)),
+            "wv": _dense_init(bk[2], (d, kv, hd)),
             "wo": _dense_init(bk[3], (cfg.n_heads, hd, d), in_axis=(0, 1)),
             "mlp_norm": ones(d),
         }
@@ -199,6 +285,8 @@ def init_params(key: jax.Array, cfg: TransformerConfig) -> Dict:
             block.update(q_norm=ones(hd), k_norm=ones(hd))
         if cfg.attn_gate:
             block["wgate"] = _dense_init(bk[7], (d, cfg.n_heads, hd))
+        if cfg.mixer_out_norm and spec.mixer == "lightning":
+            block["out_norm"] = ones(cfg.n_heads * hd)
         if cfg.sandwich_norm:
             block.update(post_attn_norm=ones(d), post_mlp_norm=ones(d))
         if spec.experts:
@@ -246,6 +334,8 @@ def param_specs(cfg: TransformerConfig) -> Dict:
             block.update(q_norm=P(None), k_norm=P(None))
         if cfg.attn_gate:
             block["wgate"] = P("fsdp", "tp", None)
+        if cfg.mixer_out_norm and spec.mixer == "lightning":
+            block["out_norm"] = P(None)
         if cfg.sandwich_norm:
             block.update(post_attn_norm=P(None), post_mlp_norm=P(None))
         if spec.experts:
@@ -350,6 +440,82 @@ def _experts_mlp(block, h, cfg: TransformerConfig):
     return routed, rows
 
 
+def _mixer(q, k, v, spec: LayerSpec, cfg: TransformerConfig):
+    """A ``lightning`` layer's mixer, or a ``sparse`` one's past
+    ``dense_len``: ``q [B, S, N, H]`` and ``k, v`` at the layer's KV
+    heads -> (``[B, S, N, H]``, the units of keys the sparse kernel
+    visited, or None). Under ``use_flash`` the Pallas kernels, else
+    their plain references, as softmax attention has it."""
+    if spec.mixer == "lightning":
+        from ray_tpu.ops.lightning_attention import (
+            decay_slopes, lightning_attention, lightning_reference)
+        fn = lightning_attention if cfg.use_flash else lightning_reference
+        return fn(q, k, v, decay_slopes(cfg.n_heads)), None
+    from ray_tpu.ops.sparse_attention import (
+        select_blocks_reference, selected_attention, sparse_reference)
+    if cfg.use_flash:
+        return selected_attention(q, k, v, cfg.sparse)
+    return sparse_reference(
+        q, k, v, select_blocks_reference(q, k, cfg.sparse),
+        cfg.sparse), None
+
+
+def _sparse_keys(cfg: TransformerConfig, batch: int, seq: int) -> dict:
+    """``ops.sparse_attention.keys_counted`` summed over the sparse
+    layers, their KV groups and the batch (``visit_pairs`` as it is)."""
+    from ray_tpu.ops.sparse_attention import keys_counted
+    groups = sum(spec.kv_heads or cfg.n_kv_heads for spec in cfg.layers
+                 if spec.mixer == "sparse") * batch
+    return {name: count if name == "visit_pairs" else count * groups
+            for name, count in keys_counted(seq, cfg.sparse).items()}
+
+
+def _record_mixers_plan(cfg: TransformerConfig, batch: int, seq: int):
+    """One ``model.mixers.plan`` record for the forward being traced, if
+    the pattern holds a lightning or a sparse layer: what the mixers do
+    at this shape, from shapes alone (docs/tracing.md)."""
+    kinds = [spec.mixer for spec in cfg.layers]
+    linear, sparse = kinds.count("lightning"), kinds.count("sparse")
+    if not linear and not sparse:
+        return
+    import time
+
+    from ray_tpu.ops.lightning_attention import choose_chunk
+    from ray_tpu.util import tracing
+    sparse_mode = int(sparse > 0 and seq > cfg.sparse.dense_len)
+    keys = _sparse_keys(cfg, batch, seq)
+    now = time.perf_counter_ns()
+    tracing.record(
+        "model.mixers.plan", now, now, tokens=batch * seq,
+        linear_layers=linear, sparse_layers=sparse, sparse_mode=sparse_mode,
+        chunk=choose_chunk(seq) if linear else 0,
+        state_bytes=linear * cfg.n_heads * cfg.head_dim ** 2 * 4,
+        **{name: keys[name] if sparse_mode else 0
+           for name in ("keys_selected", "keys_causal")})
+
+
+def record_sparse_visits(units, cfg: TransformerConfig, batch: int,
+                         seq: int, request: Optional[str] = None) -> None:
+    """One ``model.sparse.visits`` record for a forward whose
+    ``stats["sparse_units"]`` came back with its logits (``units``, on
+    the host), as ``ops.moe.record_route`` makes ``model.moe.route``:
+    ``keys_read`` is what the visits fetched, a unit's keys for each
+    query of the tile (docs/tracing.md)."""
+    import time
+
+    from ray_tpu.util import tracing
+    counted = _sparse_keys(cfg, batch, seq)
+    visited = int(np.sum(units))
+    now = time.perf_counter_ns()
+    tracing.record(
+        "model.sparse.visits", now, now, request, tokens=batch * seq,
+        layers=int(np.size(units)), units_visited=visited,
+        units_before=counted["units_before"],
+        keys_read=visited * counted["visit_pairs"],
+        keys_selected=counted["keys_selected"],
+        keys_causal=counted["keys_causal"])
+
+
 def _block_forward(block, x, positions, cfg: TransformerConfig,
                    attn_fn=None):
     """The dense block (full causal attention, RoPE, SwiGLU) that the
@@ -361,7 +527,8 @@ def _block_forward(block, x, positions, cfg: TransformerConfig,
 def _layer_forward(block, x, positions, spec: LayerSpec,
                    cfg: TransformerConfig, attn_fn):
     """One layer of the pattern. -> (x, the rows each held expert was
-    given, or None)."""
+    given, or None, the units of keys a sparse kernel visited, or
+    None)."""
     dt, eps = cfg.dtype, cfg.rms_norm_eps
     h = rms_norm(x, block["attn_norm"], eps)
     q = jnp.einsum("bsd,dnh->bsnh", h, block["wq"].astype(dt))
@@ -373,23 +540,35 @@ def _layer_forward(block, x, positions, spec: LayerSpec,
     if spec.rope:
         q = rope(q, positions, cfg.rope_theta)
         k = rope(k, positions, cfg.rope_theta)
-    # named at their KV heads, before the repeat: what a remat plan
-    # keeps of them is a quarter of what the kernel is given
-    q, k, v = (checkpoint_name(a, name) for a, name in
-               ((q, "attn_q"), (k, "attn_k"), (v, "attn_v")))
-    # GQA: repeat kv heads up to n_heads.
-    rep = cfg.n_heads // cfg.n_kv_heads
-    if rep > 1:
-        k = jnp.repeat(k, rep, axis=2)
-        v = jnp.repeat(v, rep, axis=2)
-    attn = attn_fn(q, k, v) if spec.window is None else \
-        attn_fn(q, k, v, window=spec.window)
+    # a sparse layer is plain causal attention up to ``dense_len``
+    if spec.mixer == "softmax" or (
+            spec.mixer == "sparse" and x.shape[1] <= cfg.sparse.dense_len):
+        # named at their KV heads, before the repeat: what a remat plan
+        # keeps of them is a quarter of what the kernel is given
+        q, k, v = (checkpoint_name(a, name) for a, name in
+                   ((q, "attn_q"), (k, "attn_k"), (v, "attn_v")))
+        # GQA: repeat kv heads up to n_heads.
+        rep = cfg.n_heads // k.shape[2]
+        if rep > 1:
+            k = jnp.repeat(k, rep, axis=2)
+            v = jnp.repeat(v, rep, axis=2)
+        attn = attn_fn(q, k, v) if spec.window is None else \
+            attn_fn(q, k, v, window=spec.window)
+        visited = None
+    else:
+        attn, visited = _mixer(q, k, v, spec, cfg)
+        if cfg.mixer_out_norm and spec.mixer == "lightning":
+            attn = rms_norm(attn.reshape(*x.shape[:2], -1),
+                            block["out_norm"], eps).reshape(attn.shape)
     if cfg.attn_gate:
         gate = jnp.einsum("bsd,dnh->bsnh", h, block["wgate"].astype(dt))
         attn = attn * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(dt)
     attn = jnp.einsum("bsnh,nhd->bsd", attn, block["wo"].astype(dt))
     if cfg.sandwich_norm:
         attn = rms_norm(attn, block["post_attn_norm"], eps)
+    scaled = cfg.residual_scale != 1.0
+    if scaled:
+        attn = attn * jnp.asarray(cfg.residual_scale, dt)
     x = x + attn
 
     h = rms_norm(x, block["mlp_norm"], eps)
@@ -400,7 +579,9 @@ def _layer_forward(block, x, positions, spec: LayerSpec,
         f = _swiglu(h, block["wg"], block["wi"], block["wo_mlp"], dt)
     if cfg.sandwich_norm:
         f = rms_norm(f, block["post_mlp_norm"], eps)
-    return x + f, rows
+    if scaled:
+        f = f * jnp.asarray(cfg.residual_scale, dt)
+    return x + f, rows, visited
 
 
 class RematPlan(NamedTuple):
@@ -428,7 +609,7 @@ def _layer_params(cfg: TransformerConfig, spec: LayerSpec) -> int:
     """The layer's matrices' parameters (norm scales left out)."""
     d, hd = cfg.d_model, cfg.head_dim
     attention = d * hd * ((2 + cfg.attn_gate) * cfg.n_heads
-                          + 2 * cfg.n_kv_heads)
+                          + 2 * (spec.kv_heads or cfg.n_kv_heads))
     if not spec.experts:
         return attention + 3 * d * cfg.d_ff
     return attention + d * cfg.n_experts + 3 * d * cfg.d_ff_expert * (
@@ -448,7 +629,7 @@ def _layer_counts(cfg: TransformerConfig, spec: LayerSpec, b: int, s: int,
     it = jnp.dtype(cfg.dtype).itemsize
     t, d, hd = b * s, cfg.d_model, cfg.head_dim
     n, kv, f = (max(w // tp, 1) for w in
-                (cfg.n_heads, cfg.n_kv_heads, cfg.d_ff))
+                (cfg.n_heads, spec.kv_heads or cfg.n_kv_heads, cfg.d_ff))
     wide, heads, lse = t * d * it, t * n * hd * it, b * n * s * 4
     attn_kept = 2 * heads + 2 * t * kv * hd * it + lse
     mlp_kept = 2 * t * f * it
@@ -457,6 +638,10 @@ def _layer_counts(cfg: TransformerConfig, spec: LayerSpec, b: int, s: int,
     kept = (wide, wide + attn_kept, wide + attn_kept + mlp_kept,
             4 * wide + 4 * heads + lse + mlp_kept + extra)
     seen = s / 2 if spec.window is None or spec.window >= s else spec.window
+    if spec.mixer == "lightning":       # the state's form: H a token
+        seen = hd / 2
+    elif spec.mixer == "sparse" and s > cfg.sparse.dense_len:
+        seen = cfg.sparse.top_k * cfg.sparse.block
     qkv = 2 * t * d * hd * ((1 + cfg.attn_gate) * n + 2 * kv)
     attention = 2 * 2 * t * n * hd * seen
     out = 2 * t * n * hd * d
@@ -497,9 +682,11 @@ def remat_plan(cfg: TransformerConfig, batch: int, seq: int,
     the backward pass at each layer, what the earlier layers keep,
     this layer whole (kept or recomputed) and the gradients from this
     layer on, which wait in the compute type until the clip has seen
-    them all; at the end, every gradient. Layers of routed experts
-    keep nothing (they cannot train yet), and dense S x S attention is
-    not counted: without ``use_flash`` nothing is kept."""
+    them all; at the end, every gradient. Layers of routed experts and
+    layers whose mixer is not softmax attention keep nothing (they
+    cannot train yet: their kernels have no backward and say so by name
+    under ``jax.grad``), and dense S x S attention is not counted:
+    without ``use_flash`` nothing is kept."""
     shards = shards or {}
     tp = shards.get("tp", 1)
     # fsdp shards the parameters; whether it divides the activations is
@@ -532,7 +719,8 @@ def remat_plan(cfg: TransformerConfig, batch: int, seq: int,
         levels = [0] * cfg.n_layers
         raises = [(level, i) for level in range(1, KEEP_LAYER + 1)
                   for i in range(cfg.n_layers)
-                  if not cfg.layers[i].experts]
+                  if not cfg.layers[i].experts
+                  and cfg.layers[i].mixer == "softmax"]
         for level, i in raises if room and cfg.use_flash else ():
             trial = levels[:i] + [level] + levels[i + 1:]
             if peak(trial) > room:
@@ -557,12 +745,17 @@ def forward_with_stats(params, tokens: jax.Array, cfg: TransformerConfig,
     the last position's alone). ``stats["moe_rows"]`` [routed layers,
     held experts] int32: the rows each held expert was given, which
     ``ops.moe.record_route`` turns into the ``model.moe.route`` record
-    once they are on the host with the logits. An ``attn_fn`` given
-    from outside is called ``attn_fn(q, k, v)``, with ``window=`` on a
-    layer that has one. ``remat_levels``, one a layer, say what each
-    keeps for a backward pass (``REMAT_KEEPS``; None: nothing under
-    ``cfg.remat``, everything without); a forward alone is the same
-    program at every level."""
+    once they are on the host with the logits. Where sparse layers ran
+    their kernels, ``stats["sparse_units"]`` [sparse layers] int32: the
+    units of keys each one's attention visited, known on the device
+    alone, which ``record_sparse_visits`` turns into the
+    ``model.sparse.visits`` record the same way (a caller that does not
+    ask for them pays nothing: the count is dropped from its program).
+    An ``attn_fn`` given from outside is called ``attn_fn(q, k, v)``,
+    with ``window=`` on a layer that has one. ``remat_levels``, one a
+    layer, say what each keeps for a backward pass (``REMAT_KEEPS``;
+    None: nothing under ``cfg.remat``, everything without); a forward
+    alone is the same program at every level."""
     if positions is None:
         positions = jnp.broadcast_to(
             jnp.arange(tokens.shape[1], dtype=jnp.int32)[None, :],
@@ -584,7 +777,8 @@ def forward_with_stats(params, tokens: jax.Array, cfg: TransformerConfig,
     # as long to set up).
     if remat_levels is None:
         remat_levels = (0 if cfg.remat else KEEP_LAYER,) * cfg.n_layers
-    layer_fns, moe_rows = {}, []
+    _record_mixers_plan(cfg, *tokens.shape)
+    layer_fns, moe_rows, visits = {}, [], []
     for block, spec, level in zip(params["blocks"], cfg.layers,
                                   remat_levels):
         blk = layer_fns.get((spec, level))
@@ -603,17 +797,23 @@ def forward_with_stats(params, tokens: jax.Array, cfg: TransformerConfig,
                 # ``remat=False`` stays the bare function it was
                 blk = jax.jit(blk)
             layer_fns[spec, level] = blk
-        x, rows = blk(block, x, positions)
+        x, rows, visited = blk(block, x, positions)
         if rows is not None:
             moe_rows.append(rows)
+        if visited is not None:
+            visits.append(visited)
     if logit_positions is not None:
         x = jnp.take_along_axis(x, logit_positions[:, None, None],
                                 axis=1)[:, 0]
     x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
+    if cfg.logit_scale != 1.0:
+        x = x * jnp.asarray(cfg.logit_scale, cfg.dtype)
     logits = (x @ params["unembed"].astype(cfg.dtype)).astype(jnp.float32)
     held = cfg.experts_held[1]
     stats = {"moe_rows": jnp.stack(moe_rows) if moe_rows
              else jnp.zeros((0, held), jnp.int32)}
+    if visits:
+        stats["sparse_units"] = jnp.stack(visits)
     return logits, stats
 
 

@@ -184,6 +184,77 @@ def test_trinity_prefill_fits_as_before_the_ladder(v5e):
     assert total <= 10_785_099_264 + 2 * 16384 * 3072     # one [T, D] more
 
 
+# the MiniCPM-SALA cell: 32 query heads of 128, 32 KV heads on a
+# lightning layer and 2 on a sparse one, its shortest and longest program
+@pytest.mark.parametrize("tokens", [12288, 32768])
+def test_the_mixers_kernels_compile_for_tpu(v5e, tokens):
+    """The chunked scan with its state in VMEM, the choice of blocks and
+    the attention over them (a group's K and V whole in VMEM: 16 MB at
+    32,768 tokens), each a kernel the program names."""
+    from ray_tpu.models import SparseSizes
+    from ray_tpu.ops.lightning_attention import (
+        decay_slopes, lightning_attention)
+    from ray_tpu.ops.sparse_attention import selected_attention
+    one = SingleDeviceSharding(v5e[0])
+    q = jax.ShapeDtypeStruct((1, tokens, 32, 128), jnp.bfloat16, sharding=one)
+    kv = jax.ShapeDtypeStruct((1, tokens, 2, 128), jnp.bfloat16,
+                              sharding=one)
+    scan = jax.jit(lambda q, k, v: lightning_attention(
+        q, k, v, decay_slopes(32))).lower(q, q, q)
+    _assert_kernel_not_interpreter(scan)
+    assert "lightning_attn" in scan.as_text()
+    sparse = jax.jit(lambda q, k, v: selected_attention(
+        q, k, v, SparseSizes())).lower(q, kv, kv)
+    _assert_kernel_not_interpreter(sparse)
+    text = sparse.compile().as_text()
+    calls = [line.split(" = ")[0].strip().lstrip("%") for line in
+             text.splitlines() if 'custom_call_target="tpu_custom_call"'
+             in line]
+    assert [c.rsplit(".", 1)[0] for c in calls] == ["sparse_select",
+                                                    "sparse_attn"]
+
+
+def test_minicpm_sala_prefill_fits_one_chip(v5e):
+    """The cell's longest program whole (32,768 tokens through two
+    sparse and six lightning layers at published widths, the whole
+    vocabulary, weights in bfloat16): 5.64 GB of weights and what the
+    forward holds beside them stay under the chip's 16 GB."""
+    import dataclasses
+    import json
+
+    from ray_tpu.models import config_from_hf, forward_with_stats, init_params
+    here = os.path.join(os.path.dirname(__file__), "..", "benchmark",
+                        "configs", "minicpm-sala-l8.json")
+    with open(here) as f:
+        config = json.load(f)
+    one = SingleDeviceSharding(v5e[0])
+    cfg = dataclasses.replace(config_from_hf(config, 32768), use_flash=True,
+                              remat=False)
+    params = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, jnp.bfloat16, sharding=one),
+        jax.eval_shape(lambda k: init_params(k, cfg), jax.random.PRNGKey(0)))
+
+    def answer(p, t, last):     # as benchmark/drivers/serve_prefill.py asks
+        logits, stats = forward_with_stats(p, t, cfg, logit_positions=last)
+        return jax.lax.top_k(logits[0], 8), stats["moe_rows"]
+
+    compiled = jax.jit(answer).lower(
+        params, jax.ShapeDtypeStruct((1, 32768), jnp.int32, sharding=one),
+        jax.ShapeDtypeStruct((1,), jnp.int32, sharding=one)).compile()
+    text = compiled.as_text()
+    for kernel, calls in (("lightning_attn", 6), ("sparse_select", 2),
+                          ("sparse_attn", 2)):
+        assert sum(1 for line in text.splitlines()
+                   if line.lstrip().startswith(f"%{kernel}.")
+                   and "tpu_custom_call" in line) == calls, kernel
+    memory = compiled.memory_analysis()
+    total = (memory.argument_size_in_bytes + memory.output_size_in_bytes
+             - memory.alias_size_in_bytes + memory.temp_size_in_bytes)
+    # the weights, the tokens and a position
+    assert 0 <= memory.argument_size_in_bytes - 2 * 2_820_569_088 < 2 ** 18
+    assert total < 13e9, total
+
+
 def test_flash_compiles_under_a_mesh(v5e):
     """The partitioner refuses a bare Mosaic kernel; under a mesh the
     kernel runs per device on its batch/head shard."""
