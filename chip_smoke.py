@@ -421,10 +421,17 @@ def _check_flash_against_reference(shape, seed: int = 0) -> dict:
     import jax
     import jax.numpy as jnp
 
-    from ray_tpu.ops.flash_attention import flash_attention, mha_reference
+    from ray_tpu.ops.flash_attention import (
+        flash_attention, mha_reference, repeat_kv)
 
+    # K and V at a quarter of the query heads, the model's own ratio:
+    # the kernel serves a KV group a step, the reference is given the
+    # repeated K and V (its dk and dv are then the sums over a group)
+    b, s, n, h = shape
     keys = jax.random.split(jax.random.PRNGKey(seed), 4)
-    q, k, v, w = (jax.random.normal(kk, shape, jnp.float32) for kk in keys)
+    q, k, v, w = (jax.random.normal(kk, (b, s, heads, h), jnp.float32)
+                  for kk, heads in zip(keys, (n, max(1, n // 4),
+                                              max(1, n // 4), n)))
     q, k, v = (x.astype(jnp.bfloat16) for x in (q, k, v))
 
     def flash_loss(q, k, v):
@@ -433,7 +440,8 @@ def _check_flash_against_reference(shape, seed: int = 0) -> dict:
 
     def ref_loss(q, k, v):
         with jax.default_matmul_precision("highest"):
-            out = mha_reference(*(x.astype(jnp.float32) for x in (q, k, v)))
+            q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
+            out = mha_reference(q, *repeat_kv(k, v, n))
         return jnp.sum(out * w), out
 
     grad = lambda f: jax.jit(jax.grad(f, argnums=(0, 1, 2), has_aux=True))

@@ -402,8 +402,12 @@ def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
 
 def _attention(q, k, v, *, causal: bool = True,
                window: Optional[int] = None):
-    """Plain blockless attention — the sp=1 path. [B,S,N,Hd] layout.
-    Ring attention (sp>1) is dispatched above this, in ops.attention."""
+    """Plain blockless attention — the sp=1 path. ``q [B,S,N,Hd]``,
+    ``k, v`` at their KV heads (GQA), repeated here up to the query
+    heads. Ring attention (sp>1) is dispatched above this, in
+    ops.attention."""
+    from ray_tpu.ops.flash_attention import repeat_kv
+    k, v = repeat_kv(k, v, q.shape[2])
     scale = 1.0 / np.sqrt(q.shape[-1])
     logits = jnp.einsum("bqnh,bknh->bnqk", q, k) * scale
     if causal:
@@ -543,15 +547,12 @@ def _layer_forward(block, x, positions, spec: LayerSpec,
     # a sparse layer is plain causal attention up to ``dense_len``
     if spec.mixer == "softmax" or (
             spec.mixer == "sparse" and x.shape[1] <= cfg.sparse.dense_len):
-        # named at their KV heads, before the repeat: what a remat plan
-        # keeps of them is a quarter of what the kernel is given
+        # GQA: k and v go to ``attn_fn`` at their KV heads, as the
+        # projections left them. The flash kernel serves a KV group a
+        # grid step (ops/flash_attention.py); a path that needs equal
+        # head counts repeats inside itself.
         q, k, v = (checkpoint_name(a, name) for a, name in
                    ((q, "attn_q"), (k, "attn_k"), (v, "attn_v")))
-        # GQA: repeat kv heads up to n_heads.
-        rep = cfg.n_heads // k.shape[2]
-        if rep > 1:
-            k = jnp.repeat(k, rep, axis=2)
-            v = jnp.repeat(v, rep, axis=2)
         attn = attn_fn(q, k, v) if spec.window is None else \
             attn_fn(q, k, v, window=spec.window)
         visited = None
@@ -622,8 +623,8 @@ def _layer_counts(cfg: TransformerConfig, spec: LayerSpec, b: int, s: int,
     over ``tp`` shards -> (bytes kept by level, forward FLOPs the
     backward runs again by level, the forward's FLOPs). Level 0 keeps
     the layer's input alone; KEEP_LAYER what XLA holds of an unwrapped
-    layer: the input, both norms' results and the sum between them, q
-    and the *repeated* k and v as the kernel was given them, its
+    layer: the input, both norms' results and the sum between them, q,
+    k and v at their KV heads as the kernel is given them, its
     result and log-sum-exp, gate and up (the silu and the product fuse
     into the down projection)."""
     it = jnp.dtype(cfg.dtype).itemsize
@@ -636,7 +637,7 @@ def _layer_counts(cfg: TransformerConfig, spec: LayerSpec, b: int, s: int,
     extra = (cfg.qk_norm * (heads + t * kv * hd * it)
              + cfg.attn_gate * 2 * heads + cfg.sandwich_norm * 2 * wide)
     kept = (wide, wide + attn_kept, wide + attn_kept + mlp_kept,
-            4 * wide + 4 * heads + lse + mlp_kept + extra)
+            4 * wide + attn_kept + mlp_kept + extra)
     seen = s / 2 if spec.window is None or spec.window >= s else spec.window
     if spec.mixer == "lightning":       # the state's form: H a token
         seen = hd / 2
@@ -751,8 +752,9 @@ def forward_with_stats(params, tokens: jax.Array, cfg: TransformerConfig,
     alone, which ``record_sparse_visits`` turns into the
     ``model.sparse.visits`` record the same way (a caller that does not
     ask for them pays nothing: the count is dropped from its program).
-    An ``attn_fn`` given from outside is called ``attn_fn(q, k, v)``,
-    with ``window=`` on a layer that has one. ``remat_levels``, one a
+    An ``attn_fn`` given from outside is called ``attn_fn(q, k, v)``
+    with k and v at their KV heads, and with ``window=`` on a layer
+    that has one. ``remat_levels``, one a
     layer, say what each keeps for a backward pass (``REMAT_KEEPS``;
     None: nothing under ``cfg.remat``, everything without); a forward
     alone is the same program at every level."""

@@ -9,11 +9,30 @@ depends on its shape (``benchmark/costs.py::flash_cost``; at S 4096
 it is the FLOPs), and how far the kernels stand from that bound is
 measured, not stated here: PERF.md §5.
 
-Forward is the Pallas kernel (grid over [batch×heads, query blocks],
-KV streamed through VMEM in blocks, saving only (O, LSE) residuals);
-backward is a Pallas FlashAttention-2 backward — blockwise dq/dk/dv
+Forward is one Pallas kernel that serves a KV group a grid step
+(grouped-query attention: ``q [B, S, N, H]``, ``k, v [B, S, G, H]``,
+``G`` dividing ``N``; query head ``n`` reads KV head ``n // (N / G)``).
+The grid is (batch, KV head, query block). A step holds the group's K
+and V whole in VMEM, fetched once a group, and walks their tiles once;
+on each tile it builds what decides visibility (causal, window, true
+length) once, as an additive bias, then serves the group's ``N / G``
+query heads one after another against the same tile of keys and
+values. Score tiles are held transposed, ``[block_k, block_q]``: a
+head's running maximum and normalizer are one lane-dense row, their
+reductions run down the sublanes, and the result accumulates as
+``[H, block_q]``, transposed once a query block when it is written.
+The kernel is given q heads-first, ``[B, N, S, H]``, and K and V at
+their KV heads, ``[B, G, S, H]``, and finds a group's heads through the
+block index: no repeat. (The transposes fuse into their neighbours in
+the models' programs; reading ``[B, S, heads * H]`` as the projections
+leave it is a change of tiled layout there, a copy of its own: PERF.md
+§6, PR 34.) Only (O, LSE) are saved.
+
+Backward is a Pallas FlashAttention-2 backward — blockwise dq/dk/dv
 recomputed from (O, LSE), so no S×S probability matrix ever touches
-HBM in either direction. Gradients are exact (grad-checked against the
+HBM in either direction. Its two kernels take equal head counts: K and
+V are repeated up to the query heads for them and dk, dv summed over a
+group afterwards. Gradients are exact (grad-checked against the
 dense reference in tests/test_attention.py, on real TPU lowering too).
 
 Arithmetic: every matmul takes its operands in the type the caller
@@ -23,11 +42,13 @@ float32); P and dS are rounded to the input type before their
 products, as the dense path rounds its probabilities to the value
 type. Softmax statistics, LSE, delta and the accumulators are float32
 whatever the input; float32 inputs keep float32 products throughout.
+A hidden score is ``_MASKED`` whatever it was (the bias swallows it),
+so its ``exp`` is exactly 0.
 
 Block sizes come from the shape (``_choose_blocks``) unless the caller
-names them. Every tile builds its mask: building it only on the tiles
-that cross the diagonal or the true length measured slower (PERF.md
-§6, PR 26).
+names them. Every tile builds its bias, once for the group: building
+it only on the tiles that cross the diagonal or the true length
+measured slower in the kernel's earlier form (PERF.md §6, PR 26).
 
 TPU alignment (Mosaic): dynamic VMEM loads must sit at provably
 8-aligned rows and block shapes must tile to (8, 128), so sequences
@@ -37,7 +58,7 @@ kernel (a clamped start index cannot be statically proven aligned),
 and the LSE/delta vectors carry a singleton middle axis so their
 blocks satisfy the tiling rule.
 
-Layout everywhere: [B, S, N, H].
+Layout everywhere: [B, S, heads, H].
 """
 
 from __future__ import annotations
@@ -75,6 +96,16 @@ def mha_reference(q, k, v, *, causal: bool = True,
         logits = jnp.where(mask[None, None], logits, -1e30)
     probs = jax.nn.softmax(logits, axis=-1)
     return jnp.einsum("bnqk,bknh->bqnh", probs.astype(v.dtype), v)
+
+
+def repeat_kv(k, v, n_heads: int):
+    """K and V ``[B, S, G, H]`` copied up to ``n_heads`` query heads
+    (head ``n`` reads KV head ``n // (n_heads / G)``), for the paths
+    that need equal head counts."""
+    rep = n_heads // k.shape[2]
+    if rep == 1:
+        return k, v
+    return jnp.repeat(k, rep, axis=2), jnp.repeat(v, rep, axis=2)
 
 
 def _check_window(window: Optional[int], causal: bool) -> None:
@@ -117,14 +148,17 @@ def _round_up(x: int, m: int) -> int:
 
 
 def _vmem_bytes(block: int, tile: int, whole: int, hp: int,
-                itemsize: int) -> int:
+                itemsize: int, heads: int = 1) -> int:
     """VMEM one grid step needs at ``block`` rows a grid block and
     ``tile`` rows a loop tile: two operands of ``whole`` rows and at
     most four blocked operands and results, each double-buffered by the
     pipeline, two float32 accumulators, and three float32 score tiles
-    live at once (s or p, dp, ds)."""
-    return (2 * 2 * whole * hp * itemsize + 4 * 2 * block * hp * itemsize
-            + 2 * block * hp * 4 + 3 * block * tile * 4)
+    live at once (s or p, dp, ds; in the forward s, p and the bias).
+    The forward's blocked operands and accumulators are ``heads`` wide,
+    the query heads of the KV group a step serves."""
+    return (2 * 2 * whole * hp * itemsize
+            + heads * (4 * 2 * block * hp * itemsize + 2 * block * hp * 4)
+            + 3 * block * tile * 4)
 
 
 def _choose_blocks(s_q: int, s_k: int) -> _Blocks:
@@ -142,11 +176,12 @@ def _choose_blocks(s_q: int, s_k: int) -> _Blocks:
 
 
 def _compiler_params(block: int, tile: int, whole: int, hp: int,
-                     itemsize: int) -> dict:
+                     itemsize: int, heads: int = 1) -> dict:
     """Nothing where the kernel fits ``_VMEM_BUDGET`` (bf16 up to
-    6,144 x 128 at the largest blocks); past it, a VMEM limit a third
-    over what ``_vmem_bytes`` counts. A v5e core has 128 MiB."""
-    need = _vmem_bytes(block, tile, whole, hp, itemsize)
+    6,144 x 128 at the largest blocks and one head a step); past it, a
+    VMEM limit a third over what ``_vmem_bytes`` counts. A v5e core has
+    128 MiB."""
+    need = _vmem_bytes(block, tile, whole, hp, itemsize, heads)
     if need <= _VMEM_BUDGET:
         return {}
     from jax.experimental.pallas import tpu as pltpu
@@ -168,8 +203,13 @@ def _blocks_for(s_q: int, s_k: int, block_q: Optional[int],
 # Pallas forward kernel
 # --------------------------------------------------------------------------
 
+# Heads of a KV group the forward serves in one run of straight-line
+# code; a group of more is a loop over such runs.
+_HEAD_RUN = 2
+
 _NT = (((1,), (1,)), ((), ()))      # a · bᵀ
 _NN = (((1,), (0,)), ((), ()))      # a · b
+_TN = (((0,), (0,)), ((), ()))      # aᵀ · b
 _MASKED = -1e30
 
 
@@ -224,46 +264,93 @@ def _q_tiles(ki, block_q: int, block_k: int, seq_q: int, causal: bool,
         n_q, pl.cdiv((ki + 1) * block_k - 1 + window, block_q))
 
 
-def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *,
-                      causal: bool, sm_scale: float, block_k: int,
+def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
+                      l_ref, *, causal: bool, sm_scale: float, block_k: int,
                       true_sk: int, window: Optional[int]):
-    # q_ref: [block_q, H]; k_ref/v_ref: [S_k_padded, H];
-    # o_ref: [block_q, H]; lse_ref: [1, block_q].
-    # ``true_sk`` masks KV rows that exist only as block padding.
-    block_q, head_dim = q_ref.shape
-    qi = pl.program_id(1)
-    q = q_ref[:]
+    # One KV group's query block. q_ref, o_ref: [1, heads, block_q, H];
+    # k_ref, v_ref: [1, 1, S_k_padded, H], the group's, whole; lse_ref:
+    # [1, 1, heads, block_q]. Scratch, kept over the walk: acc_ref
+    # [heads, H, block_q], m_ref and l_ref [heads, 1, block_q] float32.
+    # Score tiles are [block_k, block_q]. ``true_sk`` hides KV rows
+    # that exist only as block padding.
+    heads, head_dim, block_q = acc_ref.shape
+    qi = pl.program_id(2)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, _MASKED)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    span = max(u for u in range(1, _HEAD_RUN + 1) if heads % u == 0)
+    shape = (block_k, block_q)
+    k_row = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    # a query's distance past a key, were both tiles to start at 0
+    ahead = jax.lax.broadcasted_iota(jnp.int32, shape, 1) - k_row
 
     def tile(j, carry):
-        o, m, l = carry
         start = pl.multiple_of(j * block_k, block_k)
-        k_blk = k_ref[pl.ds(start, block_k), :]
-        v_blk = v_ref[pl.ds(start, block_k), :]
-        s = _dot(q, k_blk, _NT) * sm_scale          # [block_q, block_k]
-        s = jnp.where(_visible(qi * block_q, j * block_k, s.shape, 0,
-                               causal, true_sk, window), s, _MASKED)
-        # Key 0 is visible to every query, so m is finite from the
-        # first tile on and a masked score's exp is exactly 0. Under a
-        # window a later row of the block may see nothing in the first
-        # tiles: it gathers exp(0) there, and alpha is exactly 0 at its
-        # first visible key (the diagonal at the latest), which wipes
-        # that.
-        m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m - m_new)
-        l_new = l * alpha + jnp.sum(p, axis=1, keepdims=True)
-        o_new = o * alpha + _dot(p.astype(v_blk.dtype), v_blk, _NN)
-        return o_new, m_new, l_new
+        k_blk = k_ref[0, 0, pl.ds(start, block_k), :]
+        v_blk = v_ref[0, 0, pl.ds(start, block_k), :]
+        # what the group's heads share: which entries of the tile count
+        visible = k_row + start < true_sk
+        if causal:
+            d = ahead + (qi * block_q - start)
+            visible &= d >= 0
+            if window is not None:
+                visible &= d < window
+        bias = jnp.where(visible, 0.0, _MASKED)
 
-    o, m, l = jax.lax.fori_loop(
-        *_kv_tiles(qi, block_q, block_k, k_ref.shape[0], causal, window),
-        tile,
-        (jnp.zeros((block_q, head_dim), jnp.float32),
-         jnp.full((block_q, 1), _MASKED, jnp.float32),
-         jnp.zeros((block_q, 1), jnp.float32)))
-    l_safe = jnp.maximum(l, 1e-30)
-    o_ref[:] = (o / l_safe).astype(o_ref.dtype)
-    lse_ref[0, :] = (m + jnp.log(l_safe))[:, 0]
+        def weights(e):
+            """Head ``e``'s softmax over this tile: updates its m and l,
+            -> (p in the value type, the factor its sums shrink by)."""
+            # [block_k, block_q]
+            s = _dot(k_blk, q_ref[0, e], _NT) * sm_scale + bias
+            # Key 0 is visible to every query, so m is finite from the
+            # first tile on and a masked score's exp is exactly 0. Under
+            # a window a later query of the block may see nothing in the
+            # first tiles: it gathers exp(0) there, and alpha is exactly
+            # 0 at its first visible key (the diagonal at the latest),
+            # which wipes that.
+            m = m_ref[e]
+            m_new = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m - m_new)
+            m_ref[e] = m_new
+            l_ref[e] = l_ref[e] * alpha + jnp.sum(p, axis=0, keepdims=True)
+            return p.astype(v_blk.dtype), alpha
+
+        def gather(e, p, alpha):
+            acc_ref[e] = acc_ref[e] * alpha + _dot(v_blk, p, _TN)
+
+        def run(i, carry):
+            # ``span`` heads in straight-line code, and a head's second
+            # product written after the next head's softmax: the
+            # compiler keeps that order, so the MXU weighs one head's
+            # values while the vector unit is on the next head's
+            # exponentials. A longer run overlaps more, and Mosaic
+            # compiles that much more code each time a program is
+            # loaded (PERF.md §6, PR 34).
+            first = i * span
+            waiting = weights(first)
+            for e in range(1, span):
+                ready = weights(first + e)
+                gather(first + e - 1, *waiting)
+                waiting = ready
+            gather(first + span - 1, *waiting)
+            return carry
+
+        if heads == span:
+            return run(0, carry)
+        return jax.lax.fori_loop(0, heads // span, run, carry)
+
+    jax.lax.fori_loop(
+        *_kv_tiles(qi, block_q, block_k, k_ref.shape[2], causal, window),
+        tile, 0)
+
+    def write(e, carry):        # no order to keep here: a loop will do
+        l_safe = jnp.maximum(l_ref[e], 1e-30)
+        o_ref[0, e] = (acc_ref[e] / l_safe).T.astype(o_ref.dtype)
+        lse_ref[0, 0, pl.ds(e, 1), :] = m_ref[e] + jnp.log(l_safe)
+        return carry
+
+    jax.lax.fori_loop(0, heads, write, 0)
 
 
 def _check_blocks(block_q: int, block_k: int, sqp: int,
@@ -301,41 +388,57 @@ def _unfold(x, like):
     return x[:, :s, :h].reshape(b, n, s, h).transpose(0, 2, 1, 3)
 
 
+# Traced once for each set of shapes and settings, and its operations
+# laid into the caller's program as they are (``inline``: no call, no
+# name, the same kernel): every ``pallas_call`` traces and lowers its
+# body anew, a model makes one in every kind of layer and once more for
+# the interpreter's branch, and a process pays that before it can look
+# a program up in the compile cache (PERF.md §6, PR 34: the lowering of
+# the Trinity cell's four programs).
+@functools.partial(jax.jit, inline=True, static_argnames=(
+    "causal", "sm_scale", "block_q", "block_k", "window", "interpret"))
 def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, window,
                interpret):
+    from jax.experimental.pallas import tpu as pltpu
     b, s_q, n, h = q.shape
-    s_k = k.shape[1]
+    s_k, g = k.shape[1], k.shape[2]
+    heads = n // g
     hp = _round_up(h, _LANE)
     block_q, block_k, sqp, skp = _blocks_for(s_q, s_k, block_q, block_k)
-    qt, kt, vt = _fold(q, sqp), _fold(k, skp), _fold(v, skp)
     _check_blocks(block_q, block_k, sqp, interpret)
-    grid = (b * n, sqp // block_q)
     kernel = functools.partial(_flash_fwd_kernel, causal=causal,
                                sm_scale=sm_scale, block_k=block_k,
                                true_sk=s_k, window=window)
+    group = pl.BlockSpec((1, heads, block_q, hp),
+                         lambda bi, gi, i: (bi, gi, i, 0))
+    whole = pl.BlockSpec((1, 1, skp, hp), lambda bi, gi, i: (bi, gi, 0, 0))
     out, lse = pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_q, hp), lambda bn, i: (bn, i, 0)),
-            pl.BlockSpec((1, skp, hp), lambda bn, i: (bn, 0, 0)),
-            pl.BlockSpec((1, skp, hp), lambda bn, i: (bn, 0, 0)),
-        ],
+        grid=(b, g, sqp // block_q),
+        in_specs=[group, whole, whole],
         out_specs=[
-            pl.BlockSpec((1, block_q, hp), lambda bn, i: (bn, i, 0)),
-            pl.BlockSpec((1, 1, block_q), lambda bn, i: (bn, 0, i)),
+            group,
+            pl.BlockSpec((1, 1, heads, block_q),
+                         lambda bi, gi, i: (bi, gi, 0, i)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b * n, sqp, hp), q.dtype),
-            jax.ShapeDtypeStruct((b * n, 1, sqp), jnp.float32),
+            jax.ShapeDtypeStruct((b, n, sqp, hp), q.dtype),
+            jax.ShapeDtypeStruct((b, g, heads, sqp), jnp.float32),
         ],
+        scratch_shapes=[pltpu.VMEM((heads, hp, block_q), jnp.float32),
+                        pltpu.VMEM((heads, 1, block_q), jnp.float32),
+                        pltpu.VMEM((heads, 1, block_q), jnp.float32)],
         interpret=interpret,
-        **_compiler_params(block_q, block_k, skp, hp, q.dtype.itemsize),
-    )(qt, kt, vt)
+        **_compiler_params(block_q, block_k, skp, hp, q.dtype.itemsize,
+                           heads),
+    )(_fold(q, sqp).reshape(b, n, sqp, hp),
+      _fold(k, skp).reshape(b, g, skp, hp),
+      _fold(v, skp).reshape(b, g, skp, hp))
     # lse stays PADDED [BN, sqp]: the only consumer (_flash_bwd, which
     # pads to the same lengths) needs it padded anyway — slicing here
     # would just be re-padded there.
-    return _unfold(out, q), lse.reshape(b * n, sqp)
+    return (_unfold(out.reshape(b * n, sqp, hp), q),
+            lse.reshape(b * n, sqp))
 
 
 # Pallas BlockSpec blocks carry the leading singleton; squeeze inside.
@@ -344,9 +447,6 @@ def _squeeze_kernel(kernel):
     def wrapped(*refs, **kw):
         return kernel(*[r.at[0] for r in refs], **kw)
     return wrapped
-
-
-_flash_fwd_kernel = _squeeze_kernel(_flash_fwd_kernel)
 
 
 # --------------------------------------------------------------------------
@@ -444,7 +544,12 @@ def _flash_bwd(q, k, v, out, lse, g, causal, sm_scale, block_q, block_k,
     s_k = k.shape[1]
     hp = _round_up(h, _LANE)
     block_q, block_k, sqp, skp = _blocks_for(s_q, s_k, block_q, block_k)
-    qt, kt, vt = _fold(q, sqp), _fold(k, skp), _fold(v, skp)
+    # the two kernels take a K and a V for every query head: the
+    # group's, repeated; the repeat's own transpose sums dk and dv over
+    # a group below
+    (kr, vr), sum_groups = jax.vjp(
+        functools.partial(repeat_kv, n_heads=n), k, v)
+    qt, kt, vt = _fold(q, sqp), _fold(kr, skp), _fold(vr, skp)
     dot, ot = _fold(g, sqp), _fold(out, sqp)
     # delta = rowsum(dO ∘ O): cheap elementwise outside the kernels
     delta = jnp.sum(dot.astype(jnp.float32) * ot.astype(jnp.float32),
@@ -504,7 +609,7 @@ def _flash_bwd(q, k, v, out, lse, g, causal, sm_scale, block_q, block_k,
         **_compiler_params(block_k, block_q, sqp, hp, q.dtype.itemsize),
     )(qt, kt, vt, dot, lse3, delta3)
 
-    return _unfold(dq, q), _unfold(dk, k), _unfold(dv, v)
+    return _unfold(dq, q), *sum_groups((_unfold(dk, kr), _unfold(dv, vr)))
 
 
 def _for_lowering_platform(fn, interpret: Optional[bool], *arrays):
@@ -529,10 +634,12 @@ def flash_attention(q, k, v, causal: bool = True,
                     block_k: Optional[int] = None,
                     interpret: Optional[bool] = None,
                     window: Optional[int] = None):
-    """Fused attention. [B,S,N,H] -> [B,S,N,H]. Block sizes left at
-    None are chosen from the shape (``_choose_blocks``). ``window``
-    (static, with ``causal``): key ``j`` counts for query ``i`` iff
-    ``0 <= i - j < window``; the tiles wholly outside are skipped."""
+    """Fused attention. ``q [B,S,N,H]``, ``k, v [B,S,G,H]`` with ``G``
+    dividing ``N`` (grouped-query attention; ``G == N`` is plain
+    multi-head) -> ``[B,S,N,H]``. Block sizes left at None are chosen
+    from the shape (``_choose_blocks``). ``window`` (static, with
+    ``causal``): key ``j`` counts for query ``i`` iff ``0 <= i - j <
+    window``; the tiles wholly outside are skipped."""
     out, _res = _flash_vjp_fwd(q, k, v, causal, sm_scale, block_q,
                                block_k, interpret, window)
     return out
@@ -541,6 +648,10 @@ def flash_attention(q, k, v, causal: bool = True,
 def _flash_vjp_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret,
                    window):
     _check_window(window, causal)
+    if q.shape[2] % k.shape[2] or k.shape != v.shape:
+        raise ValueError(f"the KV heads must divide the query heads and k "
+                         f"and v agree, got q {q.shape} k {k.shape} "
+                         f"v {v.shape}")
     if sm_scale is None:
         sm_scale = 1.0 / np.sqrt(q.shape[-1])
     out, lse = _for_lowering_platform(

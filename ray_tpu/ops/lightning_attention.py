@@ -43,9 +43,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ray_tpu.ops.flash_attention import (
-    _LANE, _NN, _NT, _dot, _for_lowering_platform, _round_up)
-
-_TN = (((0,), (0,)), ((), ()))      # aᵀ · b
+    _LANE, _NN, _NT, _TN, _dot, _for_lowering_platform, _round_up)
 
 # No chunk beyond this: the quadratic part of a chunk costs C a token,
 # the state's part H, so past a few times the head size a longer chunk

@@ -34,7 +34,8 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ray_tpu.ops.flash_attention import flash_attention, mha_reference
+from ray_tpu.ops.flash_attention import (
+    flash_attention, mha_reference, repeat_kv)
 
 
 def ring_attention_shard(q, k, v, *, axis_name: str = "sp",
@@ -121,8 +122,11 @@ def make_attention_fn(mesh: Optional[Mesh] = None, *,
                       impl: str = "auto", causal: bool = True,
                       batch_axes=("dp", "fsdp"), sp_axis: str = "sp",
                       tp_axis: str = "tp"):
-    """Build the attn_fn the transformer block calls: q,k,v [B,S,N,H]
-    (globally sharded) -> attention output.
+    """Build the attn_fn the transformer block calls: q [B,S,N,H], k
+    and v at their KV heads [B,S,G,H] (globally sharded) -> attention
+    output. The flash kernel takes the groups as they are; ring,
+    ulysses and the dense reference need equal head counts (their
+    all-to-all and einsums split heads) and repeat K and V first.
 
     impl: "auto" | "ring" | "ulysses" | "flash" | "reference".
     With a mesh whose ``sp`` axis > 1, "auto" = ring. Without, "auto"
@@ -136,8 +140,12 @@ def make_attention_fn(mesh: Optional[Mesh] = None, *,
     if impl in ("ring", "ulysses") and (mesh is None or sp <= 1):
         raise ValueError(f"impl={impl!r} needs a mesh with {sp_axis}>1")
 
+    def at_query_heads(fn):
+        return lambda q, k, v: fn(q, *repeat_kv(k, v, q.shape[2]))
+
     if impl == "reference":
-        return functools.partial(mha_reference, causal=causal)
+        return at_query_heads(functools.partial(mha_reference,
+                                                causal=causal))
     if impl == "flash":
         body = lambda q, k, v: flash_attention(q, k, v, causal)  # noqa: E731
         if mesh is None:
@@ -149,5 +157,12 @@ def make_attention_fn(mesh: Optional[Mesh] = None, *,
             ring_attention_shard if impl == "ring"
             else ulysses_attention_shard,
             axis_name=sp_axis, causal=causal)
-    return jax.shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
-                         out_specs=spec, check_vma=False)
+    sharded = jax.shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
+                            out_specs=spec, check_vma=False)
+    repeated = at_query_heads(sharded)
+    if impl != "flash":
+        return repeated
+    tp = mesh.shape.get(tp_axis, 1)
+    # a head shard holds whole groups where tp divides the KV heads
+    return lambda q, k, v: (
+        sharded if k.shape[2] % tp == 0 else repeated)(q, k, v)

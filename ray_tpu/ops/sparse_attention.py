@@ -76,8 +76,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ray_tpu.ops.flash_attention import (
-    _LANE, _MASKED, _NN, _NT, _dot, _for_lowering_platform, _round_up)
-from ray_tpu.ops.lightning_attention import _TN
+    _LANE, _MASKED, _NN, _NT, _TN, _dot, _for_lowering_platform, _round_up)
 
 _TILE_Q = _LANE                     # queries a kernel step: the lanes
 _VMEM_DEFAULT, _VMEM_MOST = 16 * 2 ** 20, 100 * 2 ** 20

@@ -21,33 +21,47 @@ from ray_tpu.ops.flash_attention import (
     _choose_blocks,
     _compiler_params,
     _vmem_bytes,
+    repeat_kv,
 )
 from ray_tpu.parallel.mesh import MeshSpec, make_mesh
 
 
-def _qkv(b=2, s=256, n=4, h=64, dtype=jnp.float32, seed=0):
+def _qkv(b=2, s=256, n=4, h=64, dtype=jnp.float32, seed=0, g=None):
+    """q at ``n`` heads, k and v at ``g`` KV heads (None: ``n``)."""
     ks = jax.random.split(jax.random.PRNGKey(seed), 3)
-    shape = (b, s, n, h)
-    return tuple(jax.random.normal(k, shape, dtype) for k in ks)
+    return tuple(jax.random.normal(k, (b, s, heads, h), dtype)
+                 for k, heads in zip(ks, (n, g or n, g or n)))
 
 
+def _dense(q, k, v, **kw):
+    """The dense reference given the repeated K and V: its gradients
+    for k and v are the sums over a group."""
+    return mha_reference(q, *repeat_kv(k, v, q.shape[2]), **kw)
+
+
+# (query heads, KV heads): N / G of 1, 4 and 6
+_HEADS = [(2, 2), (4, 1), (12, 2)]
+
+
+@pytest.mark.parametrize("n,g", [(4, 4), (4, 1), (6, 1)])
 @pytest.mark.parametrize("causal", [True, False])
-def test_flash_matches_reference(causal):
-    q, k, v = _qkv()
-    ref = mha_reference(q, k, v, causal=causal)
+def test_flash_matches_reference(causal, n, g):
+    q, k, v = _qkv(n=n, g=g)
+    ref = _dense(q, k, v, causal=causal)
     out = flash_attention(q, k, v, causal, None, 128, 128, True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=2e-5, rtol=2e-5)
 
 
-def test_flash_gradients():
-    q, k, v = _qkv(s=128)
+@pytest.mark.parametrize("n,g", [(4, 4), (4, 1), (6, 1)])
+def test_flash_gradients(n, g):
+    q, k, v = _qkv(s=128, n=n, g=g)
 
     def loss_flash(q, k, v):
         return jnp.sum(flash_attention(q, k, v, True, None, 64, 64, True) ** 2)
 
     def loss_ref(q, k, v):
-        return jnp.sum(mha_reference(q, k, v, causal=True) ** 2)
+        return jnp.sum(_dense(q, k, v, causal=True) ** 2)
 
     g1 = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
     g2 = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
@@ -56,19 +70,20 @@ def test_flash_gradients():
                                    atol=2e-4, rtol=2e-4)
 
 
+@pytest.mark.parametrize("n,g", [(2, 2), (4, 1)])
 @pytest.mark.parametrize("causal", [True, False])
 @pytest.mark.parametrize("s", [96, 160])   # not divisible by block 64
-def test_flash_gradients_ragged_seq(causal, s):
+def test_flash_gradients_ragged_seq(causal, s, n, g):
     """Blockwise backward stays exact when seq % block != 0 (the
     clamped-tail de-dup mask on both dq and dkv loops)."""
-    q, k, v = _qkv(s=s, n=2)
+    q, k, v = _qkv(s=s, n=n, g=g)
 
     def loss_flash(q, k, v):
         return jnp.sum(
             flash_attention(q, k, v, causal, None, 64, 64, True) ** 2)
 
     def loss_ref(q, k, v):
-        return jnp.sum(mha_reference(q, k, v, causal=causal) ** 2)
+        return jnp.sum(_dense(q, k, v, causal=causal) ** 2)
 
     g1 = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
     g2 = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
@@ -85,67 +100,71 @@ def _out_and_grads(attend, q, k, v, w):
     return (out, *grads)
 
 
+@pytest.mark.parametrize("n,g", _HEADS)
 @pytest.mark.parametrize("causal", [True, False])
 @pytest.mark.parametrize("s,blocks", [
     (256, (128, 128)), (256, (None, None)), (160, (None, None)),
     (160, (64, 64)), (256, (128, 64)), (256, (64, 128))])
-def test_flash_bf16_matches_reference(causal, s, blocks):
+def test_flash_bf16_matches_reference(causal, s, blocks, n, g):
     """bf16 inputs go to the matmuls as bf16 (P and dS rounded to bf16,
     float32 accumulation): forward and gradients stay inside the
     tolerances chip_smoke.py holds them to on the chip, against the
-    dense reference on the same inputs cast up."""
-    q, k, v = _qkv(b=1, s=s, n=2, h=64, dtype=jnp.bfloat16)
+    dense reference on the same inputs cast up; K and V at their KV
+    heads, dk and dv the dense reference's sums over a group."""
+    q, k, v = _qkv(b=1, s=s, n=n, g=g, h=64, dtype=jnp.bfloat16)
     w = jax.random.normal(jax.random.PRNGKey(7), q.shape, jnp.float32)
     got = _out_and_grads(
         lambda q, k, v: flash_attention(q, k, v, causal, None, *blocks,
                                         True), q, k, v, w)
     assert all(x.dtype == jnp.bfloat16 for x in got)
     want = _out_and_grads(
-        lambda q, k, v: mha_reference(q, k, v, causal=causal),
+        lambda q, k, v: _dense(q, k, v, causal=causal),
         *(x.astype(jnp.float32) for x in (q, k, v)), w)
     assert _rel_err(got[0], want[0]) < _FWD_TOL
-    for g, r in zip(got[1:], want[1:]):
-        assert _rel_err(g, r) < _BWD_TOL
+    for got_grad, r in zip(got[1:], want[1:]):
+        assert _rel_err(got_grad, r) < _BWD_TOL
 
 
+@pytest.mark.parametrize("n,g", [(2, 2), (6, 1)])
 @pytest.mark.parametrize("causal", [True, False])
 @pytest.mark.parametrize("s", [96, 160, 256, 1024])
-def test_flash_float32_with_chosen_blocks(causal, s):
+def test_flash_float32_with_chosen_blocks(causal, s, n, g):
     """float32 inputs keep float32 products at the blocks the chooser
     gives (block_q = block_k = None): the float32 tolerances hold."""
-    q, k, v = _qkv(b=1, s=s, n=2, h=64)
+    q, k, v = _qkv(b=1, s=s, n=n, g=g, h=64)
     w = jax.random.normal(jax.random.PRNGKey(7), q.shape, jnp.float32)
     got = _out_and_grads(
         lambda q, k, v: flash_attention(q, k, v, causal, interpret=True),
         q, k, v, w)
     want = _out_and_grads(
-        lambda q, k, v: mha_reference(q, k, v, causal=causal), q, k, v, w)
+        lambda q, k, v: _dense(q, k, v, causal=causal), q, k, v, w)
     np.testing.assert_allclose(np.asarray(got[0]), np.asarray(want[0]),
                                atol=2e-5, rtol=2e-5)
-    for g, r in zip(got[1:], want[1:]):
-        np.testing.assert_allclose(np.asarray(g), np.asarray(r),
+    for got_grad, r in zip(got[1:], want[1:]):
+        np.testing.assert_allclose(np.asarray(got_grad), np.asarray(r),
                                    atol=2e-4, rtol=2e-4)
 
 
 # window under, equal to and over the length; a ragged length; blocks
 # shorter and longer than the window, so that tiles are skipped at both
 # ends of the loop in all three kernels
+@pytest.mark.parametrize("n,g", _HEADS)
 @pytest.mark.parametrize("s,window,blocks", [
     (256, 64, (64, 64)), (256, 100, (64, 64)), (256, 100, (128, 64)),
     (256, 100, (64, 128)), (256, 256, (64, 64)), (256, 1000, (64, 64)),
     (160, 48, (64, 64)), (160, 1, (64, 64)), (512, 130, (None, None))])
-def test_flash_window_matches_reference(s, window, blocks):
-    q, k, v = _qkv(b=1, s=s, n=2, h=64)
+def test_flash_window_matches_reference(s, window, blocks, n, g):
+    q, k, v = _qkv(b=1, s=s, n=n, g=g, h=64)
     w = jax.random.normal(jax.random.PRNGKey(7), q.shape, jnp.float32)
     got = _out_and_grads(
         lambda q, k, v: flash_attention(q, k, v, True, None, *blocks,
                                         True, window), q, k, v, w)
     want = _out_and_grads(
-        lambda q, k, v: mha_reference(q, k, v, window=window), q, k, v, w)
+        lambda q, k, v: _dense(q, k, v, window=window), q, k, v, w)
     np.testing.assert_allclose(np.asarray(got[0]), np.asarray(want[0]),
                                atol=2e-5, rtol=2e-5)
-    for g, r in zip(got[1:], want[1:]):
-        np.testing.assert_allclose(np.asarray(g), np.asarray(r),
+    for got_grad, r in zip(got[1:], want[1:]):
+        np.testing.assert_allclose(np.asarray(got_grad), np.asarray(r),
                                    atol=2e-4, rtol=2e-4)
     if window >= s:     # a window that hides nothing is causal attention
         full = flash_attention(q, k, v, True, None, *blocks, True)
@@ -214,6 +233,22 @@ def test_chosen_blocks(s_q, s_k, itemsize):
     assert (need <= _VMEM_BUDGET) == (s_k * itemsize <= 12288)
 
 
+@pytest.mark.parametrize("heads", [1, 4, 6])
+def test_forward_vmem_counts_the_groups_heads(heads):
+    """The forward serves a KV group a step: its query and result
+    blocks and its accumulators are ``heads`` wide, and what it asks
+    Mosaic for covers them (16,384 keys whole, the Trinity cell's)."""
+    one = _vmem_bytes(512, 512, 16384, 128, 2)
+    need = _vmem_bytes(512, 512, 16384, 128, 2, heads)
+    blocked = 4 * 2 * 512 * 128 * 2 + 2 * 512 * 128 * 4
+    assert need - one == (heads - 1) * blocked
+    # q and o double-buffered and the float32 accumulators alone
+    assert need > 2 * 2 * 16384 * 128 * 2 + heads * 512 * 128 * (4 * 2 + 4)
+    limit = _compiler_params(512, 512, 16384, 128, 2,
+                             heads)["compiler_params"].vmem_limit_bytes
+    assert need < limit < 100 * 2 ** 20
+
+
 def test_chosen_blocks_at_the_cells_shapes():
     """One tile for a prompt padded to 128, 256 or 512; the train
     cell's S 4,096 takes what measured fastest there (PERF.md §6, PR
@@ -266,16 +301,17 @@ def _sp_mesh(sp):
     return make_mesh(spec, devs)
 
 
+@pytest.mark.parametrize("g", [4, 2])   # K and V at the query heads, or fewer
 @pytest.mark.parametrize("impl", ["ring", "ulysses"])
 @pytest.mark.parametrize("causal", [True, False])
-def test_sequence_parallel_matches_dense(impl, causal):
+def test_sequence_parallel_matches_dense(impl, causal, g):
     mesh = _sp_mesh(sp=4)
-    q, k, v = _qkv(b=2, s=256, n=4, h=32)
+    q, k, v = _qkv(b=2, s=256, n=4, h=32, g=g)
     shard = NamedSharding(mesh, P(("dp", "fsdp"), "sp", "tp", None))
     qs, ks, vs = (jax.device_put(x, shard) for x in (q, k, v))
     attn = make_attention_fn(mesh, impl=impl, causal=causal)
     out = jax.jit(attn)(qs, ks, vs)
-    ref = mha_reference(q, k, v, causal=causal)
+    ref = _dense(q, k, v, causal=causal)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=2e-5, rtol=2e-5)
 
@@ -308,6 +344,23 @@ def test_ring_with_tp_axis():
     out = jax.jit(attn)(qs, ks, vs)
     ref = mha_reference(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("g", [4, 2, 1])
+def test_flash_under_a_mesh_takes_kv_heads(g):
+    """Under a mesh the kernel runs on each device's head shard: a
+    shard holds whole groups where tp divides the KV heads (4, 2), and
+    K and V are repeated first where it does not (1)."""
+    mesh = make_mesh(MeshSpec.auto(8, tp=2), jax.devices()[:8])
+    q, k, v = _qkv(b=4, s=128, n=4, h=32, g=g)
+    shard = NamedSharding(mesh, P(("dp", "fsdp"), None, "tp", None))
+    qs = jax.device_put(q, shard)
+    ks, vs = ((jax.device_put(x, shard) if g % 2 == 0 else x)
+              for x in (k, v))
+    out = jax.jit(make_attention_fn(mesh, impl="flash"))(qs, ks, vs)
+    np.testing.assert_allclose(np.asarray(out),
+                               np.asarray(_dense(q, k, v, causal=True)),
                                atol=2e-5, rtol=2e-5)
 
 

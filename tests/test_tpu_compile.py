@@ -48,45 +48,63 @@ def _assert_kernel_not_interpreter(lowered):
     lowered.compile()
 
 
-# the benchmark's cells: the train cell's attention with gradients and a
+# the benchmark's cells, (batch, tokens, query heads, head size) and
+# their 8 KV heads: the train cell's attention with gradients and a
 # serve prompt padded to 128, both at the blocks the kernel chooses; the
 # long Mistral cell's other two padded lengths at named blocks
 TRAIN_CELL, SERVE_CELL = (1, 4096, 32, 128), (1, 128, 32, 128)
 LONG_1K, LONG_2K = (1, 1024, 32, 128), (1, 2048, 32, 128)
 
 
-@pytest.mark.parametrize("shape,block,direction", [
-    (LONG_1K, 128, "forward"), (LONG_1K, 512, "forward"),
-    (LONG_2K, 128, "forward"), (LONG_2K, 512, "forward"),
-    (TRAIN_CELL, None, "forward"), (TRAIN_CELL, None, "backward"),
-    (SERVE_CELL, None, "forward"),
-    # whole-length K and V past the default VMEM scope, with gradients
-    ((1, 8192, 8, 128), None, "backward")])
-def test_flash_compiles_for_tpu(v5e, shape, block, direction):
-    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16,
-                             sharding=SingleDeviceSharding(v5e[0]))
+def _qkv_shapes(shape, kv_heads, device):
+    """q at the shape's heads, k and v at ``kv_heads``."""
+    one = SingleDeviceSharding(device)
+    b, s, _n, h = shape
+    q = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one)
+    kv = jax.ShapeDtypeStruct((b, s, kv_heads, h), jnp.bfloat16,
+                              sharding=one)
+    return q, kv, kv
 
+
+@pytest.mark.parametrize("shape,kv_heads,block,direction", [
+    (LONG_1K, 32, 128, "forward"), (LONG_1K, 8, 512, "forward"),
+    (LONG_2K, 32, 128, "forward"), (LONG_2K, 8, 512, "forward"),
+    (TRAIN_CELL, 32, None, "forward"), (TRAIN_CELL, 32, None, "backward"),
+    (TRAIN_CELL, 8, None, "forward"), (TRAIN_CELL, 8, None, "backward"),
+    (SERVE_CELL, 32, None, "forward"), (SERVE_CELL, 8, None, "forward"),
+    # whole-length K and V past the default VMEM scope, with gradients
+    ((1, 8192, 8, 128), 8, None, "backward")])
+def test_flash_compiles_for_tpu(v5e, shape, kv_heads, block, direction):
+    """K and V at the cells' own 8 KV heads (a group of four query
+    heads a grid step), and at the query heads (ring and ulysses call
+    the kernel so)."""
     def attend(q, k, v):        # interpret=None: chosen by the lowering
         return flash_attention(q, k, v, True, None, block, block)
 
     fn = attend if direction == "forward" else jax.grad(
         lambda q, k, v: jnp.sum(attend(q, k, v).astype(jnp.float32)),
         argnums=(0, 1, 2))
-    _assert_kernel_not_interpreter(jax.jit(fn).lower(x, x, x))
+    _assert_kernel_not_interpreter(
+        jax.jit(fn).lower(*_qkv_shapes(shape, kv_heads, v5e[0])))
 
 
-# the Trinity cell's longest prefill: 48 heads of 128 over 16,384
-# positions, whose whole-length K and V are past the default VMEM scope
-LONG_CELL = (1, 16384, 48, 128)
-
-
-@pytest.mark.parametrize("window", [4096, None])
-def test_long_windowed_flash_compiles_for_tpu(v5e, window):
-    x = jax.ShapeDtypeStruct(LONG_CELL, jnp.bfloat16,
-                             sharding=SingleDeviceSharding(v5e[0]))
-    _assert_kernel_not_interpreter(jax.jit(
+# the Trinity cell's prefills: 48 query heads of 128 over 8 KV heads, a
+# group of six a grid step; at 16,384 positions the group's whole-length
+# K and V are past the default VMEM scope
+@pytest.mark.parametrize("tokens,window", [
+    (16384, 4096), (16384, None), (6144, 4096)])
+def test_long_windowed_flash_compiles_for_tpu(v5e, tokens, window):
+    lowered = jax.jit(
         lambda q, k, v: flash_attention(q, k, v, window=window)
-    ).lower(x, x, x))
+    ).lower(*_qkv_shapes((1, tokens, 48, 128), 8, v5e[0]))
+    _assert_kernel_not_interpreter(lowered)
+    # what a device profile knows the kernel by: three operands (scratch
+    # is none) and a tuple result (benchmark/kernels/flash_fwd.json)
+    (call,) = [line for line in lowered.compile().as_text().splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    result, _, operands = call.split(" = ", 1)[1].partition(" custom-call(")
+    assert result.startswith("(")
+    assert operands.split("), custom_call_target")[0].count(" %") + 1 == 3
 
 
 def _gmm_calls(text):
@@ -259,11 +277,12 @@ def test_flash_compiles_under_a_mesh(v5e):
     """The partitioner refuses a bare Mosaic kernel; under a mesh the
     kernel runs per device on its batch/head shard."""
     mesh = make_mesh(MeshSpec(fsdp=2, tp=2), v5e)
-    x = jax.ShapeDtypeStruct(
-        (2, 4096, 32, 128), jnp.bfloat16,   # the 2x2 cell's step
+    q, kv = (jax.ShapeDtypeStruct(
+        (2, 4096, heads, 128), jnp.bfloat16,   # the 2x2 cell's step
         sharding=NamedSharding(mesh, P(("dp", "fsdp"), None, "tp", None)))
+        for heads in (32, 8))
     attend = make_attention_fn(mesh, impl="flash")
-    _assert_kernel_not_interpreter(jax.jit(attend).lower(x, x, x))
+    _assert_kernel_not_interpreter(jax.jit(attend).lower(q, kv, kv))
 
 
 def test_scheduler_kernel_compiles_for_tpu(v5e):
@@ -313,6 +332,6 @@ def test_train_step_fits_as_the_remat_plan_counts(v5e, limit):
     memory = step.lower(state, {"tokens": tokens}).compile().memory_analysis()
     total = (memory.argument_size_in_bytes + memory.output_size_in_bytes
              - memory.alias_size_in_bytes + memory.temp_size_in_bytes)
-    # 14.155 GB kept whole, 13.010 GB recomputed (PERF.md §6, PR 29)
+    # 14.090 GB kept whole, 13.010 GB recomputed (PERF.md §6, PR 34)
     assert abs(total - plan.peak_bytes) < REMAT_MARGIN * 16e9
     assert total + memory.generated_code_size_in_bytes < 16e9
