@@ -6,6 +6,7 @@ pull in optax.
 """
 
 from ray_tpu.models.transformer import (  # noqa: F401
+    EvaSizes,
     LayerSpec,
     SparseSizes,
     TransformerConfig,
@@ -28,9 +29,9 @@ from ray_tpu.models.vit import (  # noqa: F401
 _TRAINING = ("TrainState", "init_state", "make_optimizer",
              "make_train_step", "state_specs")
 
-__all__ = ["LayerSpec", "SparseSizes", "TransformerConfig", "ViTConfig",
-           "config_from_hf", "forward", "forward_with_stats", "init_params",
-           "init_vit_params", "loss_fn", "param_specs",
+__all__ = ["EvaSizes", "LayerSpec", "SparseSizes", "TransformerConfig",
+           "ViTConfig", "config_from_hf", "forward", "forward_with_stats",
+           "init_params", "init_vit_params", "loss_fn", "param_specs",
            "record_sparse_visits", "vit_forward", "vit_loss_fn",
            "vit_param_specs", *_TRAINING]
 

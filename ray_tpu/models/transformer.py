@@ -26,17 +26,22 @@ only the mesh. Design notes:
   ``ray_tpu.ops.lightning_attention``) or ``sparse`` (every query
   attends to the ``top_k`` key blocks it scores highest, its own
   window and the first block, once the sequence outgrows
-  ``SparseSizes.dense_len``: ``ray_tpu.ops.sparse_attention``). A spec
-  may name its own number of KV heads;
+  ``SparseSizes.dense_len``: ``ray_tpu.ops.sparse_attention``) or
+  ``eva`` (exact softmax over the keys of the query's own window and
+  one learned summary for each chunk of the windows before it, under
+  one normaliser: ``ray_tpu.ops.eva_attention``). A spec may name its
+  own number of KV heads;
 - model-wide switches for what some families add to every layer:
   RMSNorm on queries and keys by head, a sigmoid gate on the attention
   output, norms after attention and MLP as well as before (sandwich),
   an RMSNorm over a lightning layer's merged heads, an embedding scale,
-  a residual scale and a logit scale (muP); ``head_dim`` and
+  a residual scale and a logit scale (muP), norm scales stored as
+  their offset from 1, a float32 residual stream, float32 logits,
+  several next-token heads side by side; ``head_dim`` and
   ``rms_norm_eps`` are fields;
 - ``config_from_hf`` reads a published ``config.json``'s keys (the
-  ``mistral``, ``afmoe`` and ``minicpm_sala`` families) into a
-  ``TransformerConfig``;
+  ``mistral``, ``afmoe``, ``minicpm_sala`` and ``evabyte`` families)
+  into a ``TransformerConfig``;
 - attention runs through ``ray_tpu.ops.attention`` which dispatches to
   the ring-attention path when the mesh has a nontrivial ``sp`` axis.
 
@@ -65,11 +70,11 @@ class LayerSpec:
     window: Optional[int] = None    # keys a query sees; None: all before it
     rope: bool = True               # False: no position encoding (NoPE)
     experts: bool = False           # routed + shared experts, else dense MLP
-    mixer: str = "softmax"          # or "lightning", "sparse"
+    mixer: str = "softmax"          # or "lightning", "sparse", "eva"
     kv_heads: Optional[int] = None  # None: the model's n_kv_heads
 
 
-MIXERS = ("softmax", "lightning", "sparse")
+MIXERS = ("softmax", "lightning", "sparse", "eva")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -90,6 +95,17 @@ class SparseSizes:
                 or self.init_blocks + self.window // self.block
                 > self.top_k):
             raise ValueError(f"sparse sizes do not fit together: {self}")
+
+
+@dataclasses.dataclass(frozen=True)
+class EvaSizes:
+    """The sizes of an ``eva`` layer (``ops/eva_attention.py``)."""
+    window: int = 2048      # positions a window: exact attention inside
+    chunk: int = 16         # keys a summary stands for
+
+    def __post_init__(self):
+        if self.window % self.chunk:
+            raise ValueError(f"eva sizes do not fit together: {self}")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -122,6 +138,13 @@ class TransformerConfig:
     logit_scale: float = 1.0     # the final norm's result, before the head
     mixer_out_norm: bool = False  # RMSNorm over a lightning layer's heads
     sparse: SparseSizes = SparseSizes()     # the ``sparse`` layers' sizes
+    eva: EvaSizes = EvaSizes()              # the ``eva`` layers' sizes
+    # next-token heads side by side in ``unembed``: head ``h`` (columns
+    # ``vocab_size h ..``) predicts the token ``1 + h`` positions on
+    n_pred_heads: int = 1
+    norm_unit_offset: bool = False  # a norm's scale is 1 + what is stored
+    residual_f32: bool = False      # the residual stream stays float32
+    logits_f32: bool = False        # the head accumulates into float32
     # Routed experts, for the layers whose spec asks for them. The
     # router is ``n_experts`` wide (the published count) whatever is
     # held here: ``experts_held = (first, count)``.
@@ -170,7 +193,11 @@ def config_from_hf(config: dict, max_seq_len: int) -> TransformerConfig:
     output gate, an output norm on the lightning layers, MiniCPM's muP
     scalars; ``sparse_config``, where given, names the sparse layers'
     sizes, and ``published.num_hidden_layers`` the depth the residual
-    scale is reckoned from where the file holds a slice). A window that
+    scale is reckoned from where the file holds a slice) and the
+    ``evabyte`` family (``attention_class`` ``eva`` in every layer with
+    its ``window_size`` and ``chunk_size``, ``num_pred_heads`` heads,
+    norm scales as offsets from 1, float32 residual stream and logits).
+    A window that
     no sequence of ``max_seq_len`` outgrows is causal attention and is
     dropped. ``expert_parallel`` ``{"size", "rank"}``, where given, says
     that ``num_experts`` counts the experts held here, the ``rank``-th
@@ -194,9 +221,12 @@ def config_from_hf(config: dict, max_seq_len: int) -> TransformerConfig:
             **common, layers=(LayerSpec(window=window),) * n_layers)
     if family == "minicpm_sala":
         return _sala_config(config, common)
+    if family == "evabyte":
+        return _evabyte_config(config, common)
     if family != "afmoe":
         raise ValueError(f"config_from_hf knows the model types 'mistral', "
-                         f"'afmoe' and 'minicpm_sala', not {family!r}")
+                         f"'afmoe', 'minicpm_sala' and 'evabyte', not "
+                         f"{family!r}")
     share = config.get("expert_parallel", {"size": 1, "rank": 0})
     held = config["num_experts"]
     layers = tuple(
@@ -250,6 +280,20 @@ def _sala_config(config: dict, common: dict) -> TransformerConfig:
             config.get("sparse_config", {}).items()}))
 
 
+def _evabyte_config(config: dict, common: dict) -> TransformerConfig:
+    if config["attention_class"] != "eva":
+        raise ValueError(f"config_from_hf reads an evabyte model whose "
+                         f"attention_class is 'eva', not "
+                         f"{config['attention_class']!r}")
+    return TransformerConfig(
+        **common, layers=(LayerSpec(mixer="eva"),) * common["n_layers"],
+        eva=EvaSizes(config["window_size"], config["chunk_size"]),
+        n_pred_heads=config["num_pred_heads"],
+        norm_unit_offset=config["norm_add_unit_offset"],
+        residual_f32=config["fp32_skip_add"],
+        logits_f32=config["fp32_logits"])
+
+
 # --------------------------------------------------------------------------
 # Parameters
 # --------------------------------------------------------------------------
@@ -263,32 +307,40 @@ def _dense_init(key, shape, in_axis=0):
 def init_params(key: jax.Array, cfg: TransformerConfig) -> Dict:
     keys = jax.random.split(key, cfg.n_layers + 2)
     d, hd = cfg.d_model, cfg.head_dim
-    ones = lambda n: jnp.ones((n,), jnp.float32)            # noqa: E731
+    # a norm's scale of 1, as it is stored
+    unit = lambda n: jnp.full(                              # noqa: E731
+        (n,), 0.0 if cfg.norm_unit_offset else 1.0, jnp.float32)
     params: Dict[str, Any] = {
         "embed": jax.random.normal(keys[0], (cfg.vocab_size, d),
                                    jnp.float32) * 0.02,
-        "final_norm": ones(d),
+        "final_norm": unit(d),
         "blocks": [],
     }
     for i, spec in enumerate(cfg.layers):
         bk = jax.random.split(keys[i + 1], 8)
         kv = spec.kv_heads or cfg.n_kv_heads
         block = {
-            "attn_norm": ones(d),
+            "attn_norm": unit(d),
             "wq": _dense_init(bk[0], (d, cfg.n_heads, hd)),
             "wk": _dense_init(bk[1], (d, kv, hd)),
             "wv": _dense_init(bk[2], (d, kv, hd)),
             "wo": _dense_init(bk[3], (cfg.n_heads, hd, d), in_axis=(0, 1)),
-            "mlp_norm": ones(d),
+            "mlp_norm": unit(d),
         }
         if cfg.qk_norm:
-            block.update(q_norm=ones(hd), k_norm=ones(hd))
+            block.update(q_norm=unit(hd), k_norm=unit(hd))
         if cfg.attn_gate:
             block["wgate"] = _dense_init(bk[7], (d, cfg.n_heads, hd))
         if cfg.mixer_out_norm and spec.mixer == "lightning":
-            block["out_norm"] = ones(cfg.n_heads * hd)
+            block["out_norm"] = unit(cfg.n_heads * hd)
+        if spec.mixer == "eva":
+            pk, mk = jax.random.split(bk[7])
+            block["eva_phi"] = jax.random.normal(
+                pk, (cfg.n_heads, hd), jnp.float32) * 0.02
+            block["eva_mu"] = jax.random.normal(
+                mk, (cfg.n_heads, hd), jnp.float32) * 0.02
         if cfg.sandwich_norm:
-            block.update(post_attn_norm=ones(d), post_mlp_norm=ones(d))
+            block.update(post_attn_norm=unit(d), post_mlp_norm=unit(d))
         if spec.experts:
             ek = jax.random.split(bk[4], 8)
             f, held = cfg.d_ff_expert, cfg.experts_held[1]
@@ -307,7 +359,8 @@ def init_params(key: jax.Array, cfg: TransformerConfig) -> Dict:
             block["wg"] = _dense_init(bk[5], (d, cfg.d_ff))
             block["wo_mlp"] = _dense_init(bk[6], (cfg.d_ff, d))
         params["blocks"].append(block)
-    params["unembed"] = _dense_init(keys[-1], (d, cfg.vocab_size))
+    params["unembed"] = _dense_init(
+        keys[-1], (d, cfg.n_pred_heads * cfg.vocab_size))
     return params
 
 
@@ -336,6 +389,8 @@ def param_specs(cfg: TransformerConfig) -> Dict:
             block["wgate"] = P("fsdp", "tp", None)
         if cfg.mixer_out_norm and spec.mixer == "lightning":
             block["out_norm"] = P(None)
+        if spec.mixer == "eva":
+            block.update(eva_phi=P("tp", None), eva_mu=P("tp", None))
         if cfg.sandwich_norm:
             block.update(post_attn_norm=P(None), post_mlp_norm=P(None))
         if spec.experts:
@@ -421,10 +476,22 @@ def _attention(q, k, v, *, causal: bool = True,
     return jnp.einsum("bnqk,bknh->bqnh", probs.astype(v.dtype), v)
 
 
-def _swiglu(h, wg, wi, wo, dt):
+def _norm(x, scale, cfg: "TransformerConfig"):
+    """``rms_norm`` at the model's eps, handed on in the compute type
+    (a float32 residual stream is read through it); under
+    ``norm_unit_offset`` the scale is 1 + what is stored."""
+    if cfg.norm_unit_offset:
+        scale = 1.0 + scale
+    return rms_norm(x, scale, cfg.rms_norm_eps).astype(cfg.dtype)
+
+
+def _swiglu(h, wg, wi, wo, dt, out_type=None):
+    """``out_type``: the type the down projection accumulates into and
+    returns (None: its operands')."""
     gate = jax.nn.silu(checkpoint_name(h @ wg.astype(dt), "mlp_gate"))
     up = checkpoint_name(h @ wi.astype(dt), "mlp_up")
-    return (gate * up) @ wo.astype(dt)
+    return jnp.matmul(gate * up, wo.astype(dt),
+                      preferred_element_type=out_type)
 
 
 def _experts_mlp(block, h, cfg: TransformerConfig):
@@ -444,12 +511,17 @@ def _experts_mlp(block, h, cfg: TransformerConfig):
     return routed, rows
 
 
-def _mixer(q, k, v, spec: LayerSpec, cfg: TransformerConfig):
-    """A ``lightning`` layer's mixer, or a ``sparse`` one's past
-    ``dense_len``: ``q [B, S, N, H]`` and ``k, v`` at the layer's KV
-    heads -> (``[B, S, N, H]``, the units of keys the sparse kernel
-    visited, or None). Under ``use_flash`` the Pallas kernels, else
-    their plain references, as softmax attention has it."""
+def _mixer(q, k, v, block, spec: LayerSpec, cfg: TransformerConfig):
+    """A ``lightning`` or an ``eva`` layer's mixer, or a ``sparse``
+    one's past ``dense_len``: ``q [B, S, N, H]`` and ``k, v`` at the
+    layer's KV heads -> (``[B, S, N, H]``, the units of keys the sparse
+    kernel visited, or None). Under ``use_flash`` the Pallas kernels,
+    else their plain references, as softmax attention has it."""
+    if spec.mixer == "eva":
+        from ray_tpu.ops.eva_attention import eva_attention, eva_reference
+        fn = eva_attention if cfg.use_flash else eva_reference
+        return fn(q, k, v, block["eva_phi"], block["eva_mu"],
+                  cfg.eva.window, cfg.eva.chunk), None
     if spec.mixer == "lightning":
         from ray_tpu.ops.lightning_attention import (
             decay_slopes, lightning_attention, lightning_reference)
@@ -498,6 +570,32 @@ def _record_mixers_plan(cfg: TransformerConfig, batch: int, seq: int):
            for name in ("keys_selected", "keys_causal")})
 
 
+def _record_eva_plan(cfg: TransformerConfig, batch: int, seq: int):
+    """One ``model.eva.plan`` record for the forward being traced, if
+    the pattern holds an ``eva`` layer: what those layers do at this
+    shape, from shapes alone (docs/tracing.md)."""
+    layers = sum(spec.mixer == "eva" for spec in cfg.layers)
+    if not layers:
+        return
+    import time
+
+    from ray_tpu.ops.eva_attention import pairs
+    from ray_tpu.util import tracing
+    window, chunk = cfg.eva.window, cfg.eva.chunk
+    local, far = (p * batch * layers * cfg.n_heads
+                  for p in pairs(seq, window, chunk))
+    it = jnp.dtype(cfg.dtype).itemsize
+    now = time.perf_counter_ns()
+    tracing.record(
+        "model.eva.plan", now, now, tokens=batch * seq, eva_layers=layers,
+        window=window, chunk=chunk, windows=-(-seq // window),
+        summaries=seq // chunk, local_pairs=local, far_pairs=far,
+        # what a sequence would leave behind: one window of K and V and
+        # a summary of each for every chunk, in every layer
+        state_bytes=2 * (min(seq, window) + seq // chunk) * layers
+        * cfg.n_heads * cfg.head_dim * it)
+
+
 def record_sparse_visits(units, cfg: TransformerConfig, batch: int,
                          seq: int, request: Optional[str] = None) -> None:
     """One ``model.sparse.visits`` record for a forward whose
@@ -533,14 +631,17 @@ def _layer_forward(block, x, positions, spec: LayerSpec,
     """One layer of the pattern. -> (x, the rows each held expert was
     given, or None, the units of keys a sparse kernel visited, or
     None)."""
-    dt, eps = cfg.dtype, cfg.rms_norm_eps
-    h = rms_norm(x, block["attn_norm"], eps)
+    dt = cfg.dtype
+    # a float32 residual stream: each half reads it through a norm, in
+    # the compute type, and adds a float32 result back
+    out_type = jnp.float32 if cfg.residual_f32 else None
+    h = _norm(x, block["attn_norm"], cfg)
     q = jnp.einsum("bsd,dnh->bsnh", h, block["wq"].astype(dt))
     k = jnp.einsum("bsd,dnh->bsnh", h, block["wk"].astype(dt))
     v = jnp.einsum("bsd,dnh->bsnh", h, block["wv"].astype(dt))
     if cfg.qk_norm:
-        q = rms_norm(q, block["q_norm"], eps)
-        k = rms_norm(k, block["k_norm"], eps)
+        q = _norm(q, block["q_norm"], cfg)
+        k = _norm(k, block["k_norm"], cfg)
     if spec.rope:
         q = rope(q, positions, cfg.rope_theta)
         k = rope(k, positions, cfg.rope_theta)
@@ -557,29 +658,31 @@ def _layer_forward(block, x, positions, spec: LayerSpec,
             attn_fn(q, k, v, window=spec.window)
         visited = None
     else:
-        attn, visited = _mixer(q, k, v, spec, cfg)
+        attn, visited = _mixer(q, k, v, block, spec, cfg)
         if cfg.mixer_out_norm and spec.mixer == "lightning":
-            attn = rms_norm(attn.reshape(*x.shape[:2], -1),
-                            block["out_norm"], eps).reshape(attn.shape)
+            attn = _norm(attn.reshape(*x.shape[:2], -1),
+                         block["out_norm"], cfg).reshape(attn.shape)
     if cfg.attn_gate:
         gate = jnp.einsum("bsd,dnh->bsnh", h, block["wgate"].astype(dt))
         attn = attn * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(dt)
-    attn = jnp.einsum("bsnh,nhd->bsd", attn, block["wo"].astype(dt))
+    attn = jnp.einsum("bsnh,nhd->bsd", attn, block["wo"].astype(dt),
+                      preferred_element_type=out_type)
     if cfg.sandwich_norm:
-        attn = rms_norm(attn, block["post_attn_norm"], eps)
+        attn = _norm(attn, block["post_attn_norm"], cfg)
     scaled = cfg.residual_scale != 1.0
     if scaled:
         attn = attn * jnp.asarray(cfg.residual_scale, dt)
     x = x + attn
 
-    h = rms_norm(x, block["mlp_norm"], eps)
+    h = _norm(x, block["mlp_norm"], cfg)
     rows = None
     if spec.experts:
         f, rows = _experts_mlp(block, h, cfg)
     else:
-        f = _swiglu(h, block["wg"], block["wi"], block["wo_mlp"], dt)
+        f = _swiglu(h, block["wg"], block["wi"], block["wo_mlp"], dt,
+                    out_type)
     if cfg.sandwich_norm:
-        f = rms_norm(f, block["post_mlp_norm"], eps)
+        f = _norm(f, block["post_mlp_norm"], cfg)
     if scaled:
         f = f * jnp.asarray(cfg.residual_scale, dt)
     return x + f, rows, visited
@@ -643,6 +746,9 @@ def _layer_counts(cfg: TransformerConfig, spec: LayerSpec, b: int, s: int,
         seen = hd / 2
     elif spec.mixer == "sparse" and s > cfg.sparse.dense_len:
         seen = cfg.sparse.top_k * cfg.sparse.block
+    elif spec.mixer == "eva":
+        from ray_tpu.ops.eva_attention import pairs
+        seen = sum(pairs(s, cfg.eva.window, cfg.eva.chunk)) / s
     qkv = 2 * t * d * hd * ((1 + cfg.attn_gate) * n + 2 * kv)
     attention = 2 * 2 * t * n * hd * seen
     out = 2 * t * n * hd * d
@@ -743,7 +849,9 @@ def forward_with_stats(params, tokens: jax.Array, cfg: TransformerConfig,
                        remat_levels: Optional[Tuple[int, ...]] = None):
     """tokens [B, S] int32 -> (logits, stats). Logits are [B, S, V],
     or [B, V] at ``logit_positions [B]`` where given (a prefill needs
-    the last position's alone). ``stats["moe_rows"]`` [routed layers,
+    the last position's alone); with ``n_pred_heads`` heads ``V`` is
+    that many vocabularies side by side, head ``h`` at columns
+    ``vocab_size h ..``. ``stats["moe_rows"]`` [routed layers,
     held experts] int32: the rows each held expert was given, which
     ``ops.moe.record_route`` turns into the ``model.moe.route`` record
     once they are on the host with the logits. Where sparse layers ran
@@ -763,6 +871,8 @@ def forward_with_stats(params, tokens: jax.Array, cfg: TransformerConfig,
             jnp.arange(tokens.shape[1], dtype=jnp.int32)[None, :],
             tokens.shape)
     x = params["embed"].astype(cfg.dtype)[tokens]
+    if cfg.residual_f32:
+        x = x.astype(jnp.float32)
     if cfg.embed_scale != 1.0:
         x = x * jnp.asarray(cfg.embed_scale, cfg.dtype)
     if attn_fn is None:
@@ -780,6 +890,7 @@ def forward_with_stats(params, tokens: jax.Array, cfg: TransformerConfig,
     if remat_levels is None:
         remat_levels = (0 if cfg.remat else KEEP_LAYER,) * cfg.n_layers
     _record_mixers_plan(cfg, *tokens.shape)
+    _record_eva_plan(cfg, *tokens.shape)
     layer_fns, moe_rows, visits = {}, [], []
     for block, spec, level in zip(params["blocks"], cfg.layers,
                                   remat_levels):
@@ -792,11 +903,13 @@ def forward_with_stats(params, tokens: jax.Array, cfg: TransformerConfig,
                     blk, static_argnums=(),
                     policy=jax.checkpoint_policies.save_only_these_names(
                         *REMAT_KEEPS[level]) if level else None)
-            elif cfg.remat:
+            elif cfg.remat or spec.mixer == "eva":
                 # a layer the plan keeps whole: one jitted function a
                 # kind, traced once like the checkpointed ones (twelve
                 # bare layers take three times as long to lower);
-                # ``remat=False`` stays the bare function it was
+                # ``remat=False`` stays the bare function it was, but
+                # for an ``eva`` layer, whose kernels' bodies would
+                # lower anew in every layer
                 blk = jax.jit(blk)
             layer_fns[spec, level] = blk
         x, rows, visited = blk(block, x, positions)
@@ -807,10 +920,13 @@ def forward_with_stats(params, tokens: jax.Array, cfg: TransformerConfig,
     if logit_positions is not None:
         x = jnp.take_along_axis(x, logit_positions[:, None, None],
                                 axis=1)[:, 0]
-    x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
+    x = _norm(x, params["final_norm"], cfg)
     if cfg.logit_scale != 1.0:
         x = x * jnp.asarray(cfg.logit_scale, cfg.dtype)
-    logits = (x @ params["unembed"].astype(cfg.dtype)).astype(jnp.float32)
+    logits = jnp.matmul(
+        x, params["unembed"].astype(cfg.dtype),
+        preferred_element_type=jnp.float32 if cfg.logits_f32 else None
+    ).astype(jnp.float32)
     held = cfg.experts_held[1]
     stats = {"moe_rows": jnp.stack(moe_rows) if moe_rows
              else jnp.zeros((0, held), jnp.int32)}

@@ -1,7 +1,9 @@
 """TPU ops: fused attention kernels, sequence-parallel attention,
 routed experts with their grouped matmul, chunked linear attention with
-decay, block-sparse attention with a per-query choice of blocks."""
+decay, block-sparse attention with a per-query choice of blocks,
+windowed attention over exact keys and chunk summaries (EVA)."""
 
+from ray_tpu.ops.eva_attention import eva_attention, eva_reference
 from ray_tpu.ops.flash_attention import flash_attention, mha_reference
 from ray_tpu.ops.lightning_attention import (
     lightning_attention,
@@ -23,5 +25,6 @@ __all__ = [
     "gmm", "make_moe_fn", "routed_experts",
     "lightning_attention", "lightning_reference",
     "selected_attention", "sparse_reference",
+    "eva_attention", "eva_reference",
     "ring_attention_shard", "ulysses_attention_shard",
 ]

@@ -316,8 +316,8 @@ def test_config_from_hf_reads_the_family():
     assert cfg.sparse == SparseSizes(8, 4, 8, 6, 16, 1, 32)
     assert config_from_hf(dict(tiny(SLICE), sparse_config={}),
                           128).sparse == SparseSizes()
-    with pytest.raises(ValueError, match="'mistral', 'afmoe' and "
-                                         "'minicpm_sala'"):
+    with pytest.raises(ValueError, match="'mistral', 'afmoe', "
+                                         "'minicpm_sala' and 'evabyte'"):
         config_from_hf(dict(tiny(SLICE), model_type="other"), 128)
     with pytest.raises(ValueError, match="no such layer"):
         TransformerConfig(n_layers=1, layers=(LayerSpec(mixer="scan"),))

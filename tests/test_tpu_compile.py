@@ -273,6 +273,73 @@ def test_minicpm_sala_prefill_fits_one_chip(v5e):
     assert total < 13e9, total
 
 
+# the EvaByte cell: 32 heads of 128, window 2,048, chunk 16, its
+# shortest and longest program
+@pytest.mark.parametrize("tokens", [12288, 32768])
+def test_the_eva_kernels_compile_for_tpu(v5e, tokens):
+    """The summaries (a window of K and V a step, its 128 summaries
+    written) and the attention over a window's keys and every summary
+    before it (two heads' window and summaries in VMEM), each a kernel
+    the program names."""
+    from ray_tpu.ops.eva_attention import eva_attention
+    one = SingleDeviceSharding(v5e[0])
+    q = jax.ShapeDtypeStruct((1, tokens, 32, 128), jnp.bfloat16, sharding=one)
+    vec = jax.ShapeDtypeStruct((32, 128), jnp.bfloat16, sharding=one)
+    lowered = jax.jit(lambda q, k, v, phi, mu: eva_attention(
+        q, k, v, phi, mu, 2048, 16)).lower(q, q, q, vec, vec)
+    _assert_kernel_not_interpreter(lowered)
+    text = lowered.compile().as_text()
+    calls = [line.split(" = ")[0].strip().lstrip("%") for line in
+             text.splitlines() if 'custom_call_target="tpu_custom_call"'
+             in line]
+    assert [c.rsplit(".", 1)[0] for c in calls] == ["eva_summaries",
+                                                    "eva_attn"]
+
+
+def test_evabyte_prefill_fits_one_chip(v5e):
+    """The cell's longest program whole (32,768 bytes through sixteen
+    eva layers at published widths, eight heads of 320, weights in
+    bfloat16): 6.50 GB of weights and what the forward holds beside
+    them stay under the chip's 16 GB, and sixteen equal layers call one
+    lowering of the layer."""
+    import dataclasses
+    import json
+
+    from ray_tpu.models import config_from_hf, forward_with_stats, init_params
+    here = os.path.join(os.path.dirname(__file__), "..", "benchmark",
+                        "configs", "evabyte-l16.json")
+    with open(here) as f:
+        config = json.load(f)
+    one = SingleDeviceSharding(v5e[0])
+    cfg = dataclasses.replace(config_from_hf(config, 32768), use_flash=True,
+                              remat=False)
+    params = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, jnp.bfloat16, sharding=one),
+        jax.eval_shape(lambda k: init_params(k, cfg), jax.random.PRNGKey(0)))
+
+    def answer(p, t, last):     # as benchmark/drivers/serve_prefill.py asks
+        logits, stats = forward_with_stats(p, t, cfg, logit_positions=last)
+        return jax.lax.top_k(logits[0], 8), stats["moe_rows"]
+
+    lowered = jax.jit(answer).lower(
+        params, jax.ShapeDtypeStruct((1, 32768), jnp.int32, sharding=one),
+        jax.ShapeDtypeStruct((1,), jnp.int32, sharding=one))
+    # one function a kind of layer: the kernels' bodies lower once
+    assert lowered.as_text().count("tpu_custom_call") < 16
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    for kernel in ("eva_summaries", "eva_attn"):
+        assert sum(1 for line in text.splitlines()
+                   if line.lstrip().startswith(f"%{kernel}.")
+                   and "tpu_custom_call" in line) == 16, kernel
+    memory = compiled.memory_analysis()
+    total = (memory.argument_size_in_bytes + memory.output_size_in_bytes
+             - memory.alias_size_in_bytes + memory.temp_size_in_bytes)
+    # the weights, the tokens and a position
+    assert 0 <= memory.argument_size_in_bytes - 2 * 3_250_065_408 < 2 ** 18
+    assert total < 10e9, total
+
+
 def test_flash_compiles_under_a_mesh(v5e):
     """The partitioner refuses a bare Mosaic kernel; under a mesh the
     kernel runs per device on its batch/head shard."""
