@@ -444,15 +444,24 @@ def rms_norm(x, scale, eps=1e-6):
 
 
 def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """x: [B, S, N, Hd]; positions: [B, S]."""
+    """x: [B, S, N, Hd]; positions: [B, S]. Rotates the pairs
+    ``(2i, 2i+1)``. The pair swap ``(a, b) -> (-b, a)`` is a product
+    with a constant signed permutation, exact in any type: a stride-2
+    slice of the lanes is a gather on a TPU, and q and k then pass
+    memory eight times (PERF.md §6, PR 36)."""
     hd = x.shape[-1]
     freqs = 1.0 / (theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd))
-    angles = positions[..., None].astype(jnp.float32) * freqs  # [B,S,Hd/2]
-    cos = jnp.cos(angles)[:, :, None, :]
+    # ``repeat``: frequencies made at every index give the chip another
+    # last place in the table than the sliced form had (PERF.md §6)
+    angles = positions[..., None].astype(jnp.float32) * jnp.repeat(freqs, 2)
+    cos = jnp.cos(angles)[:, :, None, :]    # [B,S,1,Hd], a pair alike
     sin = jnp.sin(angles)[:, :, None, :]
-    x1, x2 = x[..., 0::2], x[..., 1::2]
-    out = jnp.stack([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
-    return out.reshape(x.shape).astype(x.dtype)
+    swap = np.zeros((hd, hd), np.float32)
+    even = np.arange(0, hd, 2)
+    swap[even + 1, even], swap[even, even + 1] = -1.0, 1.0
+    turned = jnp.einsum("bsnh,hk->bsnk", x, jnp.asarray(swap, x.dtype),
+                        precision=jax.lax.Precision.HIGHEST)
+    return (x * cos + turned * sin).astype(x.dtype)
 
 
 def _attention(q, k, v, *, causal: bool = True,

@@ -296,23 +296,29 @@ def test_the_eva_kernels_compile_for_tpu(v5e, tokens):
                                                     "eva_attn"]
 
 
+def _evabyte_cfg(tokens):
+    """The EvaByte cell's configuration as its driver runs it."""
+    import dataclasses
+    import json
+
+    from ray_tpu.models import config_from_hf
+    here = os.path.join(os.path.dirname(__file__), "..", "benchmark",
+                        "configs", "evabyte-l16.json")
+    with open(here) as f:
+        config = json.load(f)
+    return dataclasses.replace(config_from_hf(config, tokens), use_flash=True,
+                               remat=False)
+
+
 def test_evabyte_prefill_fits_one_chip(v5e):
     """The cell's longest program whole (32,768 bytes through sixteen
     eva layers at published widths, eight heads of 320, weights in
     bfloat16): 6.50 GB of weights and what the forward holds beside
     them stay under the chip's 16 GB, and sixteen equal layers call one
     lowering of the layer."""
-    import dataclasses
-    import json
-
-    from ray_tpu.models import config_from_hf, forward_with_stats, init_params
-    here = os.path.join(os.path.dirname(__file__), "..", "benchmark",
-                        "configs", "evabyte-l16.json")
-    with open(here) as f:
-        config = json.load(f)
+    from ray_tpu.models import forward_with_stats, init_params
     one = SingleDeviceSharding(v5e[0])
-    cfg = dataclasses.replace(config_from_hf(config, 32768), use_flash=True,
-                              remat=False)
+    cfg = _evabyte_cfg(32768)
     params = jax.tree.map(
         lambda s: jax.ShapeDtypeStruct(s.shape, jnp.bfloat16, sharding=one),
         jax.eval_shape(lambda k: init_params(k, cfg), jax.random.PRNGKey(0)))
@@ -338,6 +344,52 @@ def test_evabyte_prefill_fits_one_chip(v5e):
     # the weights, the tokens and a position
     assert 0 <= memory.argument_size_in_bytes - 2 * 3_250_065_408 < 2 ** 18
     assert total < 10e9, total
+
+
+# q and k of the EvaByte cell at 16,384 bytes, q of the Trinity cell at
+# 12,288 tokens, k of the long Mistral cell at 4,096
+@pytest.mark.parametrize("shape", [(1, 16384, 32, 128), (1, 12288, 48, 128),
+                                   (1, 4096, 8, 128)])
+def test_rope_compiles_without_a_gather(v5e, shape):
+    """RoPE's pair swap is a product with a constant permutation: the
+    compiled program holds no gather (what a stride-2 slice of the
+    lanes becomes on a TPU) and passes the tensor at most 3.5 times its
+    input plus output (2.6-3.0 x; the sliced form read 7.1-8.2 x:
+    PERF.md §6, PR 36)."""
+    from ray_tpu.models.transformer import rope
+    one = SingleDeviceSharding(v5e[0])
+    compiled = jax.jit(lambda x, p: rope(x, p, 10_000.0)).lower(
+        jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one),
+        jax.ShapeDtypeStruct(shape[:2], jnp.int32, sharding=one)).compile()
+    assert " gather(" not in compiled.as_text()
+    in_and_out = 2 * 2 * shape[1] * shape[2] * shape[3]
+    assert compiled.cost_analysis()["bytes accessed"] <= 3.5 * in_and_out
+
+
+def test_an_evabyte_layer_passes_memory_as_counted(v5e):
+    """One layer of the EvaByte cell at 16,384 bytes: the projections
+    write q and k once and one fusion each (the small product, the
+    multiply-add in its epilogue) writes them heads-first for the
+    kernel: 7.77 GB of ``bytes accessed`` (11.15 GB with RoPE's
+    gathers and the twenty relayouts round them: PERF.md §6, PR 36)."""
+    import functools
+
+    from ray_tpu.models import init_params
+    from ray_tpu.models.transformer import _layer_forward
+    one = SingleDeviceSharding(v5e[0])
+    cfg = _evabyte_cfg(16384)
+    block = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, jnp.bfloat16, sharding=one),
+        jax.eval_shape(lambda k: init_params(k, cfg)["blocks"][0],
+                       jax.random.PRNGKey(0)))
+    layer = functools.partial(_layer_forward, spec=cfg.layers[0], cfg=cfg,
+                              attn_fn=None)     # an eva layer calls none
+    compiled = jax.jit(layer).lower(
+        block, jax.ShapeDtypeStruct((1, 16384, cfg.d_model), jnp.float32,
+                                    sharding=one),
+        jax.ShapeDtypeStruct((1, 16384), jnp.int32, sharding=one)).compile()
+    assert " gather(" not in compiled.as_text()
+    assert compiled.cost_analysis()["bytes accessed"] < 8.5e9
 
 
 def test_flash_compiles_under_a_mesh(v5e):
