@@ -2907,8 +2907,10 @@ def init(**kwargs) -> Worker:
     with _global_lock:
         if _global_worker is not None:
             return _global_worker
-        _global_worker = Worker(**kwargs)
-        atexit.register(shutdown)
+        from ray_tpu.util import tracing
+        with tracing.span("runtime.init"):
+            _global_worker = Worker(**kwargs)
+            atexit.register(shutdown)
         return _global_worker
 
 

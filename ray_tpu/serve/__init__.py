@@ -345,9 +345,15 @@ def start(http: bool = True, proxy_location: str = "worker"):
       spawn — for tests and notebooks: its thread competes with the
       driver's scheduling loop for CPU.
     """
-    global _worker_proxy
     if proxy_location not in ("driver", "worker"):
         raise ValueError(f"unknown proxy_location {proxy_location!r}")
+    from ray_tpu.util import tracing
+    with tracing.span("serve.start"):
+        return _start(http, proxy_location)
+
+
+def _start(http: bool, proxy_location: str):
+    global _worker_proxy
     controller = _get_controller(
         start_http=http and proxy_location == "driver")
     if http and proxy_location == "worker":

@@ -13,7 +13,11 @@ Three layers, cheap to expensive:
 - **Program spans** (always on, ``event_log_enabled``): the flight
   recorder. ``span(name)`` / ``record(name, start_ns, end_ns)`` at the
   layer boundaries of the serve request path and the train session
-  (docs/tracing.md has the table) append to one bounded ring a process.
+  (docs/tracing.md has the table) append to one bounded ring a process,
+  and so do the process's start (``process.boot``), the runtime's
+  (``runtime.init``, ``serve.start``) and every program jax traces,
+  lowers and compiles (``jax.trace``, ``jax.lower``, ``jax.compile``:
+  ``_private/compile_cache.py`` listens).
   The clock is ``time.perf_counter_ns()`` — CLOCK_MONOTONIC on Linux,
   so one clock for every process of a host and spans of the proxy,
   the driver and a replica need no offsets. All spans of one serve
@@ -84,12 +88,40 @@ _pid = os.getpid()
 # asyncio, per task
 _open: contextvars.ContextVar = contextvars.ContextVar(
     "rtpu_open_span", default=None)
+# this process's ``process.boot`` as (start_ns, end_ns), until the ring's
+# first reader puts it there
+_boot: Optional[Tuple[int, int]] = None
+
+
+def _process_start_ns() -> Optional[int]:
+    """When the OS started this process, on ``perf_counter_ns()``'s
+    clock, or None where ``/proc`` does not say. Field 22 of
+    ``/proc/self/stat`` counts clock ticks since boot (10 ms fine);
+    CLOCK_MONOTONIC starts at boot too but stands still while the host
+    sleeps, which CLOCK_BOOTTIME less CLOCK_MONOTONIC corrects."""
+    try:
+        with open("/proc/self/stat") as f:
+            # the command's name, in brackets, may hold spaces
+            ticks = int(f.read().rsplit(")", 1)[1].split()[19])
+        since_boot = ticks * 1_000_000_000 // os.sysconf("SC_CLK_TCK")
+        slept = (time.clock_gettime_ns(time.CLOCK_BOOTTIME)
+                 - time.clock_gettime_ns(time.CLOCK_MONOTONIC))
+    except (OSError, ValueError, IndexError, AttributeError):
+        return None
+    return since_boot - slept
 
 
 def _note_process() -> None:
-    global _pid
+    """This process's clock pair and the two ends of its
+    ``process.boot`` span: from the OS starting it to the recorder's
+    import (in a forked child, to the fork's return)."""
+    global _pid, _boot
     _pid = os.getpid()
-    _anchors[_pid] = (time.time_ns(), time.perf_counter_ns())
+    now = time.perf_counter_ns()
+    _anchors[_pid] = (time.time_ns(), now)
+    started = _process_start_ns()
+    _boot = (started, now) if started is not None and started <= now \
+        else None
 
 
 def _after_fork() -> None:
@@ -200,6 +232,19 @@ def record(name: str, start_ns: int, end_ns: int,
                   threading.get_ident(), counts or None))
 
 
+def _record_boot() -> None:
+    """``process.boot`` goes into the ring when the ring is first read
+    (``spans()``, ``drain()``) and not at import: the flag is then
+    whatever ``init(_system_config=...)`` or the environment made it,
+    and importing this module builds no ``Config``."""
+    global _boot
+    if _boot is not None and get_config().event_log_enabled:
+        boot, _boot = _boot, None
+        if boot is not None:        # another thread's reader took it
+            _ring.append(("process.boot", boot[0], boot[1], None, None,
+                          _pid, threading.get_ident(), None))
+
+
 def request_of(ref) -> str:
     """The id every span of one serve request carries: the hex id of
     the actor task whose reply ``ref`` is (the replica reads the same
@@ -220,18 +265,22 @@ def current_request() -> Optional[str]:
 def spans() -> List[Span]:
     """Every span this process holds: its own ring and what
     ``collect()`` gathered, oldest first. Readable after shutdown."""
+    _record_boot()
     return sorted((Span(*row) for row in list(_gathered) + list(_ring)),
                   key=lambda s: s.start_ns)
 
 
 def clear() -> None:
+    global _boot
     _ring.clear()
     _gathered.clear()
+    _boot = None
 
 
 def drain() -> tuple:
     """This process's ring, emptied, as the ``("spans", ...)`` reply a
     process worker sends: a second collection finds only newer spans."""
+    _record_boot()
     out = []
     try:
         while True:

@@ -382,14 +382,18 @@ def test_tracing_a_forward_records_the_mixers_plan():
     assert long_["keys_causal"] == 4 * 96 * 97 // 2
     assert long_["keys_selected"] == 4 * counted["keys_selected"]
     assert "keys_read" not in long_     # shapes cannot tell it
-    # the other families record none
-    count = len(tracing.spans())
+    # the other families record none (jax's own trace events may be
+    # in the ring too, where a test before this one made it listen)
+    def model_records():
+        return [s for s in tracing.spans() if s.name.startswith("model.")]
+
+    count = len(model_records())
     plain = TransformerConfig(n_layers=1, vocab_size=96, d_model=64)
     jax.eval_shape(lambda p, t: forward(p, t, plain),
                    jax.eval_shape(lambda k: init_params(k, plain),
                                   jax.random.PRNGKey(0)),
                    jax.ShapeDtypeStruct((1, 24), jnp.int32))
-    assert len(tracing.spans()) == count
+    assert len(model_records()) == count
 
 
 def test_a_forward_gives_back_the_units_it_visited():

@@ -221,7 +221,11 @@ def test_worker_spans_are_readable_after_shutdown(tmp_path):
     assert {s.request for s in got["in_worker"]} == {
         f"req-{i}" for i in range(4)}
     # no span a task: the annotation idiom records nothing in the ring
-    assert set(got) == {"in_worker", "in_driver"}
+    # (each process worker's start and the runtime's are there since
+    # the recorder covers set-up)
+    assert set(got) == {"in_worker", "in_driver", "process.boot",
+                        "runtime.init"}
+    assert {s.pid for s in got["process.boot"]} >= pids
 
     # one exporter: tasks and spans in one Chrome trace, on the wall clock
     events = tracing.timeline(str(tmp_path / "tl.json"))
@@ -230,7 +234,10 @@ def test_worker_spans_are_readable_after_shutdown(tmp_path):
     cats = {e["cat"] for e in events}
     assert cats == {"task", "span"}
     span_events = [e for e in events if e["cat"] == "span"]
-    assert {e["pid"] for e in span_events} == pids | {os.getpid()}
+    # (a worker that ran no task still reports its `process.boot`)
+    assert {e["pid"] for e in span_events
+            if e["name"] in ("in_worker", "in_driver")} \
+        == pids | {os.getpid()}
     assert all(abs(e["ts"] / 1e6 - time.time()) < 600 for e in span_events)
     assert {e["args"].get("request") for e in span_events
             if e["name"] == "in_worker"} == {f"req-{i}" for i in range(4)}
@@ -437,3 +444,205 @@ def test_ack_wait_span_only_under_sync_reports(tmp_path):
     assert wait.parent == "train.report"
     assert wait.end_ns - wait.start_ns >= 20_000_000
     assert got["train.report.write"][0].parent == "train.report"
+
+
+# -- set-up: the process, the runtime, jax's builds ---------------------------
+
+def test_process_boot_runs_from_the_os_start_to_the_recorders_import():
+    tracing._note_process()             # what the import did
+    (boot,) = tracing.spans()
+    assert boot.name == "process.boot" and boot.pid == os.getpid()
+    assert boot.request is None and boot.counts is None
+    # it ends at the clock pair the process noted, and began before it
+    assert boot.end_ns == tracing._anchors[os.getpid()][1]
+    assert boot.start_ns < boot.end_ns <= time.perf_counter_ns()
+    # no earlier than the host's boot, on a clock that may have slept
+    slept = (time.clock_gettime_ns(time.CLOCK_BOOTTIME)
+             - time.clock_gettime_ns(time.CLOCK_MONOTONIC))
+    assert boot.start_ns + slept >= 0
+    # the OS's own reading of this process's age agrees to a tick or two
+    with open("/proc/uptime") as f:
+        uptime_ns = int(float(f.read().split()[0]) * 1e9)
+    with open("/proc/self/stat") as f:
+        ticks = int(f.read().rsplit(")", 1)[1].split()[19])
+    age_ns = uptime_ns - ticks * 1_000_000_000 // os.sysconf("SC_CLK_TCK")
+    assert abs((time.perf_counter_ns() - boot.start_ns) - age_ns) < 50e6
+
+
+def test_process_boot_waits_for_the_rings_first_reader(recorder_off):
+    """Importing the recorder builds no `Config`, and a flag set after
+    the import (`init(_system_config=...)`) still holds for the span
+    of the process's start: it goes into the ring when the ring is
+    first read."""
+    import subprocess
+    import sys
+
+    probe = ("from ray_tpu.util import tracing; "
+             "from ray_tpu._private import config; "
+             "assert config._global_config is None; "
+             "assert [s.name for s in tracing.spans()] == ['process.boot']")
+    with time_limit(60):
+        subprocess.run([sys.executable, "-c", probe], check=True)
+    tracing._note_process()
+    assert tracing.spans() == [] and tracing.drain()[3] == []
+    get_config().apply_system_config({"event_log_enabled": True})
+    assert [s.name for s in tracing.spans()] == ["process.boot"]
+    assert len(tracing.spans()) == 1            # once
+
+
+# the child of this fork takes no lock: it reads its ring and leaves
+@pytest.mark.filterwarnings("ignore:.*fork.*")
+def test_a_forked_child_has_its_own_process_boot():
+    tracing._note_process()
+    (mine,) = tracing.spans()
+    tracing.record("before_fork", 1, 2)
+    read_end, write_end = os.pipe()
+    with time_limit(60):
+        child = os.fork()
+        if child == 0:                  # the child: report and leave
+            try:
+                os.write(write_end, json.dumps(tracing.spans()).encode())
+            finally:
+                os._exit(0)
+        os.close(write_end)
+        with os.fdopen(read_end) as f:
+            rows = json.load(f)
+        assert os.waitpid(child, 0)[1] == 0
+    (boot,) = [tracing.Span(*row) for row in rows]  # the ring began anew
+    assert boot.name == "process.boot" and boot.pid == child
+    # a tick (10 ms) is as fine as the OS says when it forked
+    assert boot.start_ns > mine.start_ns
+    assert mine.end_ns - 20_000_000 <= boot.start_ns <= boot.end_ns
+
+
+def test_runtime_init_and_serve_start_appear_once_each():
+    from ray_tpu import serve
+
+    with time_limit(120):
+        ray_tpu.init(num_cpus=2, num_tpus=8, max_process_workers=1)
+        try:
+            assert ray_tpu.init() is not None   # the runtime is up: no span
+            serve.start(http=True, proxy_location="driver")
+        finally:
+            serve.shutdown()
+            ray_tpu.shutdown()
+    got = by_name(tracing.spans())
+    (init,), (start,) = got["runtime.init"], got["serve.start"]
+    assert init.counts is None and start.counts is None
+    assert init.pid == start.pid == os.getpid()
+    assert init.parent is None and start.parent is None
+    assert init.start_ns < init.end_ns <= start.start_ns < start.end_ns
+
+
+def _built(name):
+    return [s for s in tracing.spans()
+            if s.name.startswith("jax.") and s.request == name]
+
+
+def test_a_jitted_function_leaves_trace_lower_and_compile(monkeypatch,
+                                                          tmp_path):
+    """One span of each for one program, under the function's name, in
+    that order; nothing for a warm call; one listener however often the
+    cache is configured."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu._private.compile_cache import configure_compile_cache
+
+    # placed from outside, so that nothing is set in code
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    configure_compile_cache()
+    configure_compile_cache()
+
+    @jax.jit
+    def halved(x):
+        return x / 2
+
+    def scaled_and_shifted(x):
+        return halved(x) * 3 + 1
+
+    program = jax.jit(scaled_and_shifted)
+    began = time.perf_counter_ns()
+    with time_limit(60):
+        jax.block_until_ready(program(jnp.arange(16.0)))
+        ended = time.perf_counter_ns()
+        first = _built("scaled_and_shifted")
+        jax.block_until_ready(program(jnp.arange(16.0)))
+    assert _built("scaled_and_shifted") == first        # warm: no event
+    assert [s.name for s in first] == ["jax.trace", "jax.lower",
+                                       "jax.compile"]
+    trace, lower, compile_ = first
+    assert began <= trace.start_ns <= trace.end_ns <= lower.end_ns \
+        <= compile_.end_ns <= ended
+    # jax's durations are wall-clock floats laid back from the event's
+    # close: they abut to well under a millisecond
+    assert lower.start_ns >= trace.end_ns - 1_000_000
+    assert compile_.start_ns >= lower.end_ns - 1_000_000
+    assert compile_.counts == {"cache_hit": 0}
+    assert trace.counts is None and lower.counts is None
+    assert {s.pid for s in first} == {os.getpid()}
+    assert {s.thread for s in first} == {threading.get_ident()}
+    # the inner jit's trace lies inside the outer one's, as jax gives it
+    (inner,) = [s for s in _built("halved") if s.name == "jax.trace"]
+    assert trace.start_ns <= inner.start_ns <= inner.end_ns <= trace.end_ns
+
+
+def test_jax_builds_leave_nothing_with_the_recorder_off(recorder_off,
+                                                        monkeypatch,
+                                                        tmp_path):
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu._private.compile_cache import configure_compile_cache
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    configure_compile_cache()
+    with time_limit(60):
+        jax.block_until_ready(jax.jit(lambda x: x * 5 - 2)(jnp.arange(8.0)))
+    tracing._note_process()
+    assert tracing.spans() == []
+
+
+_CACHE_PROBE = """
+import json
+import jax
+import jax.numpy as jnp
+from ray_tpu._private.compile_cache import configure_compile_cache
+from ray_tpu.util import tracing
+
+configure_compile_cache()
+
+
+def cached_probe(x):
+    return jnp.tanh(x) @ x.T
+
+jax.block_until_ready(jax.jit(cached_probe)(jnp.ones((64, 64))))
+print(json.dumps([[s.name, s.counts] for s in tracing.spans()
+                  if s.request == "cached_probe"]))
+"""
+
+
+def test_a_second_process_reads_cache_hit_where_the_first_read_none(
+        tmp_path):
+    """The persistent cache in a directory of the test's own, every
+    program kept whatever it took to compile."""
+    import subprocess
+    import sys
+
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path),
+               JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0",
+               JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES="-1")
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    hits = []
+    for _ in range(2):
+        done = subprocess.run([sys.executable, "-c", _CACHE_PROBE], env=env,
+                              cwd=repo, capture_output=True, text=True,
+                              timeout=120)
+        assert done.returncode == 0, done.stderr[-2000:]
+        spans = json.loads(done.stdout.strip().splitlines()[-1])
+        assert [name for name, _counts in spans] == [
+            "jax.trace", "jax.lower", "jax.compile"]
+        hits.append(spans[-1][1]["cache_hit"])
+    assert hits == [0, 1]
+    assert os.listdir(tmp_path)
