@@ -443,23 +443,30 @@ def rms_norm(x, scale, eps=1e-6):
     return (x * jax.lax.rsqrt(var + eps)).astype(x.dtype) * scale.astype(x.dtype)
 
 
+def _rope_tables(positions: jax.Array, hd: int, theta: float, dtype):
+    """What RoPE multiplies by: ``cos`` and ``sin`` of its angles, ``[B,
+    S, 1, Hd]`` float32, a pair of lanes alike, and the signed pair swap
+    ``(a, b) -> (-b, a)`` as a matrix ``[Hd, Hd]`` in ``dtype``."""
+    freqs = 1.0 / (theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd))
+    # ``repeat``: frequencies made at every index give the chip another
+    # last place in the table than the sliced form had (PERF.md §6)
+    angles = positions[..., None].astype(jnp.float32) * jnp.repeat(freqs, 2)
+    cos = jnp.cos(angles)[:, :, None, :]
+    sin = jnp.sin(angles)[:, :, None, :]
+    swap = np.zeros((hd, hd), np.float32)
+    even = np.arange(0, hd, 2)
+    swap[even + 1, even], swap[even, even + 1] = -1.0, 1.0
+    return cos, sin, jnp.asarray(swap, dtype)
+
+
 def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
     """x: [B, S, N, Hd]; positions: [B, S]. Rotates the pairs
     ``(2i, 2i+1)``. The pair swap ``(a, b) -> (-b, a)`` is a product
     with a constant signed permutation, exact in any type: a stride-2
     slice of the lanes is a gather on a TPU, and q and k then pass
     memory eight times (PERF.md §6, PR 36)."""
-    hd = x.shape[-1]
-    freqs = 1.0 / (theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd))
-    # ``repeat``: frequencies made at every index give the chip another
-    # last place in the table than the sliced form had (PERF.md §6)
-    angles = positions[..., None].astype(jnp.float32) * jnp.repeat(freqs, 2)
-    cos = jnp.cos(angles)[:, :, None, :]    # [B,S,1,Hd], a pair alike
-    sin = jnp.sin(angles)[:, :, None, :]
-    swap = np.zeros((hd, hd), np.float32)
-    even = np.arange(0, hd, 2)
-    swap[even + 1, even], swap[even, even + 1] = -1.0, 1.0
-    turned = jnp.einsum("bsnh,hk->bsnk", x, jnp.asarray(swap, x.dtype),
+    cos, sin, swap = _rope_tables(positions, x.shape[-1], theta, x.dtype)
+    turned = jnp.einsum("bsnh,hk->bsnk", x, swap,
                         precision=jax.lax.Precision.HIGHEST)
     return (x * cos + turned * sin).astype(x.dtype)
 
@@ -520,12 +527,21 @@ def _experts_mlp(block, h, cfg: TransformerConfig):
     return routed, rows
 
 
-def _mixer(q, k, v, block, spec: LayerSpec, cfg: TransformerConfig):
+def _kernel_prologue(spec: LayerSpec, cfg: TransformerConfig) -> bool:
+    """Whether the layer's q/k norm and RoPE are the mixer's kernel's to
+    do: a lightning layer's on the kernel path, where the kernel loads
+    each tile of q and of k once."""
+    return cfg.use_flash and spec.mixer == "lightning"
+
+
+def _mixer(q, k, v, block, positions, spec: LayerSpec,
+           cfg: TransformerConfig):
     """A ``lightning`` or an ``eva`` layer's mixer, or a ``sparse``
     one's past ``dense_len``: ``q [B, S, N, H]`` and ``k, v`` at the
     layer's KV heads -> (``[B, S, N, H]``, the units of keys the sparse
     kernel visited, or None). Under ``use_flash`` the Pallas kernels,
-    else their plain references, as softmax attention has it."""
+    else their plain references, as softmax attention has it; q and k
+    come normed and rotated but under ``_kernel_prologue``."""
     if spec.mixer == "eva":
         from ray_tpu.ops.eva_attention import eva_attention, eva_reference
         fn = eva_attention if cfg.use_flash else eva_reference
@@ -534,8 +550,25 @@ def _mixer(q, k, v, block, spec: LayerSpec, cfg: TransformerConfig):
     if spec.mixer == "lightning":
         from ray_tpu.ops.lightning_attention import (
             decay_slopes, lightning_attention, lightning_reference)
-        fn = lightning_attention if cfg.use_flash else lightning_reference
-        return fn(q, k, v, decay_slopes(cfg.n_heads)), None
+        slopes = decay_slopes(cfg.n_heads)
+        if not cfg.use_flash:
+            return lightning_reference(q, k, v, slopes), None
+        # q and k as the projections left them: the kernel norms and
+        # rotates each tile it loads (``_kernel_prologue``)
+        scales = None
+        if cfg.qk_norm:
+            scales = jnp.stack([block["q_norm"], block["k_norm"]])
+            if cfg.norm_unit_offset:
+                scales = 1.0 + scales
+            scales = scales.astype(cfg.dtype)
+        tables = None
+        if spec.rope:
+            cos, sin, swap = _rope_tables(positions, cfg.head_dim,
+                                          cfg.rope_theta, cfg.dtype)
+            tables = cos[:, :, 0], sin[:, :, 0], swap
+        return lightning_attention(
+            q, k, v, slopes, qk_scales=scales, norm_eps=cfg.rms_norm_eps,
+            rope=tables), None
     from ray_tpu.ops.sparse_attention import (
         select_blocks_reference, selected_attention, sparse_reference)
     if cfg.use_flash:
@@ -574,6 +607,9 @@ def _record_mixers_plan(cfg: TransformerConfig, batch: int, seq: int):
         "model.mixers.plan", now, now, tokens=batch * seq,
         linear_layers=linear, sparse_layers=sparse, sparse_mode=sparse_mode,
         chunk=choose_chunk(seq) if linear else 0,
+        prologue_layers=sum(
+            _kernel_prologue(spec, cfg) and (cfg.qk_norm or spec.rope)
+            for spec in cfg.layers),
         state_bytes=linear * cfg.n_heads * cfg.head_dim ** 2 * 4,
         **{name: keys[name] if sparse_mode else 0
            for name in ("keys_selected", "keys_causal")})
@@ -648,12 +684,13 @@ def _layer_forward(block, x, positions, spec: LayerSpec,
     q = jnp.einsum("bsd,dnh->bsnh", h, block["wq"].astype(dt))
     k = jnp.einsum("bsd,dnh->bsnh", h, block["wk"].astype(dt))
     v = jnp.einsum("bsd,dnh->bsnh", h, block["wv"].astype(dt))
-    if cfg.qk_norm:
-        q = _norm(q, block["q_norm"], cfg)
-        k = _norm(k, block["k_norm"], cfg)
-    if spec.rope:
-        q = rope(q, positions, cfg.rope_theta)
-        k = rope(k, positions, cfg.rope_theta)
+    if not _kernel_prologue(spec, cfg):
+        if cfg.qk_norm:
+            q = _norm(q, block["q_norm"], cfg)
+            k = _norm(k, block["k_norm"], cfg)
+        if spec.rope:
+            q = rope(q, positions, cfg.rope_theta)
+            k = rope(k, positions, cfg.rope_theta)
     # a sparse layer is plain causal attention up to ``dense_len``
     if spec.mixer == "softmax" or (
             spec.mixer == "sparse" and x.shape[1] <= cfg.sparse.dense_len):
@@ -667,7 +704,7 @@ def _layer_forward(block, x, positions, spec: LayerSpec,
             attn_fn(q, k, v, window=spec.window)
         visited = None
     else:
-        attn, visited = _mixer(q, k, v, block, spec, cfg)
+        attn, visited = _mixer(q, k, v, block, positions, spec, cfg)
         if cfg.mixer_out_norm and spec.mixer == "lightning":
             attn = _norm(attn.reshape(*x.shape[:2], -1),
                          block["out_norm"], cfg).reshape(attn.shape)

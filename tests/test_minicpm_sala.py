@@ -92,6 +92,63 @@ def test_lightning_kernel_is_the_recurrence(head):
     np.testing.assert_allclose(got[0, :, 0], want, atol=1e-4)
 
 
+def prologue_case(dtype):
+    """Raw q, k and v of 200 tokens (no whole number of chunks) with what
+    a layer's q/k norm and RoPE take, positions that start past 0, and
+    `model(q, k, norm, rotate)`: the two as `jax.numpy` does them."""
+    from ray_tpu.models.transformer import _norm, _rope_tables, rope
+    cfg = TransformerConfig(n_layers=1, vocab_size=96, d_model=64,
+                            dtype=dtype, rms_norm_eps=1e-5)
+    q, k, v = (x.astype(dtype) for x in qkv(200, 4, 4, 32))
+    scales = (1.0 + 0.5 * jax.random.normal(
+        jax.random.PRNGKey(7), (2, 32))).astype(dtype)
+    positions = jnp.arange(200)[None] + jnp.array([[3], [1000]])
+    cos, sin, swap = _rope_tables(positions, 32, 10_000.0, dtype)
+    tables = cos[:, :, 0], sin[:, :, 0], swap
+
+    def model(q, k, norm=True, rotate=True):
+        if norm:
+            q, k = _norm(q, scales[0], cfg), _norm(k, scales[1], cfg)
+        if rotate:
+            q, k = (rope(x, positions, 10_000.0) for x in (q, k))
+        return q, k
+
+    return q * 3, k / 4, v, scales, tables, model     # a norm left out shows
+
+
+@pytest.mark.parametrize("chunk", [64, 128])
+@pytest.mark.parametrize("rotate", [False, True])
+@pytest.mark.parametrize("norm", [False, True])
+def test_lightning_kernel_norms_and_rotates_its_tiles(norm, rotate, chunk):
+    """The layer's q/k norm and RoPE done by the kernel on raw q and k,
+    against `_norm` + `rope` + the quadratic form: three chunks and a
+    part, or one and a part."""
+    q, k, v, scales, tables, model = prologue_case(jnp.float32)
+    slopes = decay_slopes(4)
+    want = lightning_reference(*model(q, k, norm, rotate), v, slopes)
+    got = lightning_attention(
+        q, k, v, slopes, chunk, True, qk_scales=scales if norm else None,
+        norm_eps=1e-5, rope=tables if rotate else None)
+    np.testing.assert_allclose(got, want, atol=2e-4 * float(
+        jnp.max(jnp.abs(want))))
+
+
+def test_lightning_prologue_rounds_where_the_model_rounds():
+    """bfloat16: the kernel's norm and rotation against `_norm` and
+    `rope` feeding the kernel without them. Equal but where the norm's
+    sum over a head, added in another order, tips a rounding: under a
+    thousandth of the elements, by one bfloat16 step."""
+    q, k, v, scales, tables, model = prologue_case(jnp.bfloat16)
+    slopes = decay_slopes(4)
+    want = lightning_attention(*model(q, k), v, slopes, 64, True)
+    got = lightning_attention(q, k, v, slopes, 64, True, qk_scales=scales,
+                              norm_eps=1e-5, rope=tables)
+    assert got.dtype == jnp.bfloat16
+    assert float(jnp.mean(got != want)) < 1e-3
+    np.testing.assert_allclose(got.astype(jnp.float32),
+                               want.astype(jnp.float32), rtol=2 ** -7)
+
+
 def test_lightning_slopes_and_chunk_from_the_shape():
     slopes = decay_slopes(32)
     assert slopes[0] == pytest.approx(2 ** -0.25)
@@ -374,8 +431,17 @@ def test_tracing_a_forward_records_the_mixers_plan():
                     if s.name == "model.mixers.plan"][before:]
     assert short == {
         "tokens": 24, "linear_layers": 6, "sparse_layers": 2,
-        "sparse_mode": 0, "chunk": 128, "state_bytes": 6 * 4 * 16 * 16 * 4,
+        "sparse_mode": 0, "chunk": 128, "prologue_layers": 0,
+        "state_bytes": 6 * 4 * 16 * 16 * 4,
         "keys_selected": 0, "keys_causal": 0}
+    # on the kernel path the six lightning layers' norm and rotation
+    # are the kernel's
+    jax.eval_shape(lambda p, t: forward(
+        p, t, dataclasses.replace(cfg, use_flash=True)), shapes,
+        jax.ShapeDtypeStruct((1, 24), jnp.int32))
+    assert [s.counts for s in tracing.spans()
+            if s.name == "model.mixers.plan"][-1] == dict(
+                short, prologue_layers=6)
     counted = keys_counted(96, cfg.sparse)
     assert long_["sparse_mode"] == 1 and long_["tokens"] == 96
     # two sparse layers of two KV groups each

@@ -202,6 +202,36 @@ def test_trinity_prefill_fits_as_before_the_ladder(v5e):
     assert total <= 10_785_099_264 + 2 * 16384 * 3072     # one [T, D] more
 
 
+def _cell_cfg(name, tokens):
+    """A serve cell's configuration as its driver runs it."""
+    import dataclasses
+    import json
+
+    from ray_tpu.models import config_from_hf
+    here = os.path.join(os.path.dirname(__file__), "..", "benchmark",
+                        "configs", f"{name}.json")
+    with open(here) as f:
+        config = json.load(f)
+    return dataclasses.replace(config_from_hf(config, tokens), use_flash=True,
+                               remat=False)
+
+
+def _pallas_calls(text):
+    """(name, operands, whether the result is a tuple) of each Pallas
+    call in a compiled program's text: what a device profile knows a
+    kernel by (``benchmark/xplane.py::op_name``)."""
+    calls = []
+    for line in text.splitlines():
+        if 'custom_call_target="tpu_custom_call"' not in line:
+            continue
+        head, _, rest = line.partition(" = ")
+        result, _, call = rest.partition(" custom-call(")
+        operands = call.split("), custom_call_target")[0].count("%")
+        calls.append((head.strip().lstrip("%").rsplit(".", 1)[0], operands,
+                      result.startswith("(")))
+    return calls
+
+
 # the MiniCPM-SALA cell: 32 query heads of 128, 32 KV heads on a
 # lightning layer and 2 on a sparse one, its shortest and longest program
 @pytest.mark.parametrize("tokens", [12288, 32768])
@@ -221,15 +251,22 @@ def test_the_mixers_kernels_compile_for_tpu(v5e, tokens):
         q, k, v, decay_slopes(32))).lower(q, q, q)
     _assert_kernel_not_interpreter(scan)
     assert "lightning_attn" in scan.as_text()
+    # as the cell's layers call it: the q/k norm's scales and RoPE's
+    # tables and pair swap beside q, k and v
+    scales = jax.ShapeDtypeStruct((2, 128), jnp.bfloat16, sharding=one)
+    table = jax.ShapeDtypeStruct((1, tokens, 128), jnp.float32, sharding=one)
+    swap = jax.ShapeDtypeStruct((128, 128), jnp.bfloat16, sharding=one)
+    scan = jax.jit(lambda q, k, v, s, *rope: lightning_attention(
+        q, k, v, decay_slopes(32), qk_scales=s, rope=rope)).lower(
+            q, q, q, scales, table, table, swap)
+    _assert_kernel_not_interpreter(scan)
+    assert _pallas_calls(scan.compile().as_text()) == [
+        ("lightning_attn", 8, False)]
     sparse = jax.jit(lambda q, k, v: selected_attention(
         q, k, v, SparseSizes())).lower(q, kv, kv)
     _assert_kernel_not_interpreter(sparse)
-    text = sparse.compile().as_text()
-    calls = [line.split(" = ")[0].strip().lstrip("%") for line in
-             text.splitlines() if 'custom_call_target="tpu_custom_call"'
-             in line]
-    assert [c.rsplit(".", 1)[0] for c in calls] == ["sparse_select",
-                                                    "sparse_attn"]
+    assert [c[0] for c in _pallas_calls(sparse.compile().as_text())] == [
+        "sparse_select", "sparse_attn"]
 
 
 def test_minicpm_sala_prefill_fits_one_chip(v5e):
@@ -237,17 +274,9 @@ def test_minicpm_sala_prefill_fits_one_chip(v5e):
     sparse and six lightning layers at published widths, the whole
     vocabulary, weights in bfloat16): 5.64 GB of weights and what the
     forward holds beside them stay under the chip's 16 GB."""
-    import dataclasses
-    import json
-
-    from ray_tpu.models import config_from_hf, forward_with_stats, init_params
-    here = os.path.join(os.path.dirname(__file__), "..", "benchmark",
-                        "configs", "minicpm-sala-l8.json")
-    with open(here) as f:
-        config = json.load(f)
+    from ray_tpu.models import forward_with_stats, init_params
     one = SingleDeviceSharding(v5e[0])
-    cfg = dataclasses.replace(config_from_hf(config, 32768), use_flash=True,
-                              remat=False)
+    cfg = _cell_cfg("minicpm-sala-l8", 32768)
     params = jax.tree.map(
         lambda s: jax.ShapeDtypeStruct(s.shape, jnp.bfloat16, sharding=one),
         jax.eval_shape(lambda k: init_params(k, cfg), jax.random.PRNGKey(0)))
@@ -296,20 +325,6 @@ def test_the_eva_kernels_compile_for_tpu(v5e, tokens):
                                                     "eva_attn"]
 
 
-def _evabyte_cfg(tokens):
-    """The EvaByte cell's configuration as its driver runs it."""
-    import dataclasses
-    import json
-
-    from ray_tpu.models import config_from_hf
-    here = os.path.join(os.path.dirname(__file__), "..", "benchmark",
-                        "configs", "evabyte-l16.json")
-    with open(here) as f:
-        config = json.load(f)
-    return dataclasses.replace(config_from_hf(config, tokens), use_flash=True,
-                               remat=False)
-
-
 def test_evabyte_prefill_fits_one_chip(v5e):
     """The cell's longest program whole (32,768 bytes through sixteen
     eva layers at published widths, eight heads of 320, weights in
@@ -318,7 +333,7 @@ def test_evabyte_prefill_fits_one_chip(v5e):
     lowering of the layer."""
     from ray_tpu.models import forward_with_stats, init_params
     one = SingleDeviceSharding(v5e[0])
-    cfg = _evabyte_cfg(32768)
+    cfg = _cell_cfg("evabyte-l16", 32768)
     params = jax.tree.map(
         lambda s: jax.ShapeDtypeStruct(s.shape, jnp.bfloat16, sharding=one),
         jax.eval_shape(lambda k: init_params(k, cfg), jax.random.PRNGKey(0)))
@@ -377,7 +392,7 @@ def test_an_evabyte_layer_passes_memory_as_counted(v5e):
     from ray_tpu.models import init_params
     from ray_tpu.models.transformer import _layer_forward
     one = SingleDeviceSharding(v5e[0])
-    cfg = _evabyte_cfg(16384)
+    cfg = _cell_cfg("evabyte-l16", 16384)
     block = jax.tree.map(
         lambda s: jax.ShapeDtypeStruct(s.shape, jnp.bfloat16, sharding=one),
         jax.eval_shape(lambda k: init_params(k, cfg)["blocks"][0],
@@ -390,6 +405,39 @@ def test_an_evabyte_layer_passes_memory_as_counted(v5e):
         jax.ShapeDtypeStruct((1, 16384), jnp.int32, sharding=one)).compile()
     assert " gather(" not in compiled.as_text()
     assert compiled.cost_analysis()["bytes accessed"] < 8.5e9
+
+
+def test_a_lightning_layer_passes_q_and_k_once(v5e):
+    """One lightning layer of the MiniCPM-SALA cell at 16,384 tokens:
+    the projections write q and k where the kernel reads them, and the
+    kernel norms and rotates its tiles in VMEM: 7.06 GB of ``bytes
+    accessed`` (12.24 GB with the norm, RoPE's product and four
+    relayouts of q and k in XLA: PERF.md §6, PR 38). A device profile
+    knows a flash kernel by its operands and result
+    (``benchmark/kernels/``): this call may read as none of them."""
+    import functools
+
+    from ray_tpu.models import init_params
+    from ray_tpu.models.transformer import _layer_forward
+    one = SingleDeviceSharding(v5e[0])
+    cfg = _cell_cfg("minicpm-sala-l8", 16384)
+    assert cfg.layers[1].mixer == "lightning"
+    block = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, jnp.bfloat16, sharding=one),
+        jax.eval_shape(lambda k: init_params(k, cfg)["blocks"][1],
+                       jax.random.PRNGKey(0)))
+    layer = functools.partial(_layer_forward, spec=cfg.layers[1], cfg=cfg,
+                              attn_fn=None)     # a lightning layer calls none
+    compiled = jax.jit(layer).lower(
+        block, jax.ShapeDtypeStruct((1, 16384, cfg.d_model), jnp.bfloat16,
+                                    sharding=one),
+        jax.ShapeDtypeStruct((1, 16384), jnp.int32, sharding=one)).compile()
+    text = compiled.as_text()
+    assert " gather(" not in text
+    assert compiled.cost_analysis()["bytes accessed"] < 10.0e9
+    (name, operands, tuple_result), = _pallas_calls(text)
+    assert name == "lightning_attn"
+    assert (operands, tuple_result) not in {(6, False), (3, True), (6, True)}
 
 
 def test_flash_compiles_under_a_mesh(v5e):
