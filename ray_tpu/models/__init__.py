@@ -7,7 +7,9 @@ pull in optax.
 
 from ray_tpu.models.transformer import (  # noqa: F401
     EvaSizes,
+    KdaSizes,
     LayerSpec,
+    MlaSizes,
     SparseSizes,
     TransformerConfig,
     config_from_hf,
@@ -29,7 +31,8 @@ from ray_tpu.models.vit import (  # noqa: F401
 _TRAINING = ("TrainState", "init_state", "make_optimizer",
              "make_train_step", "state_specs")
 
-__all__ = ["EvaSizes", "LayerSpec", "SparseSizes", "TransformerConfig",
+__all__ = ["EvaSizes", "KdaSizes", "LayerSpec", "MlaSizes", "SparseSizes",
+           "TransformerConfig",
            "ViTConfig", "config_from_hf", "forward", "forward_with_stats",
            "init_params", "init_vit_params", "loss_fn", "param_specs",
            "record_sparse_visits", "vit_forward", "vit_loss_fn",

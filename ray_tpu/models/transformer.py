@@ -29,7 +29,13 @@ only the mesh. Design notes:
   ``SparseSizes.dense_len``: ``ray_tpu.ops.sparse_attention``) or
   ``eva`` (exact softmax over the keys of the query's own window and
   one learned summary for each chunk of the windows before it, under
-  one normaliser: ``ray_tpu.ops.eva_attention``). A spec may name its
+  one normaliser: ``ray_tpu.ops.eva_attention``) or ``kda`` (a delta
+  rule over a state a head with a data-dependent decay for every key
+  channel, behind short causal convolutions and L2 norms on q and k,
+  its output normed by head and gated: ``ray_tpu.ops.kda_attention``)
+  or ``mla`` (causal softmax attention whose keys and values come out
+  of one low-rank latent a token, the keys wider than the values by
+  lanes all heads share, no position encoding). A spec may name its
   own number of KV heads;
 - model-wide switches for what some families add to every layer:
   RMSNorm on queries and keys by head, a sigmoid gate on the attention
@@ -40,8 +46,8 @@ only the mesh. Design notes:
   several next-token heads side by side; ``head_dim`` and
   ``rms_norm_eps`` are fields;
 - ``config_from_hf`` reads a published ``config.json``'s keys (the
-  ``mistral``, ``afmoe``, ``minicpm_sala`` and ``evabyte`` families)
-  into a ``TransformerConfig``;
+  ``mistral``, ``afmoe``, ``minicpm_sala``, ``evabyte`` and
+  ``kimi_linear`` families) into a ``TransformerConfig``;
 - attention runs through ``ray_tpu.ops.attention`` which dispatches to
   the ring-attention path when the mesh has a nontrivial ``sp`` axis.
 
@@ -70,11 +76,11 @@ class LayerSpec:
     window: Optional[int] = None    # keys a query sees; None: all before it
     rope: bool = True               # False: no position encoding (NoPE)
     experts: bool = False           # routed + shared experts, else dense MLP
-    mixer: str = "softmax"          # or "lightning", "sparse", "eva"
+    mixer: str = "softmax"          # or another of MIXERS
     kv_heads: Optional[int] = None  # None: the model's n_kv_heads
 
 
-MIXERS = ("softmax", "lightning", "sparse", "eva")
+MIXERS = ("softmax", "lightning", "sparse", "eva", "kda", "mla")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -109,6 +115,22 @@ class EvaSizes:
 
 
 @dataclasses.dataclass(frozen=True)
+class KdaSizes:
+    """The sizes of a ``kda`` layer (``ops/kda_attention.py``)."""
+    conv: int = 4           # taps of the causal convolutions on q, k, v
+    rank: int = 128         # the decay's and the gate's inner width
+
+
+@dataclasses.dataclass(frozen=True)
+class MlaSizes:
+    """The sizes of an ``mla`` layer: keys and values out of a latent."""
+    kv_rank: int = 512      # the latent a token's K and V come out of
+    nope: int = 128         # key lanes of a head's own
+    shared: int = 64        # key lanes all heads share
+    value: int = 128        # a head's values
+
+
+@dataclasses.dataclass(frozen=True)
 class TransformerConfig:
     vocab_size: int = 32_000
     d_model: int = 512
@@ -139,6 +161,8 @@ class TransformerConfig:
     mixer_out_norm: bool = False  # RMSNorm over a lightning layer's heads
     sparse: SparseSizes = SparseSizes()     # the ``sparse`` layers' sizes
     eva: EvaSizes = EvaSizes()              # the ``eva`` layers' sizes
+    kda: KdaSizes = KdaSizes()              # the ``kda`` layers' sizes
+    mla: MlaSizes = MlaSizes()              # the ``mla`` layers' sizes
     # next-token heads side by side in ``unembed``: head ``h`` (columns
     # ``vocab_size h ..``) predicts the token ``1 + h`` positions on
     n_pred_heads: int = 1
@@ -196,7 +220,11 @@ def config_from_hf(config: dict, max_seq_len: int) -> TransformerConfig:
     scale is reckoned from where the file holds a slice) and the
     ``evabyte`` family (``attention_class`` ``eva`` in every layer with
     its ``window_size`` and ``chunk_size``, ``num_pred_heads`` heads,
-    norm scales as offsets from 1, float32 residual stream and logits).
+    norm scales as offsets from 1, float32 residual stream and logits)
+    and the ``kimi_linear`` family (``linear_attn_config`` names the
+    ``kda`` and the ``mla`` layers, 1-based; the first
+    ``first_k_dense_replace`` layers dense, then sigmoid-routed experts
+    beside shared ones).
     A window that
     no sequence of ``max_seq_len`` outgrows is causal attention and is
     dropped. ``expert_parallel`` ``{"size", "rank"}``, where given, says
@@ -223,12 +251,12 @@ def config_from_hf(config: dict, max_seq_len: int) -> TransformerConfig:
         return _sala_config(config, common)
     if family == "evabyte":
         return _evabyte_config(config, common)
+    if family == "kimi_linear":
+        return _kimi_config(config, common)
     if family != "afmoe":
         raise ValueError(f"config_from_hf knows the model types 'mistral', "
-                         f"'afmoe', 'minicpm_sala' and 'evabyte', not "
-                         f"{family!r}")
-    share = config.get("expert_parallel", {"size": 1, "rank": 0})
-    held = config["num_experts"]
+                         f"'afmoe', 'minicpm_sala', 'evabyte' and "
+                         f"'kimi_linear', not {family!r}")
     layers = tuple(
         LayerSpec(window=window if kind == "sliding_attention" else None,
                   rope=kind == "sliding_attention",
@@ -239,12 +267,20 @@ def config_from_hf(config: dict, max_seq_len: int) -> TransformerConfig:
         sandwich_norm=True,
         embed_scale=float(np.sqrt(config["hidden_size"]))
         if config.get("mup_enabled") else 1.0,
-        n_experts=held * share["size"],
-        experts_held=(held * share["rank"], held),
+        **_expert_share(config),
         expert_top_k=config["num_experts_per_tok"],
         d_ff_expert=config["moe_intermediate_size"],
         n_shared_experts=config.get("num_shared_experts", 0),
         route_scale=float(config.get("route_scale", 1.0)))
+
+
+def _expert_share(config: dict) -> dict:
+    """``n_experts`` and ``experts_held`` from ``num_experts`` and,
+    where given, ``expert_parallel``."""
+    share = config.get("expert_parallel", {"size": 1, "rank": 0})
+    held = config["num_experts"]
+    return {"n_experts": held * share["size"],
+            "experts_held": (held * share["rank"], held)}
 
 
 # MiniCPM4's ``sparse_config`` keys as ``SparseSizes`` names them
@@ -294,6 +330,40 @@ def _evabyte_config(config: dict, common: dict) -> TransformerConfig:
         logits_f32=config["fp32_logits"])
 
 
+# what a kimi_linear model must say for the layers written here
+_KIMI_ONLY = {"num_expert_group": 1, "topk_group": 1, "q_lora_rank": None,
+              "mla_use_nope": True, "moe_router_activation_func": "sigmoid",
+              "moe_renormalize": True}
+
+
+def _kimi_config(config: dict, common: dict) -> TransformerConfig:
+    for key, only in _KIMI_ONLY.items():
+        if config[key] != only:
+            raise ValueError(f"config_from_hf reads a kimi_linear model "
+                             f"whose {key} is {only!r}, not {config[key]!r}")
+    linear = config["linear_attn_config"]
+    if linear["num_heads"] != config["num_attention_heads"]:
+        raise ValueError("config_from_hf reads a kimi_linear model whose "
+                         "two kinds of layer have the same heads")
+    kinds = {**{i: "mla" for i in linear["full_attn_layers"]},
+             **{i: "kda" for i in linear["kda_layers"]}}
+    return TransformerConfig(
+        **dict(common, head_dim=linear["head_dim"]),
+        layers=tuple(
+            LayerSpec(mixer=kinds[i + 1], rope=False,
+                      experts=i >= config["first_k_dense_replace"])
+            for i in range(common["n_layers"])),
+        kda=KdaSizes(conv=linear["short_conv_kernel_size"],
+                     rank=linear["head_dim"]),
+        mla=MlaSizes(config["kv_lora_rank"], config["qk_nope_head_dim"],
+                     config["qk_rope_head_dim"], config["v_head_dim"]),
+        **_expert_share(config),
+        expert_top_k=config["num_experts_per_token"],
+        d_ff_expert=config["moe_intermediate_size"],
+        n_shared_experts=config["num_shared_experts"],
+        route_scale=float(config["routed_scaling_factor"]))
+
+
 # --------------------------------------------------------------------------
 # Parameters
 # --------------------------------------------------------------------------
@@ -318,15 +388,27 @@ def init_params(key: jax.Array, cfg: TransformerConfig) -> Dict:
     }
     for i, spec in enumerate(cfg.layers):
         bk = jax.random.split(keys[i + 1], 8)
-        kv = spec.kv_heads or cfg.n_kv_heads
-        block = {
-            "attn_norm": unit(d),
-            "wq": _dense_init(bk[0], (d, cfg.n_heads, hd)),
-            "wk": _dense_init(bk[1], (d, kv, hd)),
-            "wv": _dense_init(bk[2], (d, kv, hd)),
-            "wo": _dense_init(bk[3], (cfg.n_heads, hd, d), in_axis=(0, 1)),
-            "mlp_norm": unit(d),
-        }
+        kv = cfg.n_heads if spec.mixer == "kda" else \
+            spec.kv_heads or cfg.n_kv_heads
+        if spec.mixer == "mla":     # keys and values out of one latent
+            m, n = cfg.mla, cfg.n_heads
+            ak, uk = jax.random.split(bk[7])
+            mixer = {
+                "wq": _dense_init(bk[0], (d, n, m.nope + m.shared)),
+                "wkv_a": _dense_init(ak, (d, m.kv_rank + m.shared)),
+                "kv_norm": unit(m.kv_rank),
+                "wkv_b": _dense_init(uk, (m.kv_rank, n, m.nope + m.value)),
+                "wo": _dense_init(bk[3], (n, m.value, d), in_axis=(0, 1))}
+        else:
+            mixer = {
+                "wq": _dense_init(bk[0], (d, cfg.n_heads, hd)),
+                "wk": _dense_init(bk[1], (d, kv, hd)),
+                "wv": _dense_init(bk[2], (d, kv, hd)),
+                "wo": _dense_init(bk[3], (cfg.n_heads, hd, d),
+                                  in_axis=(0, 1))}
+        block = {"attn_norm": unit(d), **mixer, "mlp_norm": unit(d)}
+        if spec.mixer == "kda":
+            block.update(_kda_init(bk[7], cfg, unit))
         if cfg.qk_norm:
             block.update(q_norm=unit(hd), k_norm=unit(hd))
         if cfg.attn_gate:
@@ -364,6 +446,33 @@ def init_params(key: jax.Array, cfg: TransformerConfig) -> Dict:
     return params
 
 
+def _kda_init(key, cfg: TransformerConfig, unit) -> Dict:
+    """What a ``kda`` layer holds beside ``wq, wk, wv, wo``: the three
+    convolutions, the decay (through ``rank``, then a bias and a rate a
+    head, drawn as the published checkpoint's initialisation draws
+    them: rates ``U(1, 16)``, steps log-uniform in ``[0.001, 0.1]``),
+    the step, the output gate (through ``rank``) and the output norm."""
+    d, n, hd, sz = cfg.d_model, cfg.n_heads, cfg.head_dim, cfg.kda
+    ks = jax.random.split(key, 10)
+    step = jnp.exp(jax.random.uniform(
+        ks[8], (n, hd), jnp.float32, np.log(1e-3), np.log(1e-1)))
+    return {
+        **{name: jax.random.normal(k, (n, hd, sz.conv), jnp.float32)
+           / np.sqrt(sz.conv)
+           for name, k in zip(("conv_q", "conv_k", "conv_v"), ks[:3])},
+        "wf_a": _dense_init(ks[3], (d, sz.rank)),
+        "wf_b": _dense_init(ks[4], (sz.rank, n, hd)),
+        "dt_bias": step + jnp.log(-jnp.expm1(-step)),   # softplus^-1
+        "a_log": jnp.log(jax.random.uniform(ks[9], (n,), jnp.float32,
+                                            1.0, 16.0)),
+        "w_beta": _dense_init(ks[5], (d, n)),
+        "wg_a": _dense_init(ks[6], (d, sz.rank)),
+        "wg_b": _dense_init(ks[7], (sz.rank, n, hd)),
+        "bg": jnp.zeros((n, hd), jnp.float32),
+        "out_norm": unit(hd),
+    }
+
+
 def param_specs(cfg: TransformerConfig) -> Dict:
     """PartitionSpec tree matching init_params, layer by layer of the
     pattern.
@@ -375,14 +484,23 @@ def param_specs(cfg: TransformerConfig) -> Dict:
     layer's matrices do.
     """
     def block_specs(spec: LayerSpec) -> Dict[str, Any]:
+        by_head = P("fsdp", "tp", None)
+        if spec.mixer == "mla":     # the latent and its norm on every shard
+            keys = {"wkv_a": P("fsdp", None), "kv_norm": P(None),
+                    "wkv_b": P(None, "tp", None)}
+        else:
+            keys = {"wk": by_head, "wv": by_head}
         block: Dict[str, Any] = {
-            "attn_norm": P(None),
-            "wq": P("fsdp", "tp", None),
-            "wk": P("fsdp", "tp", None),
-            "wv": P("fsdp", "tp", None),
-            "wo": P("tp", None, "fsdp"),
-            "mlp_norm": P(None),
-        }
+            "attn_norm": P(None), "wq": by_head, **keys,
+            "wo": P("tp", None, "fsdp"), "mlp_norm": P(None)}
+        if spec.mixer == "kda":     # by head like the rest
+            block.update(
+                conv_q=P("tp", None, None), conv_k=P("tp", None, None),
+                conv_v=P("tp", None, None), wf_a=P("fsdp", None),
+                wf_b=P(None, "tp", None), dt_bias=P("tp", None),
+                a_log=P("tp"), w_beta=P("fsdp", "tp"), wg_a=P("fsdp", None),
+                wg_b=P(None, "tp", None), bg=P("tp", None),
+                out_norm=P(None))
         if cfg.qk_norm:
             block.update(q_norm=P(None), k_norm=P(None))
         if cfg.attn_gate:
@@ -534,14 +652,74 @@ def _kernel_prologue(spec: LayerSpec, cfg: TransformerConfig) -> bool:
     return cfg.use_flash and spec.mixer == "lightning"
 
 
+def _short_conv(x, taps):
+    """A causal convolution by channel and SiLU: ``x [B, S, N, H]``,
+    ``taps [N, H, K]`` -> float32 ``silu(sum_i taps[..., i] x_(t - K + 1
+    + i))``, zeros before the first position."""
+    width = taps.shape[-1]
+    x = jnp.pad(x.astype(jnp.float32),
+                ((0, 0), (width - 1, 0), (0, 0), (0, 0)))
+    taps = taps.astype(jnp.float32)
+    return jax.nn.silu(sum(x[:, i:x.shape[1] - width + 1 + i] * taps[..., i]
+                           for i in range(width)))
+
+
+_KDA_L2_EPS = 1e-6      # under the root of q's and k's L2 norms
+
+
+def _kda_operands(block, h, cfg: TransformerConfig):
+    """A ``kda`` layer's q, k, v (convolved; q and k of unit length by
+    head, q scaled), its log-decays ``g [B, S, N, H]`` and steps ``beta
+    [B, S, N]``, both float32, from the normed input ``h``."""
+    dt = cfg.dtype
+
+    def unit(x):
+        return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True)
+                                 + _KDA_L2_EPS)
+
+    q, k, v = (_short_conv(
+        jnp.einsum("bsd,dnh->bsnh", h, block["w" + name].astype(dt)),
+        block["conv_" + name]) for name in "qkv")
+    q = (unit(q) * cfg.head_dim ** -0.5).astype(dt)
+    rate = jnp.einsum(
+        "bsr,rnh->bsnh", h @ block["wf_a"].astype(dt),
+        block["wf_b"].astype(dt), preferred_element_type=jnp.float32)
+    g = -jnp.exp(block["a_log"].astype(jnp.float32))[:, None] * \
+        jax.nn.softplus(rate + block["dt_bias"].astype(jnp.float32))
+    beta = jax.nn.sigmoid(jnp.matmul(
+        h, block["w_beta"].astype(dt), preferred_element_type=jnp.float32))
+    return q, unit(k).astype(dt), v.astype(dt), g, beta
+
+
+def _mla_operands(block, h, cfg: TransformerConfig):
+    """An ``mla`` layer's q and k ``[B, S, N, nope + shared]`` and v
+    ``[B, S, N, value]``: the latent normed and decompressed by head,
+    the shared key lanes copied to every head, nothing rotated."""
+    dt, m = cfg.dtype, cfg.mla
+    q = jnp.einsum("bsd,dnh->bsnh", h, block["wq"].astype(dt))
+    latent = h @ block["wkv_a"].astype(dt)
+    up = jnp.einsum("bsr,rnh->bsnh",
+                    _norm(latent[..., :m.kv_rank], block["kv_norm"], cfg),
+                    block["wkv_b"].astype(dt))
+    shared = jnp.broadcast_to(latent[:, :, None, m.kv_rank:],
+                              (*up.shape[:3], m.shared))
+    return (q, jnp.concatenate([up[..., :m.nope], shared], axis=-1),
+            up[..., m.nope:])
+
+
 def _mixer(q, k, v, block, positions, spec: LayerSpec,
-           cfg: TransformerConfig):
-    """A ``lightning`` or an ``eva`` layer's mixer, or a ``sparse``
-    one's past ``dense_len``: ``q [B, S, N, H]`` and ``k, v`` at the
-    layer's KV heads -> (``[B, S, N, H]``, the units of keys the sparse
-    kernel visited, or None). Under ``use_flash`` the Pallas kernels,
-    else their plain references, as softmax attention has it; q and k
-    come normed and rotated but under ``_kernel_prologue``."""
+           cfg: TransformerConfig, gates=()):
+    """A ``lightning``, an ``eva`` or a ``kda`` layer's mixer (the
+    last with its ``gates``, the log-decays and the steps), or a
+    ``sparse`` one's past ``dense_len``: ``q [B, S, N, H]`` and ``k,
+    v`` at the layer's KV heads -> (``[B, S, N, H]``, the units of keys
+    the sparse kernel visited, or None). Under ``use_flash`` the Pallas
+    kernels, else their plain references, as softmax attention has it;
+    q and k come normed and rotated but under ``_kernel_prologue``."""
+    if spec.mixer == "kda":
+        from ray_tpu.ops.kda_attention import kda_attention, kda_reference
+        fn = kda_attention if cfg.use_flash else kda_reference
+        return fn(q, k, v, *gates), None
     if spec.mixer == "eva":
         from ray_tpu.ops.eva_attention import eva_attention, eva_reference
         fn = eva_attention if cfg.use_flash else eva_reference
@@ -641,6 +819,41 @@ def _record_eva_plan(cfg: TransformerConfig, batch: int, seq: int):
         * cfg.n_heads * cfg.head_dim * it)
 
 
+def _record_delta_plan(cfg: TransformerConfig, batch: int, seq: int):
+    """One ``model.delta.plan`` record for the forward being traced, if
+    the pattern holds a ``kda`` or an ``mla`` layer: what those layers
+    do at this shape and what a sequence would leave behind in them,
+    from shapes alone (docs/tracing.md)."""
+    kinds = [spec.mixer for spec in cfg.layers]
+    kda, mla = kinds.count("kda"), kinds.count("mla")
+    if not kda and not mla:
+        return
+    import time
+
+    from ray_tpu.ops.kda_attention import CHUNK
+    from ray_tpu.util import tracing
+    n, hd, m = cfg.n_heads, cfg.head_dim, cfg.mla
+    it = jnp.dtype(cfg.dtype).itemsize
+    tokens = batch * seq
+    now = time.perf_counter_ns()
+    tracing.record(
+        "model.delta.plan", now, now, tokens=tokens, kda_layers=kda,
+        mla_layers=mla, chunk=CHUNK if kda else 0,
+        chunks=-(-seq // CHUNK) if kda else 0,
+        # a token and head: the state decayed, read by k, moved by the
+        # outer product, read by q
+        kda_flops=kda * tokens * n * 7 * hd * hd,
+        mla_pair_flops=mla * batch * (seq * (seq + 1) // 2) * n * 2
+        * (m.nope + m.shared + m.value),
+        # what a sequence would leave behind: the state and the three
+        # convolutions' tails; the latent and the shared key lanes, or
+        # the same layers' K and V decompressed
+        state_bytes=kda * batch * (
+            n * hd * hd * 4 + 3 * (cfg.kda.conv - 1) * n * hd * it),
+        latent_bytes=mla * tokens * (m.kv_rank + m.shared) * it,
+        kv_bytes=mla * tokens * n * (m.nope + m.shared + m.value) * it)
+
+
 def record_sparse_visits(units, cfg: TransformerConfig, batch: int,
                          seq: int, request: Optional[str] = None) -> None:
     """One ``model.sparse.visits`` record for a forward whose
@@ -681,18 +894,24 @@ def _layer_forward(block, x, positions, spec: LayerSpec,
     # the compute type, and adds a float32 result back
     out_type = jnp.float32 if cfg.residual_f32 else None
     h = _norm(x, block["attn_norm"], cfg)
-    q = jnp.einsum("bsd,dnh->bsnh", h, block["wq"].astype(dt))
-    k = jnp.einsum("bsd,dnh->bsnh", h, block["wk"].astype(dt))
-    v = jnp.einsum("bsd,dnh->bsnh", h, block["wv"].astype(dt))
-    if not _kernel_prologue(spec, cfg):
-        if cfg.qk_norm:
-            q = _norm(q, block["q_norm"], cfg)
-            k = _norm(k, block["k_norm"], cfg)
-        if spec.rope:
-            q = rope(q, positions, cfg.rope_theta)
-            k = rope(k, positions, cfg.rope_theta)
+    gates = ()
+    if spec.mixer == "kda":
+        q, k, v, *gates = _kda_operands(block, h, cfg)
+    elif spec.mixer == "mla":
+        q, k, v = _mla_operands(block, h, cfg)
+    else:
+        q = jnp.einsum("bsd,dnh->bsnh", h, block["wq"].astype(dt))
+        k = jnp.einsum("bsd,dnh->bsnh", h, block["wk"].astype(dt))
+        v = jnp.einsum("bsd,dnh->bsnh", h, block["wv"].astype(dt))
+        if not _kernel_prologue(spec, cfg):
+            if cfg.qk_norm:
+                q = _norm(q, block["q_norm"], cfg)
+                k = _norm(k, block["k_norm"], cfg)
+            if spec.rope:
+                q = rope(q, positions, cfg.rope_theta)
+                k = rope(k, positions, cfg.rope_theta)
     # a sparse layer is plain causal attention up to ``dense_len``
-    if spec.mixer == "softmax" or (
+    if spec.mixer in ("softmax", "mla") or (
             spec.mixer == "sparse" and x.shape[1] <= cfg.sparse.dense_len):
         # GQA: k and v go to ``attn_fn`` at their KV heads, as the
         # projections left them. The flash kernel serves a KV group a
@@ -704,10 +923,16 @@ def _layer_forward(block, x, positions, spec: LayerSpec,
             attn_fn(q, k, v, window=spec.window)
         visited = None
     else:
-        attn, visited = _mixer(q, k, v, block, positions, spec, cfg)
+        attn, visited = _mixer(q, k, v, block, positions, spec, cfg, gates)
         if cfg.mixer_out_norm and spec.mixer == "lightning":
             attn = _norm(attn.reshape(*x.shape[:2], -1),
                          block["out_norm"], cfg).reshape(attn.shape)
+        if spec.mixer == "kda":     # normed by head, gated through a rank
+            gate = jnp.einsum(
+                "bsr,rnh->bsnh", h @ block["wg_a"].astype(dt),
+                block["wg_b"].astype(dt), preferred_element_type=jnp.float32)
+            attn = _norm(attn, block["out_norm"], cfg) * jax.nn.sigmoid(
+                gate + block["bg"].astype(jnp.float32)).astype(dt)
     if cfg.attn_gate:
         gate = jnp.einsum("bsd,dnh->bsnh", h, block["wgate"].astype(dt))
         attn = attn * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(dt)
@@ -757,9 +982,16 @@ REMAT_MARGIN = 0.04
 
 def _layer_params(cfg: TransformerConfig, spec: LayerSpec) -> int:
     """The layer's matrices' parameters (norm scales left out)."""
-    d, hd = cfg.d_model, cfg.head_dim
-    attention = d * hd * ((2 + cfg.attn_gate) * cfg.n_heads
+    d, hd, n = cfg.d_model, cfg.head_dim, cfg.n_heads
+    attention = d * hd * ((2 + cfg.attn_gate) * n
                           + 2 * (spec.kv_heads or cfg.n_kv_heads))
+    if spec.mixer == "kda":
+        attention = n * hd * (4 * d + 3 * cfg.kda.conv) + d * n \
+            + 2 * cfg.kda.rank * (d + n * hd)
+    elif spec.mixer == "mla":
+        m = cfg.mla
+        attention = d * n * (m.nope + m.shared + m.value) \
+            + (m.kv_rank + m.shared) * d + m.kv_rank * n * (m.nope + m.value)
     if not spec.experts:
         return attention + 3 * d * cfg.d_ff
     return attention + d * cfg.n_experts + 3 * d * cfg.d_ff_expert * (
@@ -788,7 +1020,7 @@ def _layer_counts(cfg: TransformerConfig, spec: LayerSpec, b: int, s: int,
     kept = (wide, wide + attn_kept, wide + attn_kept + mlp_kept,
             4 * wide + attn_kept + mlp_kept + extra)
     seen = s / 2 if spec.window is None or spec.window >= s else spec.window
-    if spec.mixer == "lightning":       # the state's form: H a token
+    if spec.mixer in ("lightning", "kda"):  # the state's form: H a token
         seen = hd / 2
     elif spec.mixer == "sparse" and s > cfg.sparse.dense_len:
         seen = cfg.sparse.top_k * cfg.sparse.block
@@ -937,6 +1169,7 @@ def forward_with_stats(params, tokens: jax.Array, cfg: TransformerConfig,
         remat_levels = (0 if cfg.remat else KEEP_LAYER,) * cfg.n_layers
     _record_mixers_plan(cfg, *tokens.shape)
     _record_eva_plan(cfg, *tokens.shape)
+    _record_delta_plan(cfg, *tokens.shape)
     layer_fns, moe_rows, visits = {}, [], []
     for block, spec, level in zip(params["blocks"], cfg.layers,
                                   remat_levels):
@@ -949,12 +1182,13 @@ def forward_with_stats(params, tokens: jax.Array, cfg: TransformerConfig,
                     blk, static_argnums=(),
                     policy=jax.checkpoint_policies.save_only_these_names(
                         *REMAT_KEEPS[level]) if level else None)
-            elif cfg.remat or spec.mixer == "eva":
+            elif cfg.remat or spec.mixer in ("eva", "kda", "mla"):
                 # a layer the plan keeps whole: one jitted function a
                 # kind, traced once like the checkpointed ones (twelve
                 # bare layers take three times as long to lower);
                 # ``remat=False`` stays the bare function it was, but
-                # for an ``eva`` layer, whose kernels' bodies would
+                # for an ``eva``, a ``kda`` or an ``mla`` layer, whose
+                # kernels' bodies or whose many equal layers would
                 # lower anew in every layer
                 blk = jax.jit(blk)
             layer_fns[spec, level] = blk

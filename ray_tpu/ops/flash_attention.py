@@ -373,13 +373,26 @@ def _check_blocks(block_q: int, block_k: int, sqp: int,
             f"the whole padded sequence {sqp}) for TPU lowering")
 
 
-def _fold(x, seq: int):
-    """[B, S, N, H] -> [B·N, seq, H padded to lanes]: batch and heads
-    fold into the grid, the sequence pads to ``seq`` (masked by the
-    true lengths inside the kernels)."""
+def _lanes(h: int) -> int:
+    """The width the forward gives a head in HBM (the backward keeps
+    lane multiples): a lane multiple, but a width past one lane that is
+    a whole number of half-lanes stays as it is (192: a block as wide as
+    the array is a block Mosaic takes, and it pads the tile to its own
+    tiling in VMEM, not in memory)."""
+    if h > _LANE and h % (_LANE // 2) == 0:
+        return h
+    return _round_up(h, _LANE)
+
+
+def _fold(x, seq: int, width: Optional[int] = None):
+    """[B, S, N, H] -> [B·N, seq, H padded to ``width``, a lane multiple
+    unless the forward names its own]: batch and heads fold into the
+    grid, the sequence pads to ``seq`` (masked by the true lengths
+    inside the kernels)."""
     b, s, n, h = x.shape
     x = x.transpose(0, 2, 1, 3).reshape(b * n, s, h)
-    return jnp.pad(x, ((0, 0), (0, seq - s), (0, _round_up(h, _LANE) - h)))
+    return jnp.pad(x, ((0, 0), (0, seq - s),
+                       (0, (width or _round_up(h, _LANE)) - h)))
 
 
 def _unfold(x, like):
@@ -403,41 +416,51 @@ def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, window,
     b, s_q, n, h = q.shape
     s_k, g = k.shape[1], k.shape[2]
     heads = n // g
-    hp = _round_up(h, _LANE)
+    # q and k score over their own width, v and the result have v's
+    hp, hv = _lanes(h), _lanes(v.shape[-1])
     block_q, block_k, sqp, skp = _blocks_for(s_q, s_k, block_q, block_k)
     _check_blocks(block_q, block_k, sqp, interpret)
     kernel = functools.partial(_flash_fwd_kernel, causal=causal,
                                sm_scale=sm_scale, block_k=block_k,
                                true_sk=s_k, window=window)
-    group = pl.BlockSpec((1, heads, block_q, hp),
-                         lambda bi, gi, i: (bi, gi, i, 0))
-    whole = pl.BlockSpec((1, 1, skp, hp), lambda bi, gi, i: (bi, gi, 0, 0))
+
+    def group(width):
+        return pl.BlockSpec((1, heads, block_q, width),
+                            lambda bi, gi, i: (bi, gi, i, 0))
+
+    def whole(width):
+        return pl.BlockSpec((1, 1, skp, width),
+                            lambda bi, gi, i: (bi, gi, 0, 0))
+
     out, lse = pl.pallas_call(
         kernel,
         grid=(b, g, sqp // block_q),
-        in_specs=[group, whole, whole],
+        in_specs=[group(hp), whole(hp), whole(hv)],
         out_specs=[
-            group,
+            group(hv),
             pl.BlockSpec((1, 1, heads, block_q),
                          lambda bi, gi, i: (bi, gi, 0, i)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b, n, sqp, hp), q.dtype),
+            jax.ShapeDtypeStruct((b, n, sqp, hv), q.dtype),
             jax.ShapeDtypeStruct((b, g, heads, sqp), jnp.float32),
         ],
-        scratch_shapes=[pltpu.VMEM((heads, hp, block_q), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((heads, hv, block_q), jnp.float32),
                         pltpu.VMEM((heads, 1, block_q), jnp.float32),
                         pltpu.VMEM((heads, 1, block_q), jnp.float32)],
         interpret=interpret,
-        **_compiler_params(block_q, block_k, skp, hp, q.dtype.itemsize,
-                           heads),
-    )(_fold(q, sqp).reshape(b, n, sqp, hp),
-      _fold(k, skp).reshape(b, g, skp, hp),
-      _fold(v, skp).reshape(b, g, skp, hp))
+        # the tiles as VMEM holds them, K's and V's widths averaged
+        **_compiler_params(block_q, block_k, skp,
+                           (_round_up(hp, _LANE) + hv) // 2,
+                           q.dtype.itemsize, heads),
+    )(_fold(q, sqp, hp).reshape(b, n, sqp, hp),
+      _fold(k, skp, hp).reshape(b, g, skp, hp),
+      _fold(v, skp, hv).reshape(b, g, skp, hv))
     # lse stays PADDED [BN, sqp]: the only consumer (_flash_bwd, which
     # pads to the same lengths) needs it padded anyway — slicing here
     # would just be re-padded there.
-    return (_unfold(out.reshape(b * n, sqp, hp), q),
+    like = jax.ShapeDtypeStruct((b, s_q, n, v.shape[-1]), q.dtype)
+    return (_unfold(out.reshape(b * n, sqp, hv), like),
             lse.reshape(b * n, sqp))
 
 
@@ -648,9 +671,11 @@ def flash_attention(q, k, v, causal: bool = True,
 def _flash_vjp_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret,
                    window):
     _check_window(window, causal)
-    if q.shape[2] % k.shape[2] or k.shape != v.shape:
-        raise ValueError(f"the KV heads must divide the query heads and k "
-                         f"and v agree, got q {q.shape} k {k.shape} "
+    if (q.shape[2] % k.shape[2] or k.shape[:3] != v.shape[:3]
+            or q.shape[-1] != k.shape[-1]):
+        raise ValueError(f"the KV heads must divide the query heads, k "
+                         f"and v agree in all but their width and q and k "
+                         f"in theirs, got q {q.shape} k {k.shape} "
                          f"v {v.shape}")
     if sm_scale is None:
         sm_scale = 1.0 / np.sqrt(q.shape[-1])
@@ -668,7 +693,12 @@ def _flash_vjp_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret,
 
 def _flash_vjp_bwd(causal, sm_scale, block_q, block_k, interpret, window,
                    residuals, g):
-    q = residuals[0]
+    q, k, v = residuals[:3]
+    if k.shape != v.shape:
+        raise NotImplementedError(
+            f"ray_tpu.ops.flash_attention's backward kernels take k and v "
+            f"of one shape, got k {k.shape} v {v.shape}: keys wider than "
+            f"values run forward only (serving)")
     if sm_scale is None:
         sm_scale = 1.0 / np.sqrt(q.shape[-1])
     return _for_lowering_platform(

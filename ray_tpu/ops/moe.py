@@ -45,12 +45,13 @@ from jax.sharding import Mesh, PartitionSpec as P
 from ray_tpu.ops.flash_attention import _for_lowering_platform, _round_up
 
 _HIGHEST = lax.Precision.HIGHEST
-# Row tile and column tile of ``gmm`` unless the caller names them. An
-# expert of the benchmark's cell sees 130-260 rows a prefill, so a row
-# tile of 256 keeps most experts to one or two visits; a column tile as
-# wide as the expert (3,072) reads each row tile once, which measured
+# Row tile of ``gmm`` unless the caller names it. An expert of the
+# benchmark's cells sees 130-260 rows a prefill, so a row tile of 256
+# keeps most experts to one or two visits. The column tile is as wide
+# as the matrix where that fits (3,072 at one cell's experts, 1,024 and
+# 2,304 at another's): each row tile is then read once, which measured
 # fastest of seven pairs (PERF.md §6, PR 28).
-_TILE_M, _TILE_N = 256, 3072
+_TILE_M = 256
 # Mosaic's default scope, and the most asked of a v5e core's 128 MiB. A
 # call took 1.56 times what ``_gmm_vmem`` counts (float32 operands, my
 # chip run, PR 28): the limit asked for is 1.75 times the count.
@@ -192,7 +193,7 @@ def _gmm(lhs, rhs, starts, ends, tile_m, tile_n, interpret):
     tile_m = min(tile_m or _TILE_M, m)
     # the widest column tile that divides and fits, else the whole
     tile_n = tile_n or next(
-        (t for t in range(min(n, _TILE_N), 0, -128) if n % t == 0
+        (t for t in range(n, 0, -128) if n % t == 0
          and _gmm_vmem(tile_m, t, k, lhs.dtype.itemsize) * _VMEM_MARGIN
          <= _VMEM_MOST), n)
     if m % tile_m or n % tile_n:

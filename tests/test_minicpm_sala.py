@@ -374,7 +374,8 @@ def test_config_from_hf_reads_the_family():
     assert config_from_hf(dict(tiny(SLICE), sparse_config={}),
                           128).sparse == SparseSizes()
     with pytest.raises(ValueError, match="'mistral', 'afmoe', "
-                                         "'minicpm_sala' and 'evabyte'"):
+                                         "'minicpm_sala', 'evabyte' and "
+                                         "'kimi_linear'"):
         config_from_hf(dict(tiny(SLICE), model_type="other"), 128)
     with pytest.raises(ValueError, match="no such layer"):
         TransformerConfig(n_layers=1, layers=(LayerSpec(mixer="scan"),))

@@ -440,6 +440,81 @@ def test_a_lightning_layer_passes_q_and_k_once(v5e):
     assert (operands, tuple_result) not in {(6, False), (3, True), (6, True)}
 
 
+# the Kimi-Linear cell: 32 heads of 128 on a kda layer; on an mla layer
+# 32 heads whose keys are 192 wide and whose values are 128
+def test_the_delta_rule_and_the_wide_keyed_flash_compile_for_tpu(v5e):
+    """At 16,384 tokens: the delta-rule kernel (its state, the decays'
+    sums, the triangular system and the pairs in VMEM; five operands
+    and one result, none of the signatures a device profile reads as a
+    flash kernel) and the flash forward at K 192 / V 128 (three
+    operands and a tuple: the flash forward's own; no padded copy of q
+    or k: its operands keep their 192 lanes)."""
+    from ray_tpu.ops.kda_attention import kda_attention
+    one = SingleDeviceSharding(v5e[0])
+    wide = jax.ShapeDtypeStruct((1, 16384, 32, 128), jnp.bfloat16,
+                                sharding=one)
+    decays = jax.ShapeDtypeStruct((1, 16384, 32, 128), jnp.float32,
+                                  sharding=one)
+    steps = jax.ShapeDtypeStruct((1, 16384, 32), jnp.float32, sharding=one)
+    scan = jax.jit(kda_attention).lower(wide, wide, wide, decays, steps)
+    _assert_kernel_not_interpreter(scan)
+    (call,) = _pallas_calls(scan.compile().as_text())
+    assert call == ("kda_attn", 5, False)
+    assert call[1:] not in {(6, False), (3, True), (6, True)}
+    keys = jax.ShapeDtypeStruct((1, 16384, 32, 192), jnp.bfloat16,
+                                sharding=one)
+    flash = jax.jit(lambda q, k, v: flash_attention(q, k, v)).lower(
+        keys, keys, wide)
+    _assert_kernel_not_interpreter(flash)
+    text = flash.compile().as_text()
+    ((_name, operands, tuple_result),) = _pallas_calls(text)
+    assert (operands, tuple_result) == (3, True)
+    (line,) = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert line.count("bf16[1,32,16384,192]") == 2
+    assert "16384,256]" not in text
+
+
+def test_a_kda_layer_s_bytes_are_the_starting_point(v5e):
+    """The dense kda layer of the Kimi-Linear cell at 16,384 tokens,
+    compiled alone: 11.2 GB of ``bytes accessed`` (PERF.md §6, PR 40),
+    of which the kernel's own operands are 1.0 and the SwiGLU some 1.5:
+    the yardstick for moving the convolutions, the gates, the L2 norms
+    and the output norm out of XLA. (A routed layer's count holds every
+    rung of the experts' ladder and says nothing.) And one mla layer,
+    its flash call the forward's."""
+    import functools
+
+    from ray_tpu.models import init_params
+    from ray_tpu.models.transformer import _layer_forward
+    one = SingleDeviceSharding(v5e[0])
+    cfg = _cell_cfg("kimi-linear-48b-a3b-l13-ep8", 16384)
+    assert (cfg.layers[0].mixer, cfg.layers[3].mixer) == ("kda", "mla")
+    assert not cfg.layers[0].experts
+    from ray_tpu.ops.flash_attention import flash_attention as flash
+    found = {}
+    for index in (0, 3):
+        block = jax.tree.map(
+            lambda s: jax.ShapeDtypeStruct(s.shape, jnp.bfloat16,
+                                           sharding=one),
+            jax.eval_shape(lambda k: init_params(k, cfg)["blocks"][index],
+                           jax.random.PRNGKey(0)))
+        layer = functools.partial(
+            _layer_forward, spec=cfg.layers[index], cfg=cfg,
+            attn_fn=lambda q, k, v: flash(q, k, v))
+        compiled = jax.jit(layer).lower(
+            block, jax.ShapeDtypeStruct((1, 16384, cfg.d_model),
+                                        jnp.bfloat16, sharding=one),
+            jax.ShapeDtypeStruct((1, 16384), jnp.int32,
+                                 sharding=one)).compile()
+        calls = _pallas_calls(compiled.as_text())
+        found[cfg.layers[index].mixer] = (
+            [c for c in calls if c[0] != "moe_gmm"],
+            compiled.cost_analysis()["bytes accessed"])
+    assert found["kda"][0] == [("kda_attn", 5, False)]
+    assert [c[1:] for c in found["mla"][0]] == [(3, True)]
+    assert found["kda"][1] < 12.5e9
+
+
 def test_flash_compiles_under_a_mesh(v5e):
     """The partitioner refuses a bare Mosaic kernel; under a mesh the
     kernel runs per device on its batch/head shard."""

@@ -152,6 +152,63 @@ def test_moe_route_record_counts_the_rows_a_forward_routed(recorder_off):
     assert got.counts["rows_computed"] == 64
 
 
+def test_delta_plan_record_counts_a_traced_shape(recorder_off):
+    """One `model.delta.plan` record for each shape `forward_with_stats`
+    is traced at, from shapes alone, if the pattern holds a `kda` or an
+    `mla` layer; nothing with the recorder off, nothing for a pattern
+    without either."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models import (LayerSpec, TransformerConfig,
+                                forward_with_stats, init_params)
+    from ray_tpu.models.transformer import KdaSizes, MlaSizes
+    cfg = TransformerConfig(
+        vocab_size=32, d_model=32, n_layers=3, n_heads=2, n_kv_heads=2,
+        d_ff=64, head_dim=16, remat=False, dtype=jnp.float32,
+        layers=(LayerSpec(mixer="kda", rope=False),) * 2
+        + (LayerSpec(mixer="mla", rope=False),),
+        kda=KdaSizes(conv=4, rank=8),
+        mla=MlaSizes(kv_rank=8, nope=16, shared=8, value=16))
+    shapes = jax.eval_shape(lambda k: init_params(k, cfg),
+                            jax.random.PRNGKey(0))
+
+    def trace(batch, seq):
+        jax.eval_shape(lambda p, t: forward_with_stats(p, t, cfg), shapes,
+                       jax.ShapeDtypeStruct((batch, seq), jnp.int32))
+
+    trace(1, 100)
+    assert tracing.spans() == []
+    get_config().apply_system_config({"event_log_enabled": True})
+    jax.eval_shape(
+        lambda p, t: forward_with_stats(p, t, TransformerConfig(
+            vocab_size=32, d_model=32, n_layers=1, n_heads=2, n_kv_heads=2,
+            d_ff=64, remat=False)),
+        jax.eval_shape(lambda k: init_params(k, TransformerConfig(
+            vocab_size=32, d_model=32, n_layers=1, n_heads=2, n_kv_heads=2,
+            d_ff=64)), jax.random.PRNGKey(0)),
+        jax.ShapeDtypeStruct((1, 100), jnp.int32))
+    assert tracing.spans() == []
+    for batch, seq in ((1, 100), (2, 192)):
+        trace(batch, seq)
+    plans = [s.counts for s in tracing.spans()
+             if s.name == "model.delta.plan"]
+    assert [p["tokens"] for p in plans] == [100, 384]
+    for (batch, seq), plan in zip(((1, 100), (2, 192)), plans):
+        tokens = batch * seq
+        assert plan == {
+            "tokens": tokens, "kda_layers": 2, "mla_layers": 1, "chunk": 64,
+            "chunks": -(-seq // 64),
+            # a token and head: 7 x 16 x 16 on the state
+            "kda_flops": 2 * tokens * 2 * 7 * 16 * 16,
+            # causal pairs x heads x 2 x (24 lanes scored + 16 weighed)
+            "mla_pair_flops": batch * seq * (seq + 1) // 2 * 2 * 2 * 40,
+            # the state in float32 and three tails of three tokens
+            "state_bytes": 2 * batch * (2 * 16 * 16 * 4 + 3 * 3 * 32 * 4),
+            "latent_bytes": tokens * (8 + 8) * 4,
+            "kv_bytes": tokens * 2 * 40 * 4}
+
+
 def test_ring_is_bounded_and_drops_the_oldest():
     for i in range(tracing.RING_SPANS + 10):
         tracing.record("x", i, i + 1)
