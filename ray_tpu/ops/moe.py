@@ -22,6 +22,14 @@ combine) pass over a static prefix of the sorted rows, the shortest of
 a short ladder that holds ``ends[-1]`` rows, chosen on the device for
 each call.
 
+The pairs' order, their sorted positions and the held experts' bounds
+come from sorts and counts: a TPU sorts scalars fast and gathers or
+scatters them one at a time. The order is the payload of the stable
+sort of the keys, each pair's position the payload of a second sort of
+the order, and a held expert's bounds are running sums of its rows
+counted by a compare; every result is the one the gathers, the scatter
+and the binary searches gave, bit for bit.
+
 Shapes: tokens ``m [T, D]``; ``router [D, E]``, ``bias [E]``;
 ``wg, wi [count, D, F]``, ``wo [count, F, D]``.
 
@@ -77,6 +85,10 @@ def route(m, router, bias, *, top_k: int, route_scale: float):
         m.astype(jnp.float32), router.astype(jnp.float32),
         precision=_HIGHEST))
     _, experts = lax.top_k(scores + bias.astype(jnp.float32), top_k)
+    # Gathered, though a one-hot select gives the same scores: in a whole
+    # program on the chip XLA then compiles the sum below in another
+    # fusion, its bits differ, and ties turn in the layers after
+    # (PERF.md §6).
     chosen = jnp.take_along_axis(scores, experts, axis=-1)
     weights = route_scale * chosen / (
         jnp.sum(chosen, axis=-1, keepdims=True) + 1e-20)
@@ -92,15 +104,18 @@ def _sorted_by_expert(experts, n_experts: int, held, rows: int):
     t, k = experts.shape
     key = ((experts - first) % n_experts).reshape(-1)
     key = jnp.pad(key, (0, rows - t * k), constant_values=n_experts)
-    order = jnp.argsort(key, stable=True)
-    position = jnp.zeros((rows,), jnp.int32).at[order].set(
-        jnp.arange(rows, dtype=jnp.int32))[:t * k].reshape(t, k)
-    sorted_key = key[order]
-    groups = jnp.arange(count, dtype=key.dtype)
-    starts = jnp.searchsorted(sorted_key, groups, side="left")
-    ends = jnp.searchsorted(sorted_key, groups, side="right")
-    token = jnp.minimum(order // k, t - 1).astype(jnp.int32)
-    return token, position, starts.astype(jnp.int32), ends.astype(jnp.int32)
+    pair = lax.iota(jnp.int32, rows)
+    # the stable sort ``argsort`` runs; the pairs ride it as its payload
+    _, order = lax.sort((key, pair), num_keys=1, is_stable=True)
+    # sorting the order hands each pair its sorted position: the inverse
+    _, position = lax.sort((order, pair), num_keys=1)
+    position = position[:t * k].reshape(t, k)
+    # a held expert's rows counted, and its bounds their running sums
+    given = jnp.sum(key == jnp.arange(count, dtype=key.dtype)[:, None],
+                    axis=1, dtype=jnp.int32)
+    ends = jnp.cumsum(given, dtype=jnp.int32)
+    token = jnp.minimum(order // k, t - 1)
+    return token, position, ends - given, ends
 
 
 # --------------------------------------------------------------------------

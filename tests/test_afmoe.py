@@ -13,6 +13,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import PartitionSpec as P
 
 from benchmark.reference import afmoe as ref
 from ray_tpu.models import (LayerSpec, TransformerConfig, config_from_hf,
@@ -458,3 +459,178 @@ def test_bf16_program_stays_near_the_reference():
     want = np.asarray(ref.logits_at(weights, tokens, last, sz))
     assert got.dtype == np.float32
     assert float(np.max(np.abs(got - want))) < 0.1
+
+
+# --------------------------------------------------------------------------
+# The sort: the sorts and counts against the gathers, the scatter and the
+# binary searches they replaced, bit for bit
+# --------------------------------------------------------------------------
+
+def _sorted_by_gather(experts, n_experts, held, rows):
+    """`_sorted_by_expert` as it was: argsort, the keys gathered in
+    order, the positions scattered, the bounds by binary search."""
+    first, count = held
+    t, k = experts.shape
+    key = ((experts - first) % n_experts).reshape(-1)
+    key = jnp.pad(key, (0, rows - t * k), constant_values=n_experts)
+    order = jnp.argsort(key, stable=True)
+    position = jnp.zeros((rows,), jnp.int32).at[order].set(
+        jnp.arange(rows, dtype=jnp.int32))[:t * k].reshape(t, k)
+    sorted_key = key[order]
+    groups = jnp.arange(count, dtype=key.dtype)
+    starts = jnp.searchsorted(sorted_key, groups, side="left")
+    ends = jnp.searchsorted(sorted_key, groups, side="right")
+    token = jnp.minimum(order // k, t - 1).astype(jnp.int32)
+    return token, position, starts.astype(jnp.int32), ends.astype(jnp.int32)
+
+
+def _rows_of(t, k):
+    """The sorted list's length as `routed_experts` pads it."""
+    tile_m = min(moe._TILE_M, moe._round_up(t * k, 8))
+    return moe._round_up(t * k, tile_m)
+
+
+# Both routed cells in small: 256 published experts, 32 held, top-4
+# (Trinity) or top-8 (Kimi-Linear); bias on the held experts as ROUTERS
+# names it, or ties planted by repeating each router column (and bias).
+PLUMBING = {
+    # name: (tokens, first, bias on the held experts, repeats a column)
+    "even": (64, 0, np.zeros(32), None),
+    "rotated_first": (64, 64, np.zeros(32), None),
+    "last_share": (64, 224, np.zeros(32), None),
+    # each column thirteen times: every top-k is ties
+    "tied": (64, 0, np.zeros(32), 13),
+    # columns in threes: the k-th and the next place tie on every token
+    "tied_at_the_kth_place": (64, 64, np.zeros(32), 3),
+    "one_expert_takes_most": (64, 64, np.r_[0, 0, 0, 5.0, np.zeros(28)],
+                              None),
+    "no_pair_held": (64, 64, np.full(32, -20.0), None),
+    "every_pair_held": (64, 64, np.r_[np.full(8, 20.0), np.zeros(24)], None),
+    # T x k is no whole tile: the sorted list is padded past the pairs
+    "padded": (61, 64, np.zeros(32), None),
+    "padded_tied": (99, 0, np.zeros(32), 13),
+}
+
+
+def _plumbing_inputs(name, seed=0, d=32):
+    t, first, on_held, repeats = PLUMBING[name]
+    key = jax.random.split(jax.random.PRNGKey(seed), 3)
+    m = jax.random.normal(key[0], (t, d), jnp.float32)
+    router = jax.random.normal(key[1], (d, 256)) * d ** -0.5
+    bias = jax.random.normal(key[2], (256,)) * 0.02
+    if repeats:
+        column = np.arange(256) // repeats
+        router, bias = router[:, column], bias[column]
+    bias = bias.at[first:first + 32].add(jnp.asarray(on_held, jnp.float32))
+    return m, router, bias, first
+
+
+@pytest.mark.parametrize("top_k", [4, 8])
+@pytest.mark.parametrize("name", sorted(PLUMBING))
+def test_the_plumbing_is_the_gathers_to_the_bit(name, top_k):
+    """Each sorted row's token, each pair's position and the held
+    experts' bounds are what the gathers, the scatter and the binary
+    searches gave, element for element and type for type."""
+    m, router, bias, first = _plumbing_inputs(name)
+    t = m.shape[0]
+    rows = _rows_of(t, top_k)
+    experts, _weights = jax.jit(lambda *a: moe.route(
+        *a, top_k=top_k, route_scale=2.446))(m, router, bias)
+    got = jax.jit(moe._sorted_by_expert, static_argnums=(1, 2, 3))(
+        experts, 256, (first, 32), rows)
+    want = jax.jit(_sorted_by_gather, static_argnums=(1, 2, 3))(
+        experts, 256, (first, 32), rows)
+    for what, g, w in zip(("token", "position", "starts", "ends"), got,
+                          want):
+        assert (g.dtype, g.shape) == (w.dtype, w.shape), what
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w), what)
+    given = np.asarray(got[3] - got[2])
+    scores = np.sort(np.asarray(jax.nn.sigmoid(
+        m @ router) + bias), axis=1)[:, ::-1]
+    ties_at_kth = np.sum(scores[:, top_k - 1] == scores[:, top_k])
+    if name.startswith("tied"):
+        assert ties_at_kth > 0
+    if name == "tied_at_the_kth_place":
+        assert ties_at_kth == t
+    if name == "one_expert_takes_most":
+        assert given[3] == t and given[3] > given.sum() / 2
+    if name == "no_pair_held":
+        assert given.sum() == 0 and not np.any(np.asarray(got[3]))
+    if name == "every_pair_held":
+        assert given.sum() == t * top_k
+    if name.startswith("padded"):
+        assert rows > t * top_k
+
+
+@pytest.mark.parametrize("name", ["even", "tied", "one_expert_takes_most",
+                                  "padded"])
+def test_the_layer_is_the_one_the_gathers_made(monkeypatch, own_traces,
+                                               name):
+    """The whole routed layer at the Kimi-Linear cell's top-8, small:
+    its result and row counts are bit for bit those of the layer whose
+    sort gathers, scatters and searches."""
+    m, router, bias, first = _plumbing_inputs(name)
+    key = jax.random.split(jax.random.PRNGKey(1), 3)
+    wg, wi = (jax.random.normal(k, (32, 32, 64)) * 0.2 for k in key[:2])
+    wo = jax.random.normal(key[2], (32, 64, 32)) * 0.2
+
+    def layer():
+        return jax.jit(lambda *a: moe.routed_experts(
+            *a, held=(first, 32), top_k=8, route_scale=2.446, tile_m=8,
+            interpret=True))(m, router, bias, wg, wi, wo)
+
+    out, rows = layer()
+    monkeypatch.setattr(moe, "_sorted_by_expert", _sorted_by_gather)
+    moe._held_part.clear_cache()
+    was_out, was_rows = layer()
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(was_out))
+    np.testing.assert_array_equal(np.asarray(rows), np.asarray(was_rows))
+
+
+@pytest.mark.parametrize("top_k", [4, 8])
+def test_the_plumbing_under_a_traced_first(top_k):
+    """Under `shard_map`, as `make_moe_fn` calls it, `first` is the
+    axis index times the held count: each of four shards' sort is the
+    gathers' at its own share."""
+    from ray_tpu.parallel.mesh import MeshSpec, make_mesh
+    m, router, bias, _first = _plumbing_inputs("tied")
+    t = m.shape[0]
+    rows = _rows_of(t, top_k)
+    experts, _weights = moe.route(m, router, bias, top_k=top_k,
+                                  route_scale=2.446)
+
+    def body(experts):
+        first = jax.lax.axis_index("tp") * 32
+        return tuple(x[None] for x in moe._sorted_by_expert(
+            experts, 256, (first, 32), rows))
+
+    mesh = make_mesh(MeshSpec(tp=4), jax.devices()[:4])
+    with mesh:
+        got = jax.jit(jax.shard_map(
+            body, mesh=mesh, in_specs=P(), out_specs=(P("tp"),) * 4,
+            check_vma=False))(experts)
+    for shard in range(4):
+        want = _sorted_by_gather(experts, 256, (32 * shard, 32), rows)
+        for g, w in zip(got, want):
+            assert g.dtype == w.dtype
+            np.testing.assert_array_equal(np.asarray(g)[shard],
+                                          np.asarray(w))
+
+
+def test_the_routed_layer_sorts_with_no_gather_or_scatter_over_the_pairs():
+    """The sort at the Kimi-Linear cell's shape in small (top-8 of 256,
+    32 held) lowers with no gather and no scatter; the same check finds
+    them in the form it replaced."""
+    experts = jnp.zeros((64, 8), jnp.int32)
+    rows = _rows_of(64, 8)
+
+    def lowered(sort):
+        return jax.jit(sort, static_argnums=(1, 2, 3)).lower(
+            experts, 256, (64, 32), rows).as_text()
+
+    text = lowered(moe._sorted_by_expert)
+    assert "stablehlo.sort" in text
+    assert "stablehlo.gather" not in text
+    assert "stablehlo.scatter" not in text
+    was = lowered(_sorted_by_gather)
+    assert "stablehlo.gather" in was and "stablehlo.scatter" in was
